@@ -20,7 +20,9 @@ One-machine demo on the simulated mesh (two terminals, or `&`):
       python examples/train_async_multiprocess.py
 
 On a real pod, run ROLE=server on one host and ROLE=worker (with
-CENTER_ADDR=<server-host>:<port>) on the rest.  RULE=asgd selects the
+CENTER_ADDR=<server-host>:<port>) on the rest — one process per host: a
+second process on the same TPU host cannot claim chips the first one
+holds, so the one-machine demo above is for the CPU mesh only.  RULE=asgd selects the
 downpour exchange (accumulate ``sync_freq`` steps, ship the delta, reset
 to the returned center) instead of the elastic one.
 """

@@ -1,24 +1,21 @@
 #!/usr/bin/env python
-"""The matrix-row manifest: ONE definition of every staged bench config.
+"""The row manifest: ONE definition of every staged bench config.
 
-Before round 8, each ``perf_matrix_r*.sh`` embedded its row definitions as
-inline env assignments and ``forensics/prewarm_cache.py`` carried its own
-parallel CONFIGS list — two hand-synced copies of (model, batch, rule, spc,
-flags).  A drift between them silently forfeits the executable-cache hit
-the prewarm exists to guarantee (the program key is content-addressed: a
-shape that merely LOOKS the same misses).  This module is the single
-source both sides consume:
+Each row is a label plus the ``BENCH_*`` settings that shape it.  A drift
+between the program a row measures and the program prewarmed for it
+silently forfeits the executable-cache hit (the program key is
+content-addressed: a shape that merely LOOKS the same misses), so this
+module is the single source both sides consume:
 
-* ``scripts/perf_matrix_r8.sh`` (and later rounds) iterate
-  ``python scripts/rows.py --round 8 --sh`` — one ``label ENV=V ...`` line
-  per row, fed straight to ``_bench_row.sh``'s ``run``;
+* ``python scripts/rows.py --round 8 --sh`` prints one ``label ENV=V ...``
+  line per row, for ``env ENV=V ... python bench.py``;
 * ``scripts/prewarm_cache.py`` builds each row's program through
-  ``bench.bench_row_config(row.env)`` — the SAME env→config assembly the
-  bench inner uses — and compiles it into the executable cache.
+  ``bench.bench_row_config(row.env)`` — the SAME env→config assembly
+  ``bench.main`` uses — and compiles it into the executable cache.
 
-Row labels follow the ``_cfg_matches`` conventions in bench.py
-(model[-bN][-rule][-strategy][-spcK][-realdata][-winload][-...]) so
-``last_good`` fallbacks and resume-skip logic keep working unchanged.
+Row labels read model[-bN][-rule][-strategy][-spcK][-realdata][-winload]
+[-...].  None of these rows has run on a chip since round 3; ROADMAP S1
+rebuilds the benchmark's cells from them.
 """
 
 from __future__ import annotations
@@ -40,9 +37,8 @@ def _r(label: str, rounds: str, **env) -> Row:
                tuple(rounds.split()))
 
 
-# "heavy" = the wedge-correlated long compiles (26–270 s each measured in
-# round 5, forensics/prewarm_cache.py docstring) — the prewarm default: what
-# a short hardware window cannot afford to compile on the clock.
+# "heavy" = the long compiles (26–270 s each measured in round 5) — the
+# prewarm default.
 ROWS: List[Row] = [
     # -- round-8 canary + acceptance rows (executable-cache proof) --------
     _r("cifar10-b128-spc4", "r8 heavy", BENCH_MODEL="cifar10", BENCH_SPC=4),
@@ -167,7 +163,7 @@ ROWS: List[Row] = [
     # BENCH_FUSE=0 → THEANOMPI_TPU_NO_PALLAS=1) — identical wire bits, the
     # step-time delta is the kernels' HBM-traffic win.  On the CPU sim both
     # run the oracles (the rows pin wiring + the compress_traffic_report
-    # columns); the A/B lands when the hardware window reopens.
+    # columns); the A/B needs a chip run (ROADMAP S6).
     # scripts/predict_scaling.py joins these against the modeled shrink.
     _r("transformer_lm-b8-onebit-n2", "r12",
        BENCH_MODEL="transformer_lm", BENCH_BATCH=8, BENCH_STRATEGY="onebit",
@@ -228,7 +224,7 @@ def main(argv=None) -> int:
                    help="group tag (r7/r8/heavy), 'all', or label[,label...]")
     p.add_argument("--sh", action="store_true",
                    help="emit one shell line per row: label ENV=V ... "
-                        "(for `run` in scripts/_bench_row.sh)")
+                        "(for `env K=V ... python bench.py`)")
     p.add_argument("--labels", action="store_true",
                    help="emit labels only")
     args = p.parse_args(argv)

@@ -153,7 +153,7 @@ def test_version_mismatch_falls_back(tmp_path):
 def test_prewarm_header_check_recompiles(tmp_path):
     """``load=False`` trusts an entry only after its header parses: a
     truncated or version-drifted entry is re-prewarmed off-line instead of
-    being discovered as a deserialize-fallback in the hardware window."""
+    being discovered as a deserialize-fallback on the chip's clock."""
     cache, lowered, key = _tiny_entry(tmp_path)
     path = os.path.join(cache.cache_dir, key + ".jexec")
     with open(path, "wb") as fh:
@@ -238,9 +238,8 @@ def test_recorder_compile_bucket():
 
 
 def test_rows_manifest_consistency():
-    """Every manifest row's env round-trips through bench_row_config and
-    its label matches bench's _cfg_matches conventions — the drift guard
-    between prewarm shapes and measured shapes."""
+    """Every manifest row's env round-trips through bench_row_config —
+    the drift guard between prewarm shapes and measured shapes."""
     sys.path.insert(0, REPO)
     from scripts.rows import ROWS, rows
     import bench
@@ -260,18 +259,6 @@ def test_rows_manifest_consistency():
             else:
                 os.environ["THEANOMPI_TPU_NO_PALLAS"] = saved_np
         assert row.label.startswith(model_name), row
-        # bench.py's fallback matcher must recognize the row's own label
-        # under the row's own env (the contract last_good relies on)
-        old = {k: os.environ.get(k) for k in row.env}
-        os.environ.update(row.env)
-        try:
-            assert bench._cfg_matches(row.label), row
-        finally:
-            for k, v in old.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
         if "BENCH_SPC" in row.env and int(row.env["BENCH_SPC"]) > 1:
             assert config["steps_per_call"] == int(row.env["BENCH_SPC"])
 

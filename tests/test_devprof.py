@@ -525,35 +525,3 @@ def test_compile_cache_manifest_carries_cost_summary(tmp_path):
     assert r.returncode == 2 and "cannot resolve" in r.stderr
 
 
-# -- merge_matrix column tolerance ------------------------------------------
-
-def test_merge_matrix_tolerates_trace_columns(tmp_path):
-    """Rows carrying the BENCH_TRACE columns (and rows with odd value
-    types) merge against old rows without KeyErrors — absent columns are
-    unknown, never a regression/demotion."""
-    sys.path.insert(0, REPO)
-    from scripts import merge_matrix
-
-    p = tmp_path / "m.jsonl"
-    rows = [
-        # old-style row: no trace columns
-        {"config": "alexnet-b128", "result": {"metric": "m", "value": 10.0}},
-        # tombstone with a ts; then a new-style row whose value is absent
-        {"config": "vgg16-b32", "result": None, "note": "degraded window",
-         "voided_value": 5.0, "ts": 100.0},
-        {"config": "vgg16-b32", "ts": "not-a-number",
-         "result": {"metric": "m", "value": None,
-                    "overlap_ratio": 0.7, "exposed_comm_secs": 0.01}},
-        # newer re-measure of the first config WITH trace columns wins
-        {"config": "alexnet-b128",
-         "result": {"metric": "m", "value": 12.0, "overlap_ratio": 0.9,
-                    "exposed_comm_secs": 0.002, "device_mfu": None}},
-    ]
-    p.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    merge_matrix.merge([str(p)])          # must not raise
-    out = {r["config"]: r for r in
-           (json.loads(l) for l in p.read_text().splitlines())}
-    assert out["alexnet-b128"]["result"]["value"] == 12.0
-    assert out["alexnet-b128"]["result"]["overlap_ratio"] == 0.9
-    # the None-valued row still merged (it outranks the tombstone's null)
-    assert out["vgg16-b32"]["result"]["overlap_ratio"] == 0.7

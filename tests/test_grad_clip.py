@@ -2,7 +2,6 @@
 every rule gets it; pinned against a hand-computed clipped step."""
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +56,6 @@ def test_grad_clip_on_async_rule(mesh4):
     assert np.isfinite(float(m.current_info["cost"]))
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="CPU venue gap: the legacy (0.4.x, check_rep=False) shard_map "
-           "transposes psum as psum, inflating tp-sharded grads ~tp x "
-           "(rank-partial for replicated leaves) — Adam absorbs the "
-           "scale so plain tp equivalence passes, but the norm-dependent "
-           "clip exposes it; needs the vma type system")
 def test_grad_clip_under_tensor_parallelism(mesh8):
     """The clip norm must be the GLOBAL norm under tp (sharded leaves
     psum'd, replicated leaves counted once): tp=4 with an aggressive clip

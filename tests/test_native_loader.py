@@ -157,3 +157,31 @@ def test_u8_wire_mean_survives_para_load(tmp_path):
     cy, cx = (full.shape[0] - c) // 2, (full.shape[1] - c) // 2
     np.testing.assert_allclose(mean, full[cy:cy + c, cx:cx + c, :],
                                rtol=1e-6)
+
+
+def test_so_built_from_other_source_is_not_reused(tmp_path, monkeypatch):
+    """The built library is keyed on loader.cc + the compiler flags: a file
+    left behind by another source or other flags (the chip tool copies the
+    working tree, ignored files included) is never picked up."""
+    src = tmp_path / "loader.cc"
+    with open(native._SRC, "rb") as f:
+        src.write_bytes(f.read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    first = native._so_path()
+    with open(first, "wb") as f:
+        f.write(b"an earlier build of this very source")
+    assert native._build() == first              # same key: reused as is
+
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    second = native._so_path()
+    assert second != first
+    built = native._build()
+    if built is None:
+        pytest.skip("no native toolchain in this environment")
+    assert built == second
+    import ctypes
+    assert ctypes.CDLL(built).tmpi_loader_abi_version() == 1
+
+    monkeypatch.setattr(native, "_CXXFLAGS", native._CXXFLAGS + ("-DX=1",))
+    assert native._so_path() not in (first, second)

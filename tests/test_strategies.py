@@ -194,21 +194,6 @@ def test_pack_unpack_roundtrip():
     np.testing.assert_array_equal(signs, np.where(c >= 0, 1.0, -1.0))
 
 
-# Known venue gap, NOT a regression: interpret-mode Pallas on this
-# container's jax (0.4.x) dies on the removed `jax.typeof` before the
-# kernel runs, so the kernel-vs-oracle comparison is only executable
-# compiled on the TPU venue (or on a jax new enough to carry typeof).
-# An explicit skip keeps tier-1 output distinguishing "oracle requires
-# TPU" from a real kernel break; DOTS_PASSED is unaffected (skips print
-# `s`, not `.`).
-pallas_interpret_venue = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="CPU venue gap: interpret-mode Pallas needs jax.typeof "
-           "(absent on this 0.4.x container) — oracle comparison runs "
-           "compiled on the TPU venue")
-
-
-@pallas_interpret_venue
 def test_pack_pallas_matches_jnp_oracle():
     """The Pallas kernel pair (interpret mode here — compiled on TPU) and the
     jnp oracle must produce bit-identical wire buffers."""
@@ -221,7 +206,6 @@ def test_pack_pallas_matches_jnp_oracle():
                                   np.asarray(packed_jnp))
 
 
-@pallas_interpret_venue
 def test_unpack_weighted_sum_pallas_matches_jnp_oracle():
     r = np.random.RandomState(14)
     c = r.randn(4, compress.PACK_ALIGN).astype(np.float32)
@@ -233,7 +217,6 @@ def test_unpack_weighted_sum_pallas_matches_jnp_oracle():
                                np.asarray(expect), rtol=1e-6, atol=1e-6)
 
 
-@pallas_interpret_venue
 def test_encode_pallas_matches_jnp_oracle():
     """Fused onebit encode: one error-fed read → (packed signs, |c|),
     bit-identical to the oracle on both outputs."""
@@ -250,7 +233,6 @@ def test_encode_pallas_matches_jnp_oracle():
                                   np.asarray(abs_jnp))
 
 
-@pallas_interpret_venue
 def test_residual_pallas_matches_jnp_oracle():
     """Fused onebit residual: ``where(bit, |c|−scale, scale−|c|)`` from the
     packed bits, bit-identical to the oracle (which is itself bit-exact vs
@@ -269,63 +251,23 @@ def test_residual_pallas_matches_jnp_oracle():
                                   np.asarray(expect))
 
 
-@pallas_interpret_venue
-def test_topk_encode_pallas_matches_jnp_oracle():
-    """Fused topk encode: iterative-argmax selection must match lax.top_k
-    bit-for-bit on values, indices (incl. the lower-index tie-break), and
-    the in-place bf16 residual — with an all-zero row, where only explicit
-    selected-lane masking keeps the orders identical."""
-    r = np.random.RandomState(17)
-    rows, chunk, k = 3, 512, 8
-    c2 = r.randn(rows, chunk).astype(np.float32)
-    c2[1, :] = 0.0
-    c2 = jnp.asarray(c2)
-    vals_pl, idx_pl, state_pl = compress._topk_encode_pallas(
-        c2, k, interpret=True)
-    vals_jnp, idx_jnp, state_jnp = compress.topk_encode_jnp(c2, k)
-    np.testing.assert_array_equal(
-        np.asarray(vals_pl, dtype=np.float32),
-        np.asarray(vals_jnp, dtype=np.float32))
-    np.testing.assert_array_equal(np.asarray(idx_pl), np.asarray(idx_jnp))
-    np.testing.assert_array_equal(np.asarray(state_pl),
-                                  np.asarray(state_jnp))
-
-
-@pallas_interpret_venue
-def test_topk_decode_pallas_matches_jnp_oracle():
-    """Fused topk decode: VMEM block-local expand + folded /size mean vs
-    the oracle's scatter-add (same (worker asc, slot asc) accumulation
-    order per element)."""
-    r = np.random.RandomState(18)
-    w, rows, chunk, k = 3, 2, 256, 16
-    encs = [compress.topk_encode_jnp(
-        jnp.asarray(r.randn(rows, chunk).astype(np.float32)), k)
-        for _ in range(w)]
-    all_vals = jnp.stack([e[0] for e in encs])
-    all_idx = jnp.stack([e[1] for e in encs])
-    got = compress._topk_decode_pallas(all_vals, all_idx, chunk, w,
-                                       interpret=True)
-    expect = compress.topk_decode_jnp(all_vals, all_idx, chunk, size=w)
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(expect), rtol=1e-6, atol=1e-6)
-
-
-@pallas_interpret_venue
-def test_matmul_pack_pallas_matches_jnp_oracle():
+@pytest.mark.parametrize("rows,cols", [(10, 64), (300, 2500)])
+def test_matmul_pack_pallas_matches_jnp_oracle(rows, cols):
     """Fused PowerSGD factor matmul + staging pack: the MXU tile must equal
     ``m @ q`` with the pad rows exactly zero (the stacked-psum identity in
-    parallel/strategies.py PowerSGD rests on those zeros)."""
+    parallel/strategies.py PowerSGD rests on those zeros).  The second
+    shape spans two K blocks with a masked tail and a partial row block."""
     from theanompi_tpu.ops import factor_pack
     r = np.random.RandomState(19)
-    m = jnp.asarray(r.randn(10, 64).astype(np.float32))
-    q = jnp.asarray(r.randn(64, 2).astype(np.float32))
-    rows_pad = factor_pack.pad_rows(10)
+    m = jnp.asarray(r.randn(rows, cols).astype(np.float32))
+    q = jnp.asarray(r.randn(cols, 2).astype(np.float32))
+    rows_pad = factor_pack.pad_rows(rows)
     got = factor_pack._matmul_pack_pallas(m, q, rows_pad, interpret=True)
     expect = factor_pack.matmul_pack_jnp(m, q, rows_pad)
     assert got.shape == (rows_pad, 2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_array_equal(np.asarray(got)[10:], 0.0)
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got)[rows:], 0.0)
 
 
 def test_unpack_weighted_sum_oracle():

@@ -1,20 +1,16 @@
 """compat-boundary: shard_map/pvary/pcast go through ``jax_compat`` only.
 
-The invariant (docs/design.md §12): the container may pin a jax where
-``shard_map`` still lives in ``jax.experimental.shard_map`` and the vma
-type system (``lax.pvary`` / ``lax.pcast``) does not exist — every call
-site therefore routes through ``theanompi_tpu/jax_compat.py`` (the
-shim) or ``steps._vary`` (the version-adaptive marker, which probes via
-``getattr(lax, "pcast", ...)`` and is deliberately invisible to this
-AST check).  A direct ``jax.shard_map`` / ``lax.pvary`` / ``lax.pcast``
-reference anywhere else breaks the 0.4.x container even though it
-imports fine on current jax — exactly the class of drift PR 1 recovered
-tier-1 from.
+The invariant (docs/design.md §12): jax has moved ``shard_map`` and the
+vma markers (``lax.pvary`` → ``lax.pcast``) between releases, so every
+call site routes through ``theanompi_tpu/jax_compat.py`` (``shard_map``,
+``vary``; ``steps._vary`` is the latter's alias) and the next move is
+absorbed in one file.  A direct ``jax.shard_map`` / ``lax.pvary`` /
+``lax.pcast`` reference anywhere else is flagged.
 
 Flagged: attribute references resolving to the banned dotted names, and
-imports from ``jax.experimental.shard_map`` (the legacy location —
-only the shim may touch it).  Name USES of a banned imported alias are
-not re-flagged; the import line carries the finding.
+imports from ``jax.experimental.shard_map`` (the legacy location).  Name
+USES of a banned imported alias are not re-flagged; the import line
+carries the finding.
 """
 
 from __future__ import annotations
@@ -67,9 +63,8 @@ class CompatBoundaryChecker(Checker):
                             self.name, sf.path, node.lineno,
                             node.col_offset,
                             f"import of `{full}` outside jax_compat.py "
-                            "— absent on the 0.4.x container; use "
-                            "theanompi_tpu.jax_compat (shard_map) or "
-                            "steps._vary (pvary/pcast)"))
+                            "— use theanompi_tpu.jax_compat (shard_map, "
+                            "vary)"))
                 continue
             if isinstance(node, ast.Import):
                 for a in node.names:
@@ -87,7 +82,6 @@ class CompatBoundaryChecker(Checker):
                     findings.append(Finding(
                         self.name, sf.path, node.lineno, node.col_offset,
                         f"direct `{resolved}` reference outside "
-                        "jax_compat.py — absent on the 0.4.x container; "
-                        "use theanompi_tpu.jax_compat (shard_map) or "
-                        "steps._vary (pvary/pcast)"))
+                        "jax_compat.py — use theanompi_tpu.jax_compat "
+                        "(shard_map, vary)"))
         return findings

@@ -4,9 +4,7 @@ plus shard-rebuild-dominance, the update-sharding escape gate.
 The invariant (docs/design.md §12, guarding the PR-3 AOT-cache rules):
 ``jax.jit(..., donate_argnums=...)`` hands the argument's HBM to the
 callee — after the call the old array is invalid, and reading it is
-use-after-free that jax only sometimes catches (and a deserialized AOT
-executable on this container's CPU backend turns into heap corruption,
-which is why ``compile_cache.donated_load_safe`` exists at all).
+use-after-free that jax only sometimes catches.
 
 Per-scope analysis: the checker records names bound to
 ``jax.jit(..., donate_argnums=...)`` with their donated positional

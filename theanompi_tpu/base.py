@@ -44,9 +44,8 @@ class MeshProcess:
         control plane if configured, then the 1-D workers mesh."""
         platform = self.config.get("platform")
         if platform:
-            # programmatic platform pin (config `platform=cpu`): the
-            # JAX_PLATFORMS env var is not reliable under external PJRT
-            # plugins, and launcher-spawned workers have no other hook
+            # programmatic platform pin (config `platform=cpu`) for
+            # launcher-spawned workers, beside the JAX_PLATFORMS env var
             jax.config.update("jax_platforms", platform)
         impl = canonical_prng_impl(self.config.get("prng_impl"))
         if impl:

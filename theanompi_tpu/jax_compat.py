@@ -1,16 +1,11 @@
-"""Version-portability shims: ``shard_map`` and async collectives.
+"""The one place this package touches jax's moving SPMD surface:
+``shard_map``, the vma "varying" marker, and async collectives.
 
-The framework is written against current jax (``jax.shard_map`` with the
-vma varying-axes type system).  The container this repo grows in may pin
-an older release (observed: 0.4.37) where shard_map still lives in
-``jax.experimental.shard_map`` and replication is tracked by the legacy
-``check_rep`` pass instead of vma.  Every shard_map call site goes
-through this one shim so the SPMD machinery imports and runs on both.
-
-On the legacy path ``check_rep=False``: the old replication checker
-predates the vma typing this code is written for (per-worker varying
-scan carries, ``steps.anchor_invariant``) and rejects valid programs
-here; on current jax the vma system supersedes it anyway.
+The target is the jax this container and the chip machine run (0.9.0):
+``jax.shard_map`` with the vma varying-axes type system, ``jax.typeof`` and
+``lax.pcast``.  Every call site goes through this module (the tpulint
+``compat-boundary`` checker enforces it), so the next API move is absorbed
+in one file.
 
 **Async collective start/done pairs** (the bucketed-overlap wire,
 ``parallel/buckets.py``): some jaxlibs expose an explicit async
@@ -43,18 +38,28 @@ from jax import lax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
+
+
+def vary(x, axis_name: str):
+    """Mark a replicated value as device-varying over ``axis_name`` for
+    shard_map's vma type system (scan carries that accumulate per-worker
+    values need this).  Idempotent: an already-varying value passes
+    through — ``pcast`` raises on varying→varying, and callers like
+    ``steps._revary_bn`` see either kind (the async rules' ``sync_bn`` is
+    the identity, so their BN stats arrive varying; BSP's pmean'd stats
+    arrive invariant)."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 # -- async collective start/done ---------------------------------------------
 
 # True when the running jaxlib exposes a real async start/done surface;
-# the sync fallback below is used otherwise (0.4.x has none).
+# the sync fallback below is used otherwise.  False on jax 0.9.0
+# (``lax.psum_start`` is absent).
 HAS_ASYNC_COLLECTIVES = all(
     hasattr(lax, n) for n in ("psum_start", "psum_done"))
 

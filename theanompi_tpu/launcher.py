@@ -17,6 +17,7 @@ automatic), either printing them for ``gcloud compute tpus tpu-vm ssh
 from __future__ import annotations
 
 import argparse
+import os
 import shlex
 import subprocess
 import sys
@@ -99,7 +100,9 @@ def main(argv=None) -> int:
                         "clean exit (default 256)")
     p.add_argument("--host-devices", type=int, default=0, metavar="K",
                    help="elastic mode, CPU venue: each worker simulates K "
-                        "chips on the cpu backend (0 = real hardware)")
+                        "chips on the cpu backend (0 = the backend JAX "
+                        "finds; refused for more than one worker unless "
+                        "the platform is pinned to cpu)")
     p.add_argument("--center-proc", action="store_true",
                    help="elastic mode: run the center server as its OWN "
                         "supervised process — crash-atomic snapshots, "
@@ -159,6 +162,17 @@ def main(argv=None) -> int:
         # Elastic membership (parallel/membership.py): workers join/leave
         # mid-run; the async center algebra absorbs the churn — no
         # world restart.  BSP has no shrink reaction: refuse early.
+        if args.elastic > 1 and args.host_devices == 0 \
+                and os.environ.get("JAX_PLATFORMS") != "cpu" \
+                and "platform=cpu" not in kv:
+            # a chip belongs to one process: the island workers are
+            # separate processes and nothing binds each to its own chip
+            print(f"launcher: --elastic {args.elastic} starts "
+                  f"{args.elastic} worker processes, and on an accelerator "
+                  f"host each would claim every chip — refused; pass "
+                  f"--host-devices K (CPU venue) or platform=cpu",
+                  file=sys.stderr)
+            return 2
         from .parallel.membership import parse_kv, run_elastic
         return run_elastic(args.rule, args.modelfile, args.modelclass,
                            parse_kv(kv), args.elastic,

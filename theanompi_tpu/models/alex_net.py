@@ -34,19 +34,18 @@ class AlexNet(ModelBase):
     def build_model(self) -> None:
         cd = self.config.get("compute_dtype", jnp.bfloat16)
         nc = self.config.get("n_class", self.n_class)
-        lrn_impl = self.config.get("lrn_impl", "band")
         self.seq = L.Sequential([
             # conv1: 96 kernels 11×11 stride 4, LRN, pool 3/2  (227→55→27)
             L.Conv(3, 96, 11, stride=4, padding="VALID",
                    w_init=("normal", 0.01), b_init=("constant", 0.0),
                    compute_dtype=cd, name="conv1"),
-            L.LRN(impl=lrn_impl, name="lrn1"),
+            L.LRN(name="lrn1"),
             L.Pool(3, 2, mode="max", name="pool1"),
             # conv2: 256 kernels 5×5 pad 2, 2 groups, LRN, pool  (27→13)
             L.Conv(96, 256, 5, padding=2, groups=2,
                    w_init=("normal", 0.01), b_init=("constant", 0.1),
                    compute_dtype=cd, name="conv2"),
-            L.LRN(impl=lrn_impl, name="lrn2"),
+            L.LRN(name="lrn2"),
             L.Pool(3, 2, mode="max", name="pool2"),
             # conv3/4/5  (13→13, pool→6)
             L.Conv(256, 384, 3, padding=1,

@@ -22,6 +22,7 @@ Design departures from the reference, all deliberate and TPU-first:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -294,33 +295,49 @@ class Pool(Layer):
         return s / counts
 
 
+@functools.lru_cache(maxsize=None)
+def _lrn_band(c: int, n: int) -> np.ndarray:
+    """[c, c] 0/1 matrix whose column i sums the n-channel window at i."""
+    half = n // 2
+    band = np.zeros((c, c), np.float32)
+    for i in range(c):
+        band[max(0, i - half):i + half + 1, i] = 1.0
+    return band
+
+
 class LRN(Layer):
     """Cross-channel local response normalization (AlexNet-era; reference
     layers2.LRN):  b = a / (k + alpha/n * sum_{window} a^2)^beta.
 
     TPU mapping: the 5-tap cross-channel sum runs as a 1×1 conv against a
-    constant banded matrix — the channel dim is the lane dim on TPU, where a
-    sliding ``reduce_window`` is slow, but a tiny matmul rides the MXU and its
-    gradient is the same (symmetric) band conv.  Measured ~1.9× faster
-    fwd+bwd than ``reduce_window`` at AlexNet's lrn1 shape, bit-accurate in
-    fp32.  For β=0.75 the power is composed from ``rsqrt``/``sqrt``
-    (d^-0.75 = rsqrt(d)·sqrt(rsqrt(d))) instead of a transcendental pow.
+    constant banded matrix on the input's native NHWC shape — the channel
+    dim is the lane dim on TPU, where a sliding ``reduce_window`` is slow,
+    but a tiny matmul rides the MXU and its gradient is the same
+    (symmetric) band conv.  Accumulation is fp32.  For β=0.75 the power is
+    composed from ``rsqrt``/``sqrt`` (d^-0.75 = rsqrt(d)·sqrt(rsqrt(d)))
+    instead of a transcendental pow.
     """
 
     def __init__(self, n: int = 5, k: float = 2.0, alpha: float = 1e-4,
-                 beta: float = 0.75, impl: str = "band", name: str = "lrn"):
+                 beta: float = 0.75, name: str = "lrn"):
         self.n, self.k, self.alpha, self.beta = n, k, alpha, beta
-        self.impl = impl      # 'band' (XLA conv, default) | 'pallas' (fused)
         self.name = name
 
     def apply(self, params, x, *, train=False, rng=None, state=None):
-        # both implementations live in ops.lrn (single source of the math;
-        # the Pallas kernel is equality-tested against lrn_jnp)
-        if self.impl == "pallas":
-            from ..ops.lrn import lrn as lrn_fused
-            return lrn_fused(x, self.n, self.k, self.alpha, self.beta)
-        from ..ops.lrn import lrn_jnp
-        return lrn_jnp(x, self.n, self.k, self.alpha, self.beta)
+        c = x.shape[-1]
+        x4 = x if x.ndim == 4 else x.reshape(1, -1, 1, c)
+        xf = x4.astype(jnp.float32)
+        ssum = jax.lax.conv_general_dilated(
+            jnp.square(xf),
+            jnp.asarray(_lrn_band(c, self.n)).reshape(1, 1, c, c),
+            (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        d = self.k + (self.alpha / self.n) * ssum
+        if self.beta == 0.75:
+            inv = jax.lax.rsqrt(d)
+            scale = inv * jnp.sqrt(inv)
+        else:
+            scale = jnp.exp(-self.beta * jnp.log(d))
+        return (xf * scale).astype(x.dtype).reshape(x.shape)
 
 
 class Dropout(Layer):

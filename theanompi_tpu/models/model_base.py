@@ -478,9 +478,10 @@ class ModelBase:
         # THEANOMPI_COMPILE_CACHE env var), every compile surface switches
         # from lazy first-call jit to explicit lower → get_or_compile —
         # a warm cache turns minutes of XLA compile into seconds of
-        # deserialize (wedge-recovery restarts, checkpoint resume, the
-        # prewarm-then-measure hardware-window workflow).  Unconfigured,
-        # behavior is the pre-cache lazy jit, bit for bit.
+        # deserialize (supervised restarts, checkpoint resume, an off-line
+        # prewarm).  Unconfigured — the default — behavior is the lazy
+        # jit, bit for bit.  With it on, a surface whose AOT build or
+        # deserialize fails falls back to its lazy jit and says so.
         self._aot_from_cache()
 
     # -- AOT executable cache ---------------------------------------------
@@ -601,10 +602,6 @@ class ModelBase:
         spc = int(self.steps_per_call if spc is None else spc)
         train_fn = steps.build_train_step(self.mesh, self, exchanger,
                                           n_steps=spc)
-        if not compile_cache.donated_load_safe(self.mesh):
-            # donation-free twin where deserialized aliased execution is
-            # untrusted (see compile_cache.donated_load_safe)
-            train_fn = jax.jit(train_fn.__wrapped__)
         lowered = train_fn.lower(*self._train_input_avals(spc, exchanger))
         return cache.get_or_compile(
             lowered, label=f"train:{type(self).__name__}:spc{spc}",
@@ -634,15 +631,6 @@ class ModelBase:
             return
         spc = int(self.steps_per_call)
         name = type(self).__name__
-        # donated programs are cached/loaded only where deserialized
-        # aliased execution is trusted (TPU); elsewhere a donation-free
-        # twin of the same program is cached — identical math, its own
-        # key (see compile_cache.donated_load_safe)
-        donate_ok = compile_cache.donated_load_safe(self.mesh)
-
-        def undonated(jit_fn):
-            return jit_fn if donate_ok else jax.jit(jit_fn.__wrapped__)
-
         def attempt(fn_name, build):
             try:
                 compiled, info = build()
@@ -693,7 +681,7 @@ class ModelBase:
             # steps (spc=1); fused runs carry the cadence inside the train
             # program and never call it on the hot path
             def build_exchange():
-                lowered = undonated(exch._exchange_fn).lower(
+                lowered = exch._exchange_fn.lower(
                     self._state_avals(),
                     jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
                     jax.ShapeDtypeStruct((), jnp.int32))

@@ -18,6 +18,7 @@ bit-identical (tested in ``tests/test_native_loader.py``).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,7 +28,9 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "loader.cc")
-_SO = os.path.join(_HERE, "_loader.so")
+# No -march=native: the chip tool copies the working tree to another
+# machine, and a library tuned to the build host's CPU may not run there.
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-pthread", "-std=c++17")
 
 _lock = threading.Lock()
 _lib = None
@@ -36,32 +39,39 @@ _lib_tried = False
 DEFAULT_THREADS = min(16, os.cpu_count() or 1)
 
 
+def _so_path() -> str:
+    """The built library's path, keyed on a hash of ``loader.cc`` and the
+    compiler flags: a ``.so`` built from other source or with other flags
+    has another name and is never picked up."""
+    h = hashlib.sha256(" ".join(_CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_HERE, f"_loader-{h.hexdigest()[:16]}.so")
+
+
 def _build() -> Optional[str]:
-    """Compile loader.cc → _loader.so if stale/absent. Returns path or None.
+    """Compile loader.cc → ``_loader-<hash>.so`` if absent. Returns the
+    path, or None when there is no compiler or the build fails.
 
     Compiles to a per-process temp name and installs with an atomic
     ``os.replace`` so concurrent first-use across processes (pytest-xdist, a
     multi-process host) can't interleave writes into one file — worst case
     both compile and the last install wins, both valid.
     """
-    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
-        if (os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return _SO
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-               _SRC, "-o", tmp]
-        # -march=native when the toolchain supports it (best-effort)
-        probe = subprocess.run(cmd[:1] + ["-march=native", "-E", "-x", "c++",
-                                          "-", "-o", os.devnull],
-                               input=b"", capture_output=True)
-        if probe.returncode == 0:
-            cmd.insert(1, "-march=native")
-        r = subprocess.run(cmd, capture_output=True)
+        so = _so_path()
+    except OSError:
+        return None
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        r = subprocess.run(["g++", *_CXXFLAGS, _SRC, "-o", tmp],
+                           capture_output=True)
         if r.returncode != 0:
             return None
-        os.replace(tmp, _SO)
-        return _SO
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError):
         return None
     finally:

@@ -1,4 +1,4 @@
-"""Compression kernels for the compressed exchanger (onebit + topk).
+"""Compression ops for the compressed exchanger (onebit kernels + topk).
 
 TPU-native successor to the reference's in-repo native code: Theano-MPI's
 ``Exch_asa16``/``Exch_copper16`` compiled inline fp32↔fp16 CUDA kernels at
@@ -22,11 +22,11 @@ single-pass pipelines (docs/design.md §24):
 * **onebit decode** (:func:`unpack_signs_weighted_mean`): the decode+weighted
   accumulate with the ``/size`` mean folded into the per-worker scales, so the
   full-length division pass disappears.
-* **topk encode/decode** (:func:`topk_encode` / :func:`topk_decode`): chunk-row
-  kernels fusing the |c| top-k select, bf16 value cast, int16 offset emit and
-  in-place residual write (encode), and the expansion of every worker's
-  (vals, idx) rows into the dense chunk row block-locally in VMEM (decode),
-  replacing the serialized HBM scatter XLA makes of ``.at[idx].add``.
+
+The topk wire (:func:`topk_encode` / :func:`topk_decode`) is plain jnp on
+every backend: its Pallas pair was deleted after its first compiled run on a
+v5e (ROADMAP S6 has the numbers) — the decode ran at half the speed of XLA's
+scatter and the encode no faster than ``lax.top_k``.
 
 Every ``pl.pallas_call`` wrapper here is paired with its jnp oracle in
 :data:`PALLAS_ORACLES`; the tpulint ``oracle-pair`` checker enforces the
@@ -142,10 +142,14 @@ def unpack_signs_weighted_mean_jnp(all_packed: jnp.ndarray,
     return unpack_signs_weighted_sum_jnp(all_packed, scales / jnp.float32(size))
 
 
-def topk_encode_jnp(c2: jnp.ndarray, k: int):
-    """Oracle for the fused topk encode: per chunk row of ``c2`` [rows, chunk]
-    select the k largest-|·| entries, cast to the wire dtypes, and write the
-    bf16 rounding residual back in place.
+# ---------------------------------------------------------------------------
+# topk wire (plain jnp on every backend)
+# ---------------------------------------------------------------------------
+
+def topk_encode(c2: jnp.ndarray, k: int):
+    """Topk encode: per chunk row of ``c2`` [rows, chunk] select the k
+    largest-|·| entries, cast to the wire dtypes, and write the bf16
+    rounding residual back in place.
 
     Returns ``(wire_vals bf16 [rows, k], wire_idx int16 [rows, k],
     new_c2 f32 [rows, chunk])``.  Tie-break follows ``lax.top_k``: equal
@@ -154,21 +158,27 @@ def topk_encode_jnp(c2: jnp.ndarray, k: int):
     rows = c2.shape[0]
     _, idx = jax.lax.top_k(jnp.abs(c2), k)                 # [rows, k]
     vals = jnp.take_along_axis(c2, idx, axis=1)            # f32 [rows, k]
-    wire_vals = vals.astype(jnp.bfloat16)
+    # Round to bf16 with reduce_precision, not an f32→bf16→f32 cast pair:
+    # on the TPU XLA keeps excess precision through such a pair, the
+    # residual came out exactly zero and the wire's rounding error never
+    # reached the error feedback (seen on a v5e, PR 21).
+    rounded = jax.lax.reduce_precision(vals, exponent_bits=8,
+                                       mantissa_bits=7)
+    wire_vals = rounded.astype(jnp.bfloat16)
     wire_idx = idx.astype(jnp.int16)
-    residual = vals - wire_vals.astype(jnp.float32)
+    residual = vals - rounded
     r = jnp.arange(rows)[:, None]
     new_c2 = c2.at[r, idx].set(residual)
     return wire_vals, wire_idx, new_c2
 
 
-def topk_decode_jnp(all_vals: jnp.ndarray, all_idx: jnp.ndarray,
-                    chunk: int, size: int = 1) -> jnp.ndarray:
-    """Oracle for the fused topk decode: expand every worker's (vals, idx)
-    chunk rows into the dense vector — dense[r·chunk + idx] += val summed
-    over workers, divided by ``size`` (the worker mean folded into the
-    decode so no full-length division pass follows; ``acc / size`` per
-    element is bit-identical to dividing the assembled dense vector).
+def topk_decode(all_vals: jnp.ndarray, all_idx: jnp.ndarray,
+                chunk: int, size: int = 1) -> jnp.ndarray:
+    """Topk decode: expand every worker's (vals, idx) chunk rows into the
+    dense vector — dense[r·chunk + idx] += val summed over workers, divided
+    by ``size`` (the worker mean folded into the decode so no full-length
+    division pass follows; ``acc / size`` per element is bit-identical to
+    dividing the assembled dense vector).
     [w, rows, k] bf16/int16 → f32 [rows·chunk]."""
     w, rows, k = all_vals.shape
     base = (jnp.arange(rows, dtype=jnp.int32) * chunk).reshape(1, rows, 1)
@@ -223,7 +233,10 @@ def _make_unpack_wsum_kernel(n_workers: int):
         for b in range(32):
             acc = jnp.zeros((_WORDS_PER_BLOCK, LANES), jnp.float32)
             for w in range(n_workers):
-                bits = (packed_ref[w] >> np.uint32(b)) & np.uint32(1)
+                # the 0/1 bit goes to int32 first: Mosaic has no
+                # uint32 → float32 cast
+                bits = ((packed_ref[w] >> np.uint32(b))
+                        & np.uint32(1)).astype(jnp.int32)
                 acc = acc + bits.astype(jnp.float32) * (2.0 * scales_ref[w])
             # Σ scale·(2·bit − 1) = Σ 2·scale·bit − Σ scale
             out_ref[8 * b:8 * (b + 1), :] = acc - total
@@ -325,97 +338,6 @@ def _residual_pallas(abs2d: jnp.ndarray, packed: jnp.ndarray,
     )(abs2d, packed, scale.reshape(1).astype(jnp.float32))
 
 
-def _make_topk_encode_kernel(k: int, chunk: int):
-    def kernel(c_ref, vals_ref, idx_ref, state_ref):
-        """One chunk row per grid step: iterative argmax over |row| (first
-        max index == lax.top_k's lower-index tie-break), emitting the bf16
-        wire value, int16 chunk-local offset, and the in-place bf16 rounding
-        residual — all from one VMEM-resident copy of the row."""
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-
-        def body(j, carry):
-            cur, amask = carry
-            m = jnp.max(amask)
-            # First lane attaining the max: ties pick the lowest index,
-            # matching lax.top_k's ordering in the oracle.
-            idx = jnp.min(jnp.where(amask == m, lanes, chunk))
-            v = jnp.sum(jnp.where(lanes == idx, cur, 0.0))
-            wv = v.astype(jnp.bfloat16)
-            pl.store(vals_ref, (0, pl.dslice(j, 1)), wv.reshape(1, 1))
-            pl.store(idx_ref, (0, pl.dslice(j, 1)),
-                     idx.astype(jnp.int16).reshape(1, 1))
-            hit = lanes == idx
-            cur = jnp.where(hit, v - wv.astype(jnp.float32), cur)
-            # Selected lanes leave the running argmax for good: |·| ≥ 0, so
-            # −1 can never win again (relying on the residual being small
-            # would diverge from top_k on all-zero rows).
-            amask = jnp.where(hit, jnp.float32(-1.0), amask)
-            return cur, amask
-
-        row = c_ref[:]
-        cur, _ = jax.lax.fori_loop(0, k, body, (row, jnp.abs(row)))
-        state_ref[:] = cur
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _topk_encode_pallas(c2: jnp.ndarray, k: int, interpret: bool):
-    rows, chunk = c2.shape
-    vma = _vma_of(c2)
-    row_spec = lambda shape: pl.BlockSpec((1, shape), lambda j: (j, 0),
-                                          memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        _make_topk_encode_kernel(k, chunk),
-        grid=(rows,),
-        in_specs=[row_spec(chunk)],
-        out_specs=[row_spec(k), row_spec(k), row_spec(chunk)],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, vma=vma),
-            jax.ShapeDtypeStruct((rows, k), jnp.int16, vma=vma),
-            jax.ShapeDtypeStruct((rows, chunk), jnp.float32, vma=vma),
-        ],
-        interpret=interpret,
-    )(c2)
-
-
-def _make_topk_decode_kernel(n_workers: int, k: int, chunk: int, size: int):
-    def kernel(vals_ref, idx_ref, out_ref):
-        """All workers' (vals, idx) for one chunk row → the dense row,
-        accumulated block-locally in VMEM in (worker asc, slot asc) order —
-        the same per-element order as the flattened ``.at[gidx].add`` scatter
-        the oracle performs, with no serialized HBM scatter anywhere.  The
-        ``/size`` worker mean rides the final store."""
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-        acc = jnp.zeros((1, chunk), jnp.float32)
-        for w in range(n_workers):
-            def body(j, acc, w=w):
-                v = pl.load(vals_ref, (w, 0, pl.dslice(j, 1)))
-                i = pl.load(idx_ref, (w, 0, pl.dslice(j, 1)))
-                hit = lanes == i.astype(jnp.int32).reshape(1, 1)
-                return acc + jnp.where(hit, v.astype(jnp.float32), 0.0)
-            acc = jax.lax.fori_loop(0, k, body, acc)
-        out_ref[:] = acc / jnp.float32(size) if size != 1 else acc
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "size", "interpret"))
-def _topk_decode_pallas(all_vals: jnp.ndarray, all_idx: jnp.ndarray,
-                        chunk: int, size: int, interpret: bool) -> jnp.ndarray:
-    w, rows, k = all_vals.shape
-    wire_spec = pl.BlockSpec((w, 1, k), lambda j: (0, j, 0),
-                             memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        _make_topk_decode_kernel(w, k, chunk, size),
-        grid=(rows,),
-        in_specs=[wire_spec, wire_spec],
-        out_specs=pl.BlockSpec((1, chunk), lambda j: (j, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, chunk), jnp.float32,
-                                       vma=_vma_of(all_vals, all_idx)),
-        interpret=interpret,
-    )(all_vals, all_idx)
-
-
 # ---------------------------------------------------------------------------
 # Public API (dispatching)
 # ---------------------------------------------------------------------------
@@ -492,26 +414,6 @@ def unpack_signs_weighted_mean(all_packed: jnp.ndarray, scales: jnp.ndarray,
     return _unpack_wsum_pallas(all_packed, ws, False).reshape(-1)
 
 
-def topk_encode(c2: jnp.ndarray, k: int):
-    """Fused topk encode of ``c2`` [rows, chunk]: per chunk row, the k
-    largest-|·| entries as ``(bf16 vals, int16 offsets)`` plus the new error
-    state with the bf16 rounding residual written in place."""
-    if not _dispatch_pallas():
-        return topk_encode_jnp(c2, k)
-    return _topk_encode_pallas(c2, k, False)
-
-
-def topk_decode(all_vals: jnp.ndarray, all_idx: jnp.ndarray,
-                chunk: int, size: int = 1) -> jnp.ndarray:
-    """Fused topk decode: all workers' ``[w, rows, k]`` wire rows expanded
-    and summed into the dense f32 ``[rows·chunk]`` vector block-locally (no
-    serialized HBM scatter), with the ``/size`` worker mean folded in."""
-    if not _dispatch_pallas():
-        return topk_decode_jnp(all_vals, all_idx, chunk, size)
-    return _topk_decode_pallas(all_vals, all_idx, chunk, size,
-                               False).reshape(-1)
-
-
 # pallas_call wrapper → jnp oracle pairing, enforced by the tpulint
 # ``oracle-pair`` checker (every wrapper must appear here, every oracle must
 # be defined in this module, and a test must reference both).
@@ -520,6 +422,4 @@ PALLAS_ORACLES = {
     "_unpack_wsum_pallas": "unpack_signs_weighted_sum_jnp",
     "_encode_pallas": "pack_signs_encode_jnp",
     "_residual_pallas": "signed_residual_jnp",
-    "_topk_encode_pallas": "topk_encode_jnp",
-    "_topk_decode_pallas": "topk_decode_jnp",
 }

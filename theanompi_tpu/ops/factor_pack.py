@@ -31,9 +31,13 @@ from ._pallas_util import vma_of as _vma_of
 # Factor tiles are padded to the fp32 sublane multiple so every slice of the
 # concatenated staging buffer stays tile-aligned.
 _SUBLANE = 8
-# Grid block over the output rows; the contraction dim rides whole in VMEM
-# (PowerSGD leaves have cols ≤ a few thousand — far under the VMEM budget).
+# Grid blocks: ROW_BLOCK output rows by K_BLOCK of the contraction dim, so the
+# double-buffered tiles (2 MiB of ``m``, 1 MiB of lane-padded ``q``) stay far
+# inside the 16 MiB scoped-VMEM limit whatever the leaf.  The Q-side pass
+# contracts over a leaf's ROWS (9,216 for AlexNet's fc6, 25,088 for
+# VGG-16's): riding that dim whole in VMEM does not compile on a v5e.
 ROW_BLOCK = 256
+K_BLOCK = 2048
 
 
 def pad_rows(rows: int) -> int:
@@ -49,16 +53,37 @@ def matmul_pack_jnp(m: jnp.ndarray, q: jnp.ndarray,
     return jnp.pad(p, ((0, rows_pad - p.shape[0]), (0, 0)))
 
 
-def _make_matmul_pack_kernel(rows: int, block_rows: int):
+def _make_matmul_pack_kernel(rows: int, cols: int, block_rows: int,
+                             block_k: int):
+    n_k = -(-cols // block_k)
+
     def kernel(m_ref, q_ref, out_ref):
-        """(block, cols) f32 × (cols, rank) f32 → (block, rank) f32 staging
-        tile, rows ≥ the true row count zeroed so the downstream psum of the
+        """(block, bk) f32 × (bk, rank) f32 accumulated over the K grid axis
+        into the (block, rank) f32 staging tile; rows ≥ the true row count
+        are zeroed on the last K step so the downstream psum of the
         concatenated staging buffer matches the per-leaf psums exactly."""
-        j = pl.program_id(0)
-        p = jnp.dot(m_ref[:], q_ref[:], preferred_element_type=jnp.float32)
-        rid = j * block_rows + jax.lax.broadcasted_iota(
-            jnp.int32, (block_rows, 1), 0)
-        out_ref[:] = jnp.where(rid < rows, p, 0.0)
+        j, kk = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(kk == 0)
+        def _():
+            out_ref[:] = jnp.zeros_like(out_ref)
+
+        m, q = m_ref[:], q_ref[:]
+        if cols % block_k:
+            # the last K block reads past the operands: whatever lies there
+            # must not reach the sum
+            k0 = kk * block_k
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+            m = jnp.where(k0 + lane < cols, m, 0.0)
+            q = jnp.where(k0 + row < cols, q, 0.0)
+        out_ref[:] += jnp.dot(m, q, preferred_element_type=jnp.float32)
+
+        @pl.when(kk == n_k - 1)
+        def _():
+            rid = j * block_rows + jax.lax.broadcasted_iota(
+                jnp.int32, (block_rows, 1), 0)
+            out_ref[:] = jnp.where(rid < rows, out_ref[:], 0.0)
     return kernel
 
 
@@ -68,20 +93,22 @@ def _matmul_pack_pallas(m: jnp.ndarray, q: jnp.ndarray, rows_pad: int,
     rows, cols = m.shape
     rank = q.shape[1]
     block = min(ROW_BLOCK, rows_pad)
-    nb = -(-rows_pad // block)
+    block_k = cols if cols <= K_BLOCK else K_BLOCK
     return pl.pallas_call(
-        _make_matmul_pack_kernel(rows, block),
-        grid=(nb,),
+        _make_matmul_pack_kernel(rows, cols, block, block_k),
+        grid=(pl.cdiv(rows_pad, block), pl.cdiv(cols, block_k)),
         in_specs=[
-            pl.BlockSpec((block, cols), lambda j: (j, 0),
+            pl.BlockSpec((block, block_k), lambda j, k: (j, k),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((cols, rank), lambda j: (0, 0),
+            pl.BlockSpec((block_k, rank), lambda j, k: (k, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((block, rank), lambda j: (j, 0),
+        out_specs=pl.BlockSpec((block, rank), lambda j, k: (j, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows_pad, rank), jnp.float32,
                                        vma=_vma_of(m, q)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(m, q)
 

@@ -29,6 +29,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..jax_compat import shard_map
+from ..jax_compat import vary as _vary
 from ..utils import numerics
 from .mesh import WORKER_AXIS, batch_sharding, worker_local_sharding
 
@@ -179,57 +180,6 @@ def local_param_template(params, pspecs, mesh: Mesh):
 # ---------------------------------------------------------------------------
 # microbatch gradient accumulation (reference: n_subb sub-batches, §3.4)
 # ---------------------------------------------------------------------------
-
-def _vary(x, axis: str):
-    """Mark a replicated value as device-varying for shard_map's vma type
-    system (scan carries that accumulate per-worker values need this).
-    Idempotent: an already-varying value passes through — pcast raises on
-    varying→varying, and callers like _revary_bn see either (the async
-    rules' sync_bn is the identity, so their BN stats arrive varying;
-    BSP's pmean'd stats arrive invariant).
-
-    Version-robust across the jax API churn around the vma system
-    (round-5 ADVICE): ``jax.typeof`` may be absent while ``lax.pcast``
-    exists — the varying→varying pcast then fails with whatever error
-    that version raises, so the failure is caught BROADLY and falls back
-    to ``lax.pvary``.  A failure is masked only when the value cannot be
-    proven non-varying (no typeof to consult): when typeof CAN prove the
-    value was not already varying, the error is genuine misuse (wrong
-    axis name, outside shard_map) and re-raises at the call site.  On
-    versions predating the vma system entirely (no pcast, no pvary —
-    e.g. 0.4.x, where shard_map tracks replication via check_rep
-    instead) the marker is a no-op by construction."""
-    typeof = getattr(jax, "typeof", None)
-
-    def already_varying():
-        """True/False when typeof can answer, None when it can't."""
-        if typeof is None:
-            return None
-        try:
-            vma = getattr(typeof(x), "vma", None)
-            return None if vma is None else (axis in vma)
-        except Exception:
-            return None
-
-    if already_varying():
-        return x
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        try:
-            return pcast(x, (axis,), to="varying")
-        except Exception:      # varying→varying, or signature drift
-            if already_varying() is False:
-                raise          # provably NOT varying — genuine misuse
-    pvary = getattr(lax, "pvary", None)
-    if pvary is not None:
-        try:
-            return pvary(x, (axis,))
-        except Exception:      # already varying on a pvary that checks
-            if already_varying() is False:
-                raise
-            return x
-    return x
-
 
 def _revary_bn(bn_state, axis: str):
     """Re-mark synced BN stats as worker-varying.  ``sync_bn``'s pmean

@@ -938,12 +938,9 @@ def compress_traffic_model(strategy: str, n_elems: int, n_workers: int, *,
             ("serialized HBM scatter-add", 2 * fn + w * wire),
             ("div /size -> mean", 2 * fn),
         ]
-        fused_enc = [
-            ("topk_encode kernel", fn + fn + 2 * wire),
-        ]
-        fused_dec = [
-            ("topk_decode kernel (VMEM expand + /size)", w * wire + fn),
-        ]
+        # no fused topk pipeline: its kernel pair was deleted after losing
+        # to this op graph on the chip (PR 21, ROADMAP S6) — shrink 1.0
+        fused_enc, fused_dec = legacy_enc, legacy_dec
     elif strategy.startswith("powersgd"):
         r = rank
         shapes = [s for s in (leaf_shapes or [])

@@ -120,7 +120,7 @@ class StallWatchdog:
               f"Dumping all thread stacks:", file=sys.stderr, flush=True)
         faulthandler.dump_traceback(file=sys.stderr)
         # the last few flight-recorder events inline: what the rank was
-        # doing when it hung — a tunnel-window stall is then diagnosable
+        # doing when it hung — a stall is then diagnosable
         # from the console log alone, no record_dir needed
         tail = telemetry.active().tail(8)
         if tail:

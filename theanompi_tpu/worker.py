@@ -364,7 +364,7 @@ class Worker(MeshProcess):
     def _log_compile_cache(self, model) -> None:
         """Startup line for the AOT executable cache (utils/compile_cache):
         per-program hit/miss + wall time, and the process counters — the
-        at-a-glance evidence that a wedge-recovery restart or checkpoint
+        at-a-glance evidence that a supervised restart or checkpoint
         resume deserialized instead of recompiling."""
         if not self.verbose:
             return
@@ -427,6 +427,13 @@ def main(argv=None):
                 config[k] = float(v)
             except ValueError:
                 config[k] = {"true": True, "false": False}.get(v.lower(), v)
+    # JAX's persistent compilation cache, at a fixed place (the launcher's
+    # in-process path lands here too; the session API leaves it to its host
+    # application, and a process pinned to the CPU — by `platform=cpu` here,
+    # by JAX_PLATFORMS inside configure — is left without one)
+    if config.get("platform") != "cpu":
+        from .utils import jax_cache
+        jax_cache.configure()
     worker = WORKERS[rule](config)
     model = worker.build_model(modelfile, modelclass)
     worker.run(model)
