@@ -62,7 +62,7 @@ def main(argv=None):
     from theanompi_tpu.parallel.exchanger import get_exchanger
     from theanompi_tpu.parallel.mesh import WORKER_AXIS, worker_mesh
     from theanompi_tpu.parallel import steps
-    from theanompi_tpu.utils import devprof
+    from theanompi_tpu.utils import devprof, telemetry
 
     jax.config.update("jax_default_prng_impl", "rbg")
     overrides = json.loads(args.cfg) if args.cfg else {}
@@ -94,7 +94,7 @@ def main(argv=None):
         # convention: the fused in-scan exchange cadence fires at its true
         # rate (a 0-based count would run steps down to count0 < 0 and
         # fire a step-0 exchange no real run issues)
-        with jax.profiler.TraceAnnotation(devprof.TRAIN_DISPATCH_SPAN):
+        with telemetry.span(devprof.TRAIN_DISPATCH_SPAN):   # counted by devprof
             model.step_state, cost, err = model.train_fn(
                 model.step_state, dev_batch, lr, rng,
                 jnp.int32((i + 1) * spc))

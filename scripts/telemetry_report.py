@@ -560,7 +560,27 @@ def build_trace(events):
                   1 if e.get("name") in ("round", "exchange") else 2)
                  for e in span_evs}
 
+    # ring spans (phase events with a `tid`, utils/telemetry.span) from
+    # threads other than the recorder's — the loader's producer and pool —
+    # get tracks of their own (tid 10, 11, ...): overlapping spans on one
+    # track do not render
+    main_tid = {}
+    for e in events:
+        if e.get("ev") == "phase" and "tid" in e and \
+                e.get("sec") in ("compile", "train", "val"):
+            main_tid.setdefault(int(e.get("rank", 0)), e["tid"])
+    host_tids = {}
+    for e in events:
+        if e.get("ev") == "phase" and "tid" in e:
+            r = int(e.get("rank", 0))
+            if e["tid"] != main_tid.get(r, e["tid"]):
+                host_tids.setdefault((r, e["tid"]),
+                                     10 + sum(k[0] == r for k in host_tids))
+
     meta, body = [], []
+    for (r, _), tid in sorted(host_tids.items(), key=lambda kv: kv[1]):
+        meta.append({"ph": "M", "pid": r, "tid": tid, "name": "thread_name",
+                     "args": {"name": f"host thread {tid - 9}"}})
     for r in ranks:
         meta.append({"ph": "M", "pid": r, "name": "process_name",
                      "args": {"name": "center" if r < 0 else f"rank {r}"}})
@@ -578,9 +598,12 @@ def build_trace(events):
         rank = int(ev.get("rank", 0))
         if kind == "phase":
             dur = max(0.0, float(ev.get("dt", 0.0))) * 1e6
-            end = us(ev["ts"])
+            # a ring span carries its own start (Unix ns); a bare bracket
+            # is stamped at its end
+            end = us(ev["t0"] / 1e9) + dur if "t0" in ev else us(ev["ts"])
             start = max(0.0, end - dur)
-            body.append({"ph": "X", "pid": rank, "tid": 0,
+            body.append({"ph": "X", "pid": rank,
+                         "tid": host_tids.get((rank, ev.get("tid")), 0),
                          "ts": round(start, 1),
                          "dur": round(end - start, 1),
                          "name": str(ev.get("sec", "?")), "cat": "phase"})
@@ -762,10 +785,10 @@ def print_report(rep):
           f"ranks: {rep['ranks']}   events: {rep['events']}")
     if rep["phases"]:
         print("\nphase breakdown (seconds per dispatch):")
-        print(f"  {'phase':<9}{'count':>7}{'total':>10}{'mean':>10}"
+        print(f"  {'phase':<18}{'count':>7}{'total':>10}{'mean':>10}"
               f"{'p50':>10}{'p95':>10}{'p99':>10}")
         for sec, p in rep["phases"].items():
-            print(f"  {sec:<9}{p['count']:>7}{p['total']:>10.3f}"
+            print(f"  {sec:<18}{p['count']:>7}{p['total']:>10.3f}"
                   f"{p['mean']:>10.5f}{p['p50']:>10.5f}{p['p95']:>10.5f}"
                   f"{p['p99']:>10.5f}")
     if rep["straggler_ranking"]:

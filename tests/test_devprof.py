@@ -3,7 +3,6 @@ and interval math, the live capture→attribution round-trip on a psum
 program, the training sentry, the Perfetto trace export, and the
 compile-cache cost manifests + explain CLI."""
 
-import gzip
 import json
 import os
 import subprocess
@@ -149,19 +148,23 @@ def test_attribute_no_comm_yields_none_ratio_and_module_split():
 
 
 def test_dispatch_anchors_counted_host_junk_ignored():
+    """Dispatches are counted off the span ring's rows of the capture
+    interval (``train.call``, ``exchange``), not off host events in the
+    trace: a chip capture holds none."""
+    rows = [("train", 9, 0, 9, None, 1),
+            (devprof.TRAIN_DISPATCH_SPAN, 9, 0, 5, "train", 1),
+            (devprof.TRAIN_DISPATCH_SPAN, 9, 6, 11, "train", 2),
+            (devprof.EXCHANGE_SPAN, 9, 12, 14, None, None),
+            ("input.materialize", 7, 0, 99, None, 3)]
     prof = devprof.attribute([
-        {"ph": "X", "pid": 9, "tid": 9, "ts": 0, "dur": 5,
-         "name": devprof.TRAIN_DISPATCH_SPAN},
-        {"ph": "X", "pid": 9, "tid": 9, "ts": 6, "dur": 5,
-         "name": devprof.TRAIN_DISPATCH_SPAN},
-        {"ph": "X", "pid": 9, "tid": 9, "ts": 12, "dur": 2,
-         "name": devprof.EXCHANGE_SPAN},
         {"ph": "X", "pid": 9, "tid": 9, "ts": 0, "dur": 99,
          "name": "$builtins isinstance"},        # host python span: ignored
         {"ph": "M", "pid": 9, "name": "process_name",
          "args": {"name": "x"}},
         _op(0, 10, "fusion.1"),
-    ])
+    ], rows)
+    assert (devprof.TRAIN_DISPATCH_SPAN, devprof.EXCHANGE_SPAN) == \
+        ("train.call", "exchange")
     assert prof["train_dispatches"] == 2
     assert prof["exchange_dispatches"] == 1
     assert prof["n_op_events"] == 1
@@ -180,8 +183,8 @@ def test_profile_dir_empty_and_truncated(tmp_path):
     assert devprof.profile_dir(str(tmp_path)) is None
     sess = tmp_path / "plugins" / "profile" / "2026_01_01"
     sess.mkdir(parents=True)
-    with gzip.open(sess / "host.trace.json.gz", "wt") as f:
-        f.write('{"traceEvents": [')          # truncated capture
+    with open(sess / "host.xplane.pb", "wb") as f:
+        f.write(b"\x0a\xff\x7f/device:TPU:0")   # truncated capture
     assert devprof.profile_dir(str(tmp_path)) is None
 
 

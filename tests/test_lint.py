@@ -501,6 +501,39 @@ def test_telemetry_hot_path_good_fixture(tmp_path):
                         "telemetry-hot-path") == []
 
 
+TELEMETRY_RING = """
+from theanompi_tpu.utils import telemetry
+
+def hot_loop(n, q):
+    tm = telemetry.active()
+    for i in range(n):
+        with telemetry.span("load.dequeue") as sp:
+            sp.batch = q.get()
+        telemetry.count("input.dequeues")
+        %s
+"""
+
+
+def test_telemetry_hot_path_ring_needs_no_guard_registry_still_does(tmp_path):
+    """PR 25: ``telemetry.span``/``count`` are always on by contract (a
+    bounded cost, not a guard); a registry call beside them is still a
+    finding unless it sits under ``.enabled``."""
+    from theanompi_tpu.analysis.checkers import telemetry_hot_path as thp
+    assert {"span", "count"} <= thp.ALWAYS_ON
+    assert not thp.ALWAYS_ON & thp.RECORDING
+    assert lint_snippet(tmp_path, "prefetch.py", TELEMETRY_RING % "pass",
+                        "telemetry-hot-path") == []
+    found = lint_snippet(tmp_path, "prefetch.py",
+                         TELEMETRY_RING % 'tm.counter("prefetch.dequeues")',
+                         "telemetry-hot-path")
+    assert len(found) == 1
+    assert "unguarded telemetry call `tm.counter" in found[0].message
+    found = lint_snippet(tmp_path, "prefetch.py",
+                         TELEMETRY_RING % 'telemetry.active().event("x")',
+                         "telemetry-hot-path")
+    assert len(found) == 0 or "event" in found[0].message
+
+
 TRACING_BAD = """
 from theanompi_tpu.utils import tracing
 
@@ -673,6 +706,41 @@ def test_schema_drift_good_live_modules():
     """The real modules must be in sync (this IS the absorbed guard)."""
     from theanompi_tpu.utils import recorder, telemetry
     assert sd.live_drift_errors(recorder, telemetry) == []
+
+
+def test_schema_drift_span_vocabulary_good_and_bad():
+    """PR 25: the span ring's vocabulary is guarded in both directions."""
+    from theanompi_tpu.utils import telemetry
+    assert sd.span_vocabulary_errors(telemetry) == []
+    site = ("from ..utils import telemetry\n"
+            "def f(q, name):\n"
+            "    with telemetry.span('load.dequeue'):\n"
+            "        pass\n"
+            "    with telemetry.span('made.up'):\n"
+            "        pass\n"
+            "    telemetry.count('input.dequeues')\n"
+            "    telemetry.count(name)\n")
+    errors = sd.span_vocabulary_errors(telemetry, sources={"x.py": site})
+    msgs = [m for _, m in errors]
+    assert any("'made.up') is not in telemetry.SPANS" in m for m in msgs)
+    assert any("needs a literal name" in m for m in msgs)
+    # every declared name without a site in these sources is reported too
+    assert any("lists 'train.call' but no telemetry.span" in m
+               for m in msgs)
+    assert any("lists 'input.bytes_put' but no telemetry.count" in m
+               for m in msgs)
+    # compile.* spans are written by the monitoring listener, not a site
+    assert not any("'compile.xla'" in m for m in msgs)
+
+    class Clash:
+        PHASES = telemetry.PHASES
+        SPANS = telemetry.SPANS + ("train", "bogus.head")
+        COUNTS = telemetry.COUNTS
+        COMPILE_EVENTS = telemetry.COMPILE_EVENTS
+
+    msgs = [m for _, m in sd.span_vocabulary_errors(Clash)]
+    assert any("repeats recorder phases ['train']" in m for m in msgs)
+    assert any("'bogus.head'" in m and "neither a phase" in m for m in msgs)
 
 
 def test_schema_drift_bad_fixture(monkeypatch):
@@ -2241,11 +2309,11 @@ def test_injection_unguarded_producer_write_in_prefetch(tmp_path):
         tmp_path, "theanompi_tpu/models/data/prefetch.py",
         "                cursor = self._data.get_cursor() \\\n"
         "                    if hasattr(self._data, \"get_cursor\") else {}\n"
-        "                if tm.enabled:\n",
+        "                if stop.is_set():     # restart raced the load",
         "                cursor = self._data.get_cursor() \\\n"
         "                    if hasattr(self._data, \"get_cursor\") else {}\n"
         "                self._consumed_cursor = cursor\n"
-        "                if tm.enabled:\n")
+        "                if stop.is_set():     # restart raced the load")
     proc = _lint_cli(tmp_path, rel, "--check-baseline")
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert "shared-state-race" in proc.stdout

@@ -207,7 +207,7 @@ def test_prefetch_exports_queue_depth_and_producer_gauges():
         data.next_train_batch(i)
     assert tm.counters["prefetch.dequeues"] == 8
     assert tm.hists["prefetch.queue_depth"].count == 8
-    assert tm.hists["prefetch.produce_secs"].count >= 1
+    assert tm.hists["phase.input.materialize"].count >= 1
     assert "prefetch.queue_depth" in tm.gauges
     # a consumer outrunning the producer leaves starved dequeues behind
     assert tm.counters.get("prefetch.starved_dequeues", 0) <= 8

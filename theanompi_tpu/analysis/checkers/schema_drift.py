@@ -26,6 +26,11 @@ wire-contract/retry-safety checkers rest on is pinned to the runtime
 surface, so an extraction rule going stale fails the gate instead of
 silently blinding the protocol pass.
 
+PR 25 adds the always-on span ring's vocabulary (``telemetry.SPANS`` /
+``telemetry.COUNTS``, docs/design.md §11): every ``telemetry.span`` /
+``telemetry.count`` site in the package names a declared literal, and
+every declared name has a site (:func:`span_vocabulary_errors`).
+
 Unlike the AST checkers this is a PROJECT-level probe against LIVE
 objects (a Recorder driven through one print, a Telemetry instance fed
 one bracket per phase, a sentry pushed into an anomaly), so a
@@ -58,6 +63,10 @@ SENTRY_PATH = "theanompi_tpu/utils/sentry.py"
 REPORT_PATH = "scripts/telemetry_report.py"
 CHAOS_PATH = "theanompi_tpu/utils/chaos.py"
 NUMERICS_PATH = "theanompi_tpu/utils/numerics.py"
+# where the always-on ring's spans are written (span_vocabulary_errors)
+SPAN_SITE_PATHS = ("theanompi_tpu/models/data/prefetch.py",
+                   "theanompi_tpu/models/model_base.py",
+                   "theanompi_tpu/parallel/exchanger.py", RECORDER_PATH)
 
 # one lane, one module: a compute span [0,50]us and a comm span [40,60]us
 # → compute 50us, comm 20us, exposed 10us, overlap 0.5 — a COMPLETE
@@ -122,6 +131,82 @@ def live_drift_errors(recorder, telemetry) -> List[tuple]:
         errors.append((TELEMETRY_PATH,
                        f"telemetry phase histograms {sorted(got_hists)} "
                        "drifted from PHASES"))
+    return errors
+
+
+def span_vocabulary_errors(telemetry, root: Optional[str] = None,
+                           sources: Optional[dict] = None) -> List[tuple]:
+    """The always-on span ring's vocabulary, guarded like ``PHASES``: every
+    ``telemetry.span("...")`` / ``telemetry.count("...")`` literal in the
+    package must be in ``telemetry.SPANS`` / ``telemetry.COUNTS``, every
+    listed name must have a site (``compile.*`` spans are written by the
+    ``jax.monitoring`` listener, named in ``COMPILE_EVENTS``), no span is
+    called like a recorder phase, and a dotted name's head is a phase or
+    ``input`` (the loader's threads).  ``sources`` ({path: text}) stands
+    in for the package's files in tests."""
+    import ast as _ast
+    errors: List[tuple] = []
+    declared = {"span": set(telemetry.SPANS), "count": set(telemetry.COUNTS)}
+    clash = declared["span"] & set(telemetry.PHASES)
+    if clash:
+        errors.append((TELEMETRY_PATH,
+                       f"telemetry.SPANS repeats recorder phases "
+                       f"{sorted(clash)}: Recorder.end writes those rows"))
+    heads = set(telemetry.PHASES) | {"input"}
+    for name in sorted(declared["span"] | declared["count"]):
+        if "." in name and name.split(".", 1)[0] not in heads:
+            errors.append((TELEMETRY_PATH,
+                           f"span/counter {name!r}: the part before the "
+                           f"dot is neither a phase nor 'input'"))
+    given = sources is not None
+    if not given:
+        if root is None:
+            root = os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__)))))
+        sources = {}
+        for dirpath, _dirs, names in os.walk(
+                os.path.join(root, "theanompi_tpu")):
+            for n in names:
+                if n.endswith(".py"):
+                    full = os.path.join(dirpath, n)
+                    with open(full, encoding="utf-8") as f:
+                        sources[os.path.relpath(full, root)] = f.read()
+    used = {"span": set(), "count": set()}
+    for path, text in sources.items():
+        if "telemetry.span(" not in text and "telemetry.count(" not in text:
+            continue
+        try:
+            tree = _ast.parse(text)
+        except SyntaxError:
+            continue               # the parse step reports it already
+        for node in _ast.walk(tree):
+            if not (isinstance(node, _ast.Call)
+                    and isinstance(node.func, _ast.Attribute)
+                    and node.func.attr in used
+                    and isinstance(node.func.value, _ast.Name)
+                    and node.func.value.id == "telemetry"):
+                continue
+            arg = node.args[0] if node.args else None
+            if not (isinstance(arg, _ast.Constant)
+                    and isinstance(arg.value, str)):
+                errors.append((path, f"line {node.lineno}: telemetry."
+                               f"{node.func.attr}() needs a literal name "
+                               f"from the declared vocabulary"))
+                continue
+            used[node.func.attr].add(arg.value)
+            if arg.value not in declared[node.func.attr]:
+                errors.append((path, f"line {node.lineno}: telemetry."
+                               f"{node.func.attr}({arg.value!r}) is not in "
+                               f"telemetry.{node.func.attr.upper()}S"))
+    used["span"] |= set(telemetry.COMPILE_EVENTS.values())
+    if not (given or all(p in sources for p in SPAN_SITE_PATHS)):
+        return errors      # a partial tree (precommit_lint.sh): the sites'
+                           # files are not in it, so none can be missing
+    for kind in ("span", "count"):
+        for name in sorted(declared[kind] - used[kind]):
+            errors.append((TELEMETRY_PATH,
+                           f"telemetry.{kind.upper()}S lists {name!r} but "
+                           f"no telemetry.{kind}({name!r}) site exists"))
     return errors
 
 
@@ -1121,6 +1206,9 @@ class SchemaDriftChecker(Checker):
         # package __init__)
         from theanompi_tpu.utils import recorder, telemetry
         errors = live_drift_errors(recorder, telemetry)
+        # round 25 (PR 25): the always-on span ring's vocabulary against
+        # every telemetry.span/count site in the package
+        errors += span_vocabulary_errors(telemetry)
         try:
             # absent from a partial tree (precommit_lint.sh lints staged
             # blobs — a restricted checkout may omit them): the device

@@ -29,6 +29,15 @@ module-level ``tracing.emit_wire_span``/``emit_server_span`` one-shot
 emitters are recording calls — an unguarded hot-path span is a lint
 finding.  The hot set grows the wire/center/island files the span API
 rides through.
+
+The always-on span ring (``telemetry.span`` / ``telemetry.count`` and the
+readers beside them, :data:`ALWAYS_ON`) is the one sanctioned exception:
+its contract is a bounded cost per call (about a microsecond, no lock, no
+I/O), not a guard, because the benchmark's metrics and a ``trace_dir``
+capture read it whatever the configuration says.  Those module-level
+calls need no ``.enabled`` check — and only those: the registry's
+``counter``/``gauge``/``observe``/``event`` still do, including when they
+stand right next to a span.
 """
 
 from __future__ import annotations
@@ -57,6 +66,14 @@ RECORDING = {"counter", "gauge", "observe", "phase", "event",
              "system_snapshot", "dump_flight", "tail", "summary", "close",
              "begin", "emit_wire_span", "emit_server_span", "emit_alert",
              "record"}
+
+# the always-on ring's module-level surface (utils/telemetry.py): never a
+# finding, guarded or not.  Kept disjoint from RECORDING so that adding a
+# name to one cannot silently exempt or flag the other.
+ALWAYS_ON = {"span", "count", "spans", "totals", "on_trace_clock",
+             "watch_compiles", "open_bracket", "close_bracket",
+             "drop_bracket"}
+assert not ALWAYS_ON & RECORDING
 
 HANDLE_SOURCES = {TELEMETRY_MODULE + ".active", TELEMETRY_MODULE + ".init",
                   TRACING_MODULE + ".active", TRACING_MODULE + ".init"}

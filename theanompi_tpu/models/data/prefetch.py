@@ -21,12 +21,18 @@ wrapper exposes the same duck-typed surface (``next_train_batch``,
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
-import time
 from typing import Optional
 
 from ...utils import telemetry
+
+
+def _nbytes(batch) -> int:
+    """Host bytes of a batch's leaves (a dict of arrays)."""
+    leaves = batch.values() if hasattr(batch, "values") else ()
+    return sum(int(getattr(v, "nbytes", 0)) for v in leaves)
 
 
 def _stack_host(batches):
@@ -52,7 +58,19 @@ class PrefetchLoader:
     ``set_window(k, stage_fn)`` (``steps_per_call`` > 1): production goes
     WINDOW-granular — the queue holds whole ``[k, ...]`` dispatch inputs,
     staged to the mesh by the producer, consumed via
-    ``next_train_window`` (docs/design.md §9)."""
+    ``next_train_window`` (docs/design.md §9).
+
+    Every producer writes the same spans into ``telemetry``'s always-on
+    ring — ``input.plan`` and ``input.enqueue`` (the blocking ``q.put``) on
+    the producer thread, ``input.materialize`` and ``input.device_put`` on
+    whichever thread does the work (the pool's, when there is one) — and
+    the consumer writes ``load.dequeue`` / ``load.result``.  All carry the
+    queue item's id (``last_batch_id`` after a dequeue), so one batch can
+    be followed from its plan to the step that trains on it.
+    ``input.device_put`` times the host call only: ``jax.device_put``
+    returns before PJRT's threads have transposed and transferred the
+    batch; whether they had finished by the time the batch was dequeued is
+    the counter ``input.unready_dequeues`` (``is_ready()``, non-blocking)."""
 
     def __init__(self, data, depth: int = 2, device_put_fn=None,
                  n_workers: int = 1):
@@ -69,6 +87,10 @@ class PrefetchLoader:
         # revive it against the new queue / shared data object)
         self._stop: Optional[threading.Event] = None
         self._consumed_cursor: dict = {}
+        # ids of queue items, unique across epochs and restarts; the id of
+        # the item dequeued last is what train_iter stamps on its spans
+        self._ids = itertools.count(1)
+        self.last_batch_id: Optional[int] = None
 
     def set_window(self, k: int, stage_fn=None) -> None:
         """Switch to WINDOW-granular production (``steps_per_call`` > 1):
@@ -191,15 +213,14 @@ class PrefetchLoader:
                 "next_train_window (or set_window(0) first)")
         if self._q is None:          # shuffle_data not called yet (smoke use)
             return self._maybe_put(self._data.next_train_batch(count))
-        item = self._dequeue()
-        if isinstance(item, BaseException):
-            raise item
-        batch, cursor = item
+        batch, cursor = self._dequeue()
         if hasattr(batch, "result"):     # pooled producer: an ordered future
-            batch = batch.result()       # (re-raises materialize errors)
+            with telemetry.span("load.result", self.last_batch_id):
+                batch = batch.result()   # (re-raises materialize errors)
         # commit the cursor only AFTER the batch is in hand — a failed
         # materialize must not mark its batch consumed
         self._consumed_cursor = cursor
+        self._count_dequeue(batch)
         return batch
 
     def next_train_window(self, count: int):
@@ -212,14 +233,12 @@ class PrefetchLoader:
             batches = [self._data.next_train_batch(count - self.window + 1 + j)
                        for j in range(self.window)]
             return self._stage(_stack_host(batches))
-        item = self._dequeue()
-        if isinstance(item, BaseException):
-            raise item
-        window, cursor = item
+        window, cursor = self._dequeue()
         # commit only after the window is in hand (same contract as the
         # per-batch path); the cursor is AT WINDOW GRANULARITY — as of
         # after this window's k-th batch was drawn
         self._consumed_cursor = cursor
+        self._count_dequeue(window)
         return window
 
     def next_val_batch(self, count: int):
@@ -228,10 +247,12 @@ class PrefetchLoader:
         return self._maybe_put(self._data.next_val_batch(count))
 
     def _dequeue(self):
-        """One queue pop, instrumented: queue depth at dequeue (min/p50 in
-        the report — 0 means the consumer is about to starve) and a
-        starved-dequeue counter.  Disabled telemetry ≡ one attribute
-        check."""
+        """One queue pop -> ``(payload, cursor)``, instrumented: the wait
+        as the ring span ``load.dequeue`` (always on), and for the
+        registry the queue depth at dequeue (min/p50 in the report — 0
+        means the consumer is about to starve) and a starved-dequeue
+        counter.  Disabled telemetry ≡ one attribute check.  A producer's
+        error surfaces here."""
         tm = telemetry.active()
         if tm.enabled:
             depth = self._q.qsize()
@@ -240,7 +261,24 @@ class PrefetchLoader:
             tm.counter("prefetch.dequeues")
             if depth == 0:
                 tm.counter("prefetch.starved_dequeues")
-        return self._q.get()
+        with telemetry.span("load.dequeue") as sp:
+            item = self._q.get()
+            if isinstance(item, BaseException):
+                raise item
+            payload, cursor, sp.batch = item
+        self.last_batch_id = sp.batch
+        return payload, cursor
+
+    @staticmethod
+    def _count_dequeue(batch) -> None:
+        """``input.dequeues``, and ``input.unready_dequeues`` when a device
+        leaf of the batch in hand is still being transposed or transferred
+        by the runtime's threads (``is_ready()`` does not block)."""
+        telemetry.count("input.dequeues")
+        for v in batch.values() if hasattr(batch, "values") else ():
+            if hasattr(v, "is_ready") and not v.is_ready():
+                telemetry.count("input.unready_dequeues")
+                return
 
     # producer -------------------------------------------------------------
     def _producer(self, n_batches: int, q: queue.Queue,
@@ -256,30 +294,46 @@ class PrefetchLoader:
                                               "plan_train_batch"):
                 self._producer_pooled(n_batches, q, stop)
                 return
-            tm = telemetry.active()
             for i in range(n_batches):
                 if stop.is_set():
                     return
-                t0 = time.time()
-                batch = self._maybe_put(self._data.next_train_batch(i + 1))
+                bid = next(self._ids)
+                # materialize + device_put time up (relative to the
+                # consumer's step time) = the producer becoming the
+                # bottleneck
+                batch = self._maybe_put(self._draw(i + 1, bid), bid)
                 cursor = self._data.get_cursor() \
                     if hasattr(self._data, "get_cursor") else {}
-                if tm.enabled:
-                    # produce time up (relative to the consumer's step
-                    # time) = the producer becoming the bottleneck
-                    tm.observe("prefetch.produce_secs", time.time() - t0)
                 if stop.is_set():     # restart raced the load: drop, don't put
                     return
-                t0 = time.time()
-                q.put((batch, cursor))
-                if tm.enabled:
-                    # blocked on a full queue = the producer is AHEAD
-                    # (healthy overlap); ~0 everywhere + starved dequeues
-                    # = the producer can't keep up
-                    tm.observe("prefetch.producer_blocked_secs",
-                               time.time() - t0)
+                self._enqueue(q, (batch, cursor, bid))
         except BaseException as e:    # surface loader errors in the consumer
             q.put(e)
+
+    def _plan(self, count: int, bid: int):
+        with telemetry.span("input.plan", bid):
+            return self._data.plan_train_batch(count)
+
+    def _materialize(self, plan, bid: int):
+        with telemetry.span("input.materialize", bid):
+            return self._data.materialize(plan)
+
+    def _draw(self, count: int, bid: int):
+        """One host batch on the calling thread: plan then materialize
+        where the data has the split, else its ``next_train_batch`` whole
+        (as ``input.materialize``)."""
+        if hasattr(self._data, "plan_train_batch"):
+            return self._materialize(self._plan(count, bid), bid)
+        with telemetry.span("input.materialize", bid):
+            return self._data.next_train_batch(count)
+
+    @staticmethod
+    def _enqueue(q: queue.Queue, item) -> None:
+        """The bounded ``q.put``.  Long = the producer is AHEAD (healthy
+        overlap); ~0 everywhere + unready or starved dequeues = the
+        producer can't keep up."""
+        with telemetry.span("input.enqueue", item[2]):
+            q.put(item)
 
     def _producer_pooled(self, n_batches: int, q: queue.Queue,
                          stop: threading.Event) -> None:
@@ -297,16 +351,18 @@ class PrefetchLoader:
             for i in range(n_batches):
                 if stop.is_set() or failed:
                     return             # consumer hits the error at .result()
-                plan = self._data.plan_train_batch(i + 1)
+                bid = next(self._ids)
+                plan = self._plan(i + 1, bid)
                 cursor = self._data.get_cursor() \
                     if hasattr(self._data, "get_cursor") else {}
                 fut = pool.submit(
-                    lambda p: self._maybe_put(self._data.materialize(p)),
-                    plan)
+                    lambda p, b: self._maybe_put(self._materialize(p, b), b),
+                    plan, bid)
                 fut.add_done_callback(on_done)
                 if stop.is_set():
                     return
-                q.put((fut, cursor))   # bounded: blocks at depth+n_workers
+                # bounded: blocks at depth+n_workers
+                self._enqueue(q, (fut, cursor, bid))
 
     def _producer_windows(self, n_batches: int, q: queue.Queue,
                           stop: threading.Event) -> None:
@@ -324,43 +380,46 @@ class PrefetchLoader:
         pooled = self.n_workers > 1 and hasattr(self._data,
                                                 "plan_train_batch")
         pool = ThreadPoolExecutor(self.n_workers) if pooled else None
-        tm = telemetry.active()
         try:
             for w in range(n_batches // k):
                 if stop.is_set():
                     return
-                t0 = time.time()
+                bid = next(self._ids)       # one id per window
                 if pooled:
-                    plans = [self._data.plan_train_batch(w * k + j + 1)
+                    plans = [self._plan(w * k + j + 1, bid)
                              for j in range(k)]
-                    futs = [pool.submit(self._data.materialize, p)
+                    futs = [pool.submit(self._materialize, p, bid)
                             for p in plans]
                     batches = [f.result() for f in futs]  # re-raises, ordered
                 else:
-                    batches = [self._data.next_train_batch(w * k + j + 1)
+                    batches = [self._draw(w * k + j + 1, bid)
                                for j in range(k)]
                 cursor = self._data.get_cursor() \
                     if hasattr(self._data, "get_cursor") else {}
-                window = self._stage(_stack_host(batches))
-                if tm.enabled:
-                    tm.observe("prefetch.produce_secs", time.time() - t0)
+                window = self._stage(_stack_host(batches), bid)
                 if stop.is_set():     # restart raced the stage: drop
                     return
-                t0 = time.time()
-                q.put((window, cursor))
-                if tm.enabled:
-                    tm.observe("prefetch.producer_blocked_secs",
-                               time.time() - t0)
+                self._enqueue(q, (window, cursor, bid))
         finally:
             if pool is not None:
                 pool.shutdown(wait=False)
 
-    def _stage(self, window):
-        return self._stage_window_fn(window) if self._stage_window_fn \
-            else window
+    def _stage(self, window, bid=None):
+        return self._put(self._stage_window_fn, window, bid)
 
-    def _maybe_put(self, batch):
-        return self._device_put_fn(batch) if self._device_put_fn else batch
+    def _maybe_put(self, batch, bid=None):
+        return self._put(self._device_put_fn, batch, bid)
+
+    @staticmethod
+    def _put(put_fn, batch, bid):
+        """Host -> mesh through ``put_fn``, when there is one: the bytes
+        handed over (``input.bytes_put``) and the host call
+        (``input.device_put``; the transfer itself runs on after it)."""
+        if put_fn is None:
+            return batch
+        telemetry.count("input.bytes_put", _nbytes(batch))
+        with telemetry.span("input.device_put", bid):
+            return put_fn(batch)
 
     def _shutdown(self) -> None:
         if self._thread is not None and self._thread.is_alive():

@@ -26,6 +26,7 @@ from ..parallel.mesh import WORKER_AXIS, worker_mesh
 from ..utils import checkpoint as ckpt_lib
 from ..utils import helper_funcs
 from ..utils import numerics as numerics_lib
+from ..utils import telemetry
 from ..utils.opt import get_optimizer
 from . import layers as L
 
@@ -335,7 +336,17 @@ class ModelBase:
 
     def compile_iter_fns(self, exchanger=None) -> None:
         """≙ reference ``model.compile_iter_fns()`` → ``theano.function``;
-        here: jit the SPMD train/val steps and box the state onto the mesh."""
+        here: jit the SPMD train/val steps and box the state onto the mesh.
+
+        Nothing compiles here: the jit is lazy.  What this call costs is
+        the ring span ``compile.place``; the XLA compile, or the load from
+        the persistent cache, is ``compile.xla`` wherever it then happens
+        (the first ``train_iter``), through the listener registered here."""
+        telemetry.watch_compiles()
+        with telemetry.span("compile.place"):
+            self._compile_iter_fns(exchanger)
+
+    def _compile_iter_fns(self, exchanger=None) -> None:
         from ..parallel.exchanger import BSP_Exchanger
         self.exchanger = exchanger or BSP_Exchanger(self.config)
         if self._fsdp is not None:
@@ -772,19 +783,25 @@ class ModelBase:
         if recorder:
             recorder.end("stage")
             recorder.start()
+        # the `train` bracket in three ring spans: the two scalar
+        # conversions (a small program each), the step program's call
+        # alone, the two means (two more small programs) — stamped with
+        # the id of the batch the loader handed over
+        bid = getattr(self.data, "last_batch_id", None)
+        with telemetry.span("train.args", bid):
+            lr, step = jnp.float32(self.current_lr), jnp.int32(count)
+        with telemetry.span("train.call", bid):
+            out = self.train_fn(self.step_state, dev_batch, lr,
+                                self._step_rng, step)
         if getattr(self, "_numerics_on", False):
             # the aux stays device-resident (async dispatch preserved) —
             # the worker materializes it at print cadence, alongside
             # cost/error
-            (self.step_state, cost, err,
-             self.numerics_aux) = self.train_fn(
-                self.step_state, dev_batch, jnp.float32(self.current_lr),
-                self._step_rng, jnp.int32(count))
+            self.step_state, cost, err, self.numerics_aux = out
         else:
-            self.step_state, cost, err = self.train_fn(
-                self.step_state, dev_batch, jnp.float32(self.current_lr),
-                self._step_rng, jnp.int32(count))
-        cost, err = jnp.mean(cost), jnp.mean(err)
+            self.step_state, cost, err = out
+        with telemetry.span("train.reduce", bid):
+            cost, err = jnp.mean(cost), jnp.mean(err)
         if recorder:
             recorder.end("train")
         if self.config.get("sync_each_iter", False):

@@ -57,7 +57,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import buckets, steps, topology, update_sharding
 from ..jax_compat import shard_map
-from ..utils import devprof, telemetry, tracing
+from ..utils import telemetry, tracing
 from .mesh import WORKER_AXIS
 from .strategies import Strategy, get_strategy
 
@@ -457,11 +457,10 @@ class Exchanger:
         if recorder:
             recorder.start()
         t0 = time.time() if tm.enabled else 0.0
-        # devprof dispatch anchor: a profiler capture sees one named span
-        # per standalone exchange dispatch, so trace attribution can count
-        # exchanges without guessing from collective-op repetitions (a
-        # TraceMe no-op while no capture is active)
-        with jax.profiler.TraceAnnotation(devprof.EXCHANGE_SPAN):
+        # the standalone exchange's dispatch, in the always-on span ring
+        # (a TraceAnnotation could not say it: a capture on the chip holds
+        # device planes only, utils/devprof.profile_options)
+        with telemetry.span("exchange"):
             self.model.step_state = self._exchange_fn(
                 self.model.step_state, self.model.next_exchange_key(), count)
         if tm.enabled:

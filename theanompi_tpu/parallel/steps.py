@@ -20,7 +20,7 @@ bookkeeping, and zero memory overhead versus replicated params.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -523,7 +523,21 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
         in_specs=(state_spec, batch_spec, P(), P(), P()),
         out_specs=out_specs,
     )
-    return jax.jit(sm, donate_argnums=(0,))
+    return jax.jit(sm, donate_argnums=(0,),
+                   compiler_options=_keep_collectives_apart())
+
+
+def _keep_collectives_apart() -> Optional[dict]:
+    """Compiler options for the train step on the CPU backend: its
+    ``cpu-all-reduce-combiner`` pass merges the step's all-reduces (one a
+    leaf on the monolithic wire, one a bucket on the bucketed one) into a
+    single variadic collective, so a ``devprof`` capture on the CPU mesh
+    counted 1 collective a step whatever the wire issued — the structure
+    the CPU rehearsals exist to check.  Nothing on any other backend:
+    their programs, and cache keys, stay as they were."""
+    if jax.default_backend() != "cpu":
+        return None
+    return {"xla_disable_hlo_passes": "cpu-all-reduce-combiner"}
 
 
 def build_val_step(mesh: Mesh, model) -> Callable:

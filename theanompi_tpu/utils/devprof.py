@@ -12,14 +12,47 @@ records.  The CUDA-aware-MPI characterization (PAPERS.md, 1810.11112)
 makes the same point for GPU clusters: overlap of reduction with
 backprop, not raw bandwidth, is the scaling variable.
 
-This module is the ONE trace-proto reader in the repo (the glob/gzip/json
-walk ``scripts/profile_model.py`` used to do inline, promoted and
-tested).  ``jax.profiler.stop_trace`` writes
-``<dir>/plugins/profile/<session>/<host>.trace.json.gz`` — gzipped
-Chrome trace-event JSON where every executed HLO op is a complete
-(``"ph": "X"``) event carrying ``args.hlo_op`` / ``args.hlo_module``.
-That marker is the discriminator: host-side Python/runtime spans have no
-``hlo_op``, so the parse needs no tensorboard plugin and stays stdlib.
+This module is the program's ONE trace reader.  ``jax.profiler.stop_trace``
+writes ``<dir>/plugins/profile/<session>/<host>.xplane.pb`` on every
+backend (the TPU writes nothing else; the CPU backend of this container
+also writes a Chrome ``.trace.json.gz`` with the same events, which nothing
+reads any more), and ``jax.profiler.ProfileData`` reads it without
+tensorboard or TensorFlow.  :func:`xplane_events` turns it into the event
+dicts :func:`attribute` takes (``ph``/``pid``/``tid``/``ts``/``dur`` in
+microseconds, ``name``, ``args.hlo_op`` / ``args.hlo_module``):
+
+* **TPU** — one plane per chip, ``/device:TPU:<n>``; its line ``XLA Ops``
+  holds one event per executed HLO instruction whose *name is the whole
+  instruction text* (``%all-reduce.16 = (f32[96]{...}, ...) all-reduce(...)``,
+  see ``tests/data/tpu_v5e_bsp4_trace_names.json``), so the opcode is
+  parsed from the text after the result type; the line ``XLA Modules``
+  (``jit_per_worker(<fingerprint>)``) says which program an instruction
+  ran in.  The events' stats carry no op name or scope.
+* **CPU** — the op events *are* host events: plane ``/host:CPU``, one line
+  per executor thread, every op event marked by an ``hlo_op`` stat and
+  named after the jax primitive it came from (``psum_invariant.7``), not
+  its opcode.  The opcode is looked up in the compiled module's HloProto,
+  which the profiler embeds in the plane ``/host:metadata``
+  (:func:`_hlo_opcodes`, a forty-line protobuf walk: ``ProfileData`` does
+  not expose event metadata).
+
+A collective's event is named by its opcode (``all-reduce``,
+``all-reduce-start``), every other event by its instruction, so
+:func:`op_class` and :func:`is_comm_op` work on both.
+
+**What is captured** — :func:`profile_options`: on a TPU, device planes
+only.  With the host tracer on, PJRT's host-side layout transposition of
+every staged batch emits 0.5–2.4 million events per runtime thread: a
+1.1 GB trace, and 4 steps in 18 s where the untraced run makes 4.2 a second
+(PERF.md §6, PR 23 finding 2).  At level 0 the trace is a few MB and the
+pace untouched.  The host side of a capture is ``telemetry``'s always-on
+span ring instead: :func:`write_host_spans` keeps the rows of the capture's
+interval beside the trace as ``host_spans.jsonl``, and the session's
+``profile_start_time`` (Unix ns, plane ``Task Environment``) puts them on
+the trace's clock (``telemetry.on_trace_clock``).  :func:`idle_by_span`
+then says, for chip 0, under which span of each thread class (main,
+producer, pool) the device sat idle.  On the CPU backend the host tracer
+stays on: there the op events are host events.
 
 **Attribution model.**  Op events are grouped into *lanes* (one
 ``(pid, tid)`` pair — a device plane's op line on TPU, one per-device
@@ -44,11 +77,10 @@ the done on a dedicated async-collective stream must not read as a
 second, fully-exposed collective while the issuing lane's compute hides
 the real one).
 
-Host dispatch anchors: the worker loop and the standalone exchange tag
-their dispatches with ``jax.profiler.TraceAnnotation`` spans named
-:data:`TRAIN_DISPATCH_SPAN` / :data:`EXCHANGE_SPAN`; the parser counts
-them so per-dispatch means don't depend on guessing the iteration count
-from op repetitions.
+Dispatch counts come from the ring, not from the trace: one ``train.call``
+row per dispatch of the step program (``train_iter``, or a script that wraps
+its own ``train_fn`` call in ``telemetry.span("train.call")``), one
+``exchange`` row per standalone exchange.
 
 Consumers: the worker's ``trace_dir`` capture feeds the result into the
 PR 4 telemetry registry as ``device.*`` gauges (:func:`feed_telemetry` —
@@ -63,19 +95,18 @@ the schema constants); :func:`capture` imports it lazily.
 from __future__ import annotations
 
 import glob
-import gzip
 import json
 import math
 import os
 import re
+from bisect import bisect_right
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-# Dispatch-anchor span names (host-side jax.profiler.TraceAnnotation):
-# worker.py wraps each train_iter dispatch, exchanger.exchange wraps the
-# standalone collective dispatch.  Constant strings — the parser matches
-# them exactly.
-TRAIN_DISPATCH_SPAN = "theanompi.train_dispatch"
-EXCHANGE_SPAN = "theanompi.exchange"
+# Ring span names that count dispatches (telemetry.SPANS), and the file the
+# worker leaves beside a capture.
+TRAIN_DISPATCH_SPAN = "train.call"
+EXCHANGE_SPAN = "exchange"
+HOST_SPANS_FILE = "host_spans.jsonl"
 
 # The device.* gauge vocabulary feed_telemetry emits — ONE list, guarded
 # by the tpulint schema-drift checker so emitters and report consumers
@@ -191,28 +222,182 @@ def is_comm_op(name: str) -> bool:
     return op_class(name).startswith(COMM_OP_PREFIXES)
 
 
-# -- trace file discovery / loading ----------------------------------------
+# -- capture options, trace file discovery / loading -------------------------
+
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+ENV_PLANE = "Task Environment"
+START_STAT = "profile_start_time"
+MODULES_LINE = "XLA Modules"
+OPS_LINE = "XLA Ops"
 
 
-def find_trace_files(trace_dir: str) -> List[str]:
-    """The ``*.trace.json.gz`` files of the NEWEST capture session under
+def profile_options():
+    """``ProfileOptions`` for ``jax.profiler.start_trace``, the same for the
+    worker's ``trace_dir`` capture and :class:`capture`: on a TPU the host
+    and python tracers are off, so the trace holds device planes only (the
+    module docstring and PERF.md §6 finding 2 say what host tracing costs
+    while batches are staged); elsewhere the host tracer stays on, because
+    the CPU backend's op events are host events."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    if jax.default_backend() == "tpu":
+        opts.host_tracer_level = 0
+    return opts
+
+
+def find_xplane_files(trace_dir: str) -> List[str]:
+    """The ``*.xplane.pb`` files of the NEWEST capture session under
     ``trace_dir`` (jax writes ``plugins/profile/<timestamp>/`` per
     ``stop_trace``; one file per host)."""
-    sessions = sorted(
-        d for d in glob.glob(os.path.join(trace_dir, "plugins", "profile", "*"))
-        if os.path.isdir(d))
+    sessions = [d for d in glob.glob(os.path.join(trace_dir, "plugins",
+                                                  "profile", "*"))
+                if os.path.isdir(d)]
     if not sessions:
         return []
     newest = max(sessions, key=os.path.getmtime)
-    return sorted(glob.glob(os.path.join(newest, "*.trace.json.gz")))
+    return sorted(glob.glob(os.path.join(newest, "*.xplane.pb")))
 
 
-def load_trace_events(path: str) -> List[dict]:
-    """All trace events from one gzipped Chrome-trace file."""
-    with gzip.open(path, "rt") as f:
-        data = json.load(f)
-    evs = data.get("traceEvents", []) if isinstance(data, dict) else []
-    return [e for e in evs if isinstance(e, dict)]
+def _varint(buf: bytes, i: int) -> Tuple[int, int]:
+    val = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        val |= (b & 0x7F) << shift
+        shift += 7
+        if b < 0x80:
+            return val, i
+
+
+def _pb_fields(buf: bytes):
+    """``(field number, value)`` pairs of one protobuf message: varints as
+    ints, length-delimited fields as bytes, fixed ones skipped."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+            yield key >> 3, val
+        elif wire == 2:
+            size, i = _varint(buf, i)
+            yield key >> 3, buf[i:i + size]
+            i += size
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"protobuf wire type {wire}")
+
+
+def _hlo_opcodes(xspace: bytes) -> Dict[Tuple[str, str], str]:
+    """``{(module, instruction name): opcode}`` from the HloProtos the
+    profiler embeds in the plane ``/host:metadata``: XSpace.planes(1) ->
+    XPlane.event_metadata(4, a map entry whose value is 2) ->
+    XEventMetadata.stats(5) -> XStat.bytes_value(6) = HloProto ->
+    hlo_module(1) -> name(1), computations(3) -> instructions(2) ->
+    name(1), opcode(2).  Empty when the trace embeds none."""
+    def sub(buf, field):
+        return [v for f, v in _pb_fields(buf) if f == field
+                and isinstance(v, bytes)]
+
+    out: Dict[Tuple[str, str], str] = {}
+    for plane in sub(xspace, 1):
+        if b"/host:metadata" not in sub(plane, 2):
+            continue
+        for entry in sub(plane, 4):
+            for meta in sub(entry, 2):
+                for stat in sub(meta, 5):
+                    for proto in sub(stat, 6):
+                        try:
+                            for mod in sub(proto, 1):
+                                mname = sub(mod, 1)[0].decode()
+                                for comp in sub(mod, 3):
+                                    for ins in sub(comp, 2):
+                                        out[mname, sub(ins, 1)[0].decode()] \
+                                            = sub(ins, 2)[0].decode()
+                        except (IndexError, ValueError,
+                                UnicodeDecodeError):
+                            continue    # a bytes stat that is no HloProto
+    return out
+
+
+def hlo_opcode(hlo_text: str) -> str:
+    """The opcode of an instruction given as HLO text (a TPU ``XLA Ops``
+    event's name): the token after the result type, which is one token
+    without spaces or a parenthesised tuple.  Text that is no instruction
+    (no `` = ``) names itself."""
+    if " = " not in hlo_text:
+        return hlo_text.strip()
+    rest = hlo_text.split(" = ", 1)[1].lstrip()
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += ch == "("
+            depth -= ch == ")"
+            if depth == 0:
+                rest = rest[i + 1:].lstrip()
+                break
+    else:
+        rest = rest.split(" ", 1)[1].lstrip() if " " in rest else ""
+    return re.split(r"[\s(]", rest, maxsplit=1)[0]
+
+
+def _event_name(instruction: str, opcode: Optional[str]) -> str:
+    """A collective is named by its opcode, anything else by itself."""
+    return opcode if opcode and is_comm_op(opcode) else instruction
+
+
+def xplane_events(xspace: bytes, src: int = 0
+                  ) -> Tuple[List[dict], Optional[int]]:
+    """One serialized XSpace -> (the op events as the dicts
+    :func:`attribute` takes, the session's ``profile_start_time`` in Unix
+    ns).  Times are microseconds on the trace's clock; a lane is
+    ``(src, plane, line)``."""
+    from jax.profiler import ProfileData
+    profile = ProfileData.from_serialized_xspace(xspace)
+    opcodes: Optional[Dict[Tuple[str, str], str]] = None
+    events: List[dict] = []
+    start_ns = None
+    for plane in profile.planes:
+        if plane.name == ENV_PLANE:
+            start_ns = dict(plane.stats).get(START_STAT)
+            continue
+        if DEVICE_PLANE.match(plane.name):
+            lines = {line.name: line for line in plane.lines}
+            mods = sorted((e.start_ns, e.start_ns + e.duration_ns,
+                           e.name.split("(", 1)[0])
+                          for e in getattr(lines.get(MODULES_LINE),
+                                           "events", ()))
+            starts = [m[0] for m in mods]
+            for e in getattr(lines.get(OPS_LINE), "events", ()):
+                instr = e.name.split(" = ", 1)[0].strip().lstrip("%")
+                i = bisect_right(starts, e.start_ns) - 1
+                module = mods[i][2] if i >= 0 and e.start_ns < mods[i][1] \
+                    else "?"
+                events.append({
+                    "ph": "X", "_src": src, "pid": plane.name,
+                    "tid": OPS_LINE, "ts": e.start_ns / 1e3,
+                    "dur": e.duration_ns / 1e3,
+                    "name": _event_name(instr, hlo_opcode(e.name)),
+                    "args": {"hlo_op": instr, "hlo_module": module}})
+            continue
+        for line in plane.lines:          # host planes: the CPU backend
+            for e in line.events:
+                stats = dict(e.stats)
+                if "hlo_op" not in stats:
+                    continue              # host python/runtime span
+                if opcodes is None:
+                    opcodes = _hlo_opcodes(xspace)
+                module = str(stats.get("hlo_module", "?"))
+                events.append({
+                    "ph": "X", "_src": src, "pid": plane.name,
+                    "tid": line.name, "ts": e.start_ns / 1e3,
+                    "dur": e.duration_ns / 1e3,
+                    "name": _event_name(e.name,
+                                        opcodes.get((module, e.name))),
+                    "args": {"hlo_op": e.name, "hlo_module": module}})
+    return events, start_ns
 
 
 # -- interval algebra -------------------------------------------------------
@@ -317,14 +502,16 @@ def _merge_async_pairs(comm_ev: Dict[Tuple, List[Tuple[float, float, str]]]
 # -- attribution ------------------------------------------------------------
 
 
-def attribute(events: Iterable[dict]) -> Dict[str, Any]:
+def attribute(events: Iterable[dict],
+              host_rows: Iterable[tuple] = ()) -> Dict[str, Any]:
     """Per-dispatch device-time breakdown from raw trace events.
 
     Returns a plain JSON-able dict: ``compute_secs`` / ``comm_secs`` /
     ``exposed_comm_secs`` / ``overlap_ratio`` / ``lanes`` totals, the
     per-``hlo_module`` breakdown, the top op classes by device time, and
-    the host dispatch-anchor counts (``train_dispatches`` /
-    ``exchange_dispatches``)."""
+    the dispatch counts (``train_dispatches`` / ``exchange_dispatches``)
+    read off ``host_rows``, the span ring's rows of the capture interval
+    (``telemetry.spans``)."""
     # lane = (pid, tid); per lane the compute interval lists and the comm
     # EVENT lists (us; comm keeps the op class so async start/done pairs
     # can merge into one in-flight interval — see _merge_async_pairs)
@@ -335,19 +522,14 @@ def attribute(events: Iterable[dict]) -> Dict[str, Any]:
     # can't masquerade as overlap for device B's collective
     per_module: Dict[str, Dict[str, Dict[Tuple, List]]] = {}
     op_totals: Dict[str, List[float]] = {}            # class -> [us, count]
-    train_dispatches = 0
-    exchange_dispatches = 0
+    host_names = [r[0] for r in host_rows]
+    train_dispatches = host_names.count(TRAIN_DISPATCH_SPAN)
+    exchange_dispatches = host_names.count(EXCHANGE_SPAN)
     n_op_events = 0
     for ev in events:
         if ev.get("ph") != "X":
             continue
         name = ev.get("name", "")
-        if name == TRAIN_DISPATCH_SPAN:
-            train_dispatches += 1
-            continue
-        if name == EXCHANGE_SPAN:
-            exchange_dispatches += 1
-            continue
         args = ev.get("args")
         if not isinstance(args, dict) or "hlo_op" not in args:
             continue                       # host python/runtime span
@@ -446,34 +628,183 @@ def attribute(events: Iterable[dict]) -> Dict[str, Any]:
     }
 
 
+def _load_dir(trace_dir: str) -> Tuple[List[dict], Optional[int], List[str]]:
+    events: List[dict] = []
+    start_ns = None
+    paths = find_xplane_files(trace_dir)
+    for src, p in enumerate(paths):
+        try:
+            with open(p, "rb") as f:
+                file_events, file_start = xplane_events(f.read(), src)
+        except Exception:
+            continue          # a truncated capture file is not fatal
+        events.extend(file_events)
+        if start_ns is None:
+            start_ns = file_start
+    return events, start_ns, paths
+
+
+def session_start_ns(trace_dir: str) -> Optional[int]:
+    """The newest session's ``profile_start_time`` (Unix ns), read off the
+    plane ``Task Environment`` without walking the events."""
+    from jax.profiler import ProfileData
+    for p in find_xplane_files(trace_dir):
+        try:
+            plane = ProfileData.from_file(p).find_plane_with_name(ENV_PLANE)
+        except Exception:
+            continue
+        if plane is not None:
+            return dict(plane.stats).get(START_STAT)
+    return None
+
+
 def load_dir_events(trace_dir: str) -> List[dict]:
-    """Raw trace events of the newest capture session under ``trace_dir``,
-    merged across per-host files and ``_src``-tagged per file (the lane
+    """Op events of the newest capture session under ``trace_dir``, merged
+    across per-host files and ``_src``-tagged per file (the lane
     disambiguator ``attribute()``/``schedule_occupancy()`` expect).  Empty
     when no capture is found."""
-    events: List[dict] = []
-    for src, p in enumerate(find_trace_files(trace_dir)):
-        try:
-            file_events = load_trace_events(p)
-        except (OSError, ValueError):
-            continue          # a truncated capture file is not fatal
-        for ev in file_events:
-            ev["_src"] = src  # lane disambiguator (see attribute())
-        events.extend(file_events)
-    return events
+    return _load_dir(trace_dir)[0]
 
 
-def profile_dir(trace_dir: str) -> Optional[Dict[str, Any]]:
+def profile_dir(trace_dir: str, host_rows: Optional[List[tuple]] = None
+                ) -> Optional[Dict[str, Any]]:
     """Parse the newest capture session under ``trace_dir`` into one
-    attribution dict (events merged across per-host files).  None when no
-    capture is found."""
-    paths = find_trace_files(trace_dir)
-    events = load_dir_events(trace_dir)
+    attribution dict (events merged across per-host files).  The host side
+    is ``host_rows`` (ring rows, Unix ns) or, failing that, the
+    ``host_spans.jsonl`` the worker left in ``trace_dir``: dispatch counts,
+    and with the session's start time the idle-by-span table.  None when
+    no capture is found."""
+    events, start_ns, paths = _load_dir(trace_dir)
     if not events:
         return None
-    prof = attribute(events)
+    if host_rows is None:
+        host_rows = read_host_spans(trace_dir)
+    prof = attribute(events, host_rows)
     prof["trace_files"] = [os.path.basename(p) for p in paths]
+    if host_rows and start_ns is not None:
+        prof["idle_by_span"] = idle_by_span(events, host_rows, start_ns)
     return prof
+
+
+# -- the host side of a capture: the span ring's rows ------------------------
+
+
+def write_host_spans(trace_dir: str, t0_ns: int, t1_ns: int) -> str:
+    """Keep the span ring's rows of ``[t0_ns, t1_ns]`` (Unix ns) beside a
+    capture, one JSON object a line (``name``, ``tid``, ``t0``, ``t1``,
+    ``parent``, ``batch``) under a header line with the interval and the
+    session's ``profile_start_time``: subtract it (``on_trace_clock``) and
+    a row lies on the trace's clock."""
+    from . import telemetry
+    start_ns = session_start_ns(trace_dir)
+    path = os.path.join(trace_dir, HOST_SPANS_FILE)
+    os.makedirs(trace_dir, exist_ok=True)
+    with open(path, "w") as f:
+        f.write(json.dumps({"t0": t0_ns, "t1": t1_ns,
+                            "profile_start_time": start_ns}) + "\n")
+        for name, tid, t0, t1, parent, batch in telemetry.spans(t0_ns,
+                                                                 t1_ns):
+            # a row that straddles an end is cut to the interval
+            f.write(json.dumps({"name": name, "tid": tid,
+                                "t0": max(t0, t0_ns), "t1": min(t1, t1_ns),
+                                "parent": parent, "batch": batch}) + "\n")
+    return path
+
+
+def read_host_spans(trace_dir: str) -> List[tuple]:
+    """The rows of ``host_spans.jsonl`` as ring tuples; empty if absent."""
+    path = os.path.join(trace_dir, HOST_SPANS_FILE)
+    if not os.path.exists(path):
+        return []
+    with open(path) as f:
+        recs = [json.loads(line) for line in f if line.strip()]
+    return [(r["name"], r["tid"], r["t0"], r["t1"], r["parent"],
+             r["batch"]) for r in recs[1:]]
+
+
+def thread_classes(rows: Iterable[tuple]) -> Dict[int, str]:
+    """``{thread id: 'producer' | 'pool' | 'main'}`` by the spans a thread
+    wrote: the one that plans is the loader's producer, one that only
+    materializes or stages is a pool thread, any other is the consumer."""
+    names: Dict[int, set] = {}
+    for row in rows:
+        names.setdefault(row[1], set()).add(row[0])
+    return {tid: "producer" if "input.plan" in n or "input.enqueue" in n
+            else "pool" if n & {"input.materialize", "input.device_put"}
+            else "main" for tid, n in names.items()}
+
+
+def innermost_by_piece(pieces: List[Tuple[float, float]],
+                       rows: List[tuple]) -> Dict[str, float]:
+    """Length of ``pieces`` (sorted, disjoint intervals) by the innermost
+    (shortest) of one thread's ``rows`` ``(name, t0, t1)`` open at the
+    time, ``(none)`` where none is."""
+    cuts = sorted({t for _, a, b in rows for t in (a, b)})
+    by: Dict[str, float] = {}
+    for lo, hi in pieces:
+        inside = cuts[bisect_right(cuts, lo):bisect_right(cuts, hi)]
+        edges = [lo] + [c for c in inside if lo < c < hi] + [hi]
+        for a, b in zip(edges, edges[1:]):
+            mid = (a + b) / 2
+            cover = [(t1 - t0, name) for name, t0, t1 in rows
+                     if t0 <= mid < t1]
+            label = min(cover)[1] if cover else "(none)"
+            by[label] = by.get(label, 0.0) + b - a
+    return by
+
+
+def idle_by_span(events: Iterable[dict], host_rows: Iterable[tuple],
+                 start_ns: int) -> Dict[str, Any]:
+    """The first device lane's idle time inside the host rows' interval,
+    by the innermost span open on each thread class — the operator's
+    version of the benchmark's ``idle_gaps``, with the loader's threads in
+    it.  A class with several threads (the pool) gets the mean over them,
+    so every column sums to the idle time.  Seconds."""
+    host_rows = list(host_rows)
+    lanes: Dict[Tuple, List[Tuple[float, float]]] = {}
+    for ev in events:
+        lane = (ev.get("_src"), ev.get("pid"), ev.get("tid"))
+        lanes.setdefault(lane, []).append(
+            (ev["ts"] * 1e3, (ev["ts"] + ev["dur"]) * 1e3))
+    if not lanes or not host_rows:
+        return {}
+    lane = min(lanes, key=str)
+    # the rows' interval, cut to the session: the trace's clock starts at
+    # 0, and before it the device's events are simply not there
+    lo = max(0, min(r[2] for r in host_rows) - start_ns)
+    hi = max(r[3] for r in host_rows) - start_ns
+    idle, at = [], lo
+    for s, e in _union(lanes[lane]):
+        if e <= lo or s >= hi:
+            continue
+        if s > at:
+            idle.append((at, s))
+        at = max(at, e)
+    if hi > at:
+        idle.append((at, hi))
+    classes = thread_classes(host_rows)
+    per_thread: Dict[int, List[tuple]] = {}
+    for name, tid, t0, t1, _parent, _batch in host_rows:
+        per_thread.setdefault(tid, []).append(
+            (name, t0 - start_ns, t1 - start_ns))
+    out: Dict[str, Any] = {"lane": f"{lane[1]}/{lane[2]}",
+                           "window_secs": (hi - lo) / 1e9,
+                           "idle_secs": _measure(idle) / 1e9,
+                           "threads": {}, "by_class": {}}
+    for cls in ("main", "producer", "pool"):
+        tids = [t for t, c in classes.items() if c == cls]
+        if not tids:
+            continue
+        acc: Dict[str, float] = {}
+        for tid in tids:
+            for label, ns in innermost_by_piece(idle,
+                                                per_thread[tid]).items():
+                acc[label] = acc.get(label, 0.0) + ns / len(tids)
+        out["threads"][cls] = len(tids)
+        out["by_class"][cls] = sorted(
+            ([label, ns / 1e9] for label, ns in acc.items()),
+            key=lambda kv: -kv[1])
+    return out
 
 
 # -- schedule occupancy ------------------------------------------------------
@@ -745,22 +1076,29 @@ class capture:
         self._cap = _Capture(trace_dir)
 
     def __enter__(self) -> _Capture:
+        import time
         import jax
-        jax.profiler.start_trace(self._cap.trace_dir)
+        self._t0_ns = time.time_ns()
+        jax.profiler.start_trace(self._cap.trace_dir,
+                                 profiler_options=profile_options())
         return self._cap
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        import time
         import jax
+        from . import telemetry
         jax.profiler.stop_trace()
         if exc_type is None:
             try:
-                self._cap.profile = profile_dir(self._cap.trace_dir)
+                self._cap.profile = profile_dir(
+                    self._cap.trace_dir,
+                    telemetry.spans(self._t0_ns, time.time_ns()))
             except Exception:
                 self._cap.profile = None    # attribution must never raise
                                             # into the training loop
         if self._own_dir:
             # anonymous capture: the caller only wants the attribution, so
-            # the multi-MB .trace.json.gz files must not accumulate under
+            # the multi-MB capture files must not accumulate under
             # /tmp across bench rows (pass trace_dir to keep the raw
             # capture for Perfetto)
             import shutil
@@ -1028,4 +1366,18 @@ def format_profile(profile: Dict[str, Any], top: int = 15) -> str:
             lines.append(f"  {o['secs'] * 1e3:9.2f} ms  "
                          f"{100 * o['secs'] / total:5.1f}%  x{o['count']:<5d} "
                          f"{o['op'][:90]}{tag}")
+    idle = profile.get("idle_by_span")
+    if idle:
+        lines.append(
+            f"device idle by host span ({idle['lane']}: idle "
+            f"{idle['idle_secs'] * 1e3:.1f} ms of "
+            f"{idle['window_secs'] * 1e3:.1f} ms; innermost open span per "
+            f"thread class, a class's threads averaged):")
+        for cls, rows in idle["by_class"].items():
+            lines.append(f"  {cls} ({idle['threads'][cls]} thread(s)):")
+            for label, secs in rows[:top]:
+                share = 100 * secs / idle["idle_secs"] \
+                    if idle["idle_secs"] else 0.0
+                lines.append(f"    {secs * 1e3:9.2f} ms  {share:5.1f}%  "
+                             f"{label}")
     return "\n".join(lines)

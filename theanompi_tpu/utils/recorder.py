@@ -8,14 +8,18 @@ offline plotting.  The paper's "time per 5120 images" tables come from this
 component, so the bucket names and the 5120-image accounting are preserved.
 
 Additions over the reference: JSONL record emission (alongside the ``.npy``
-dumps) and an images/sec/chip derivation — the north-star metric in
-``BASELINE.json``.
+dumps), an images/sec/chip derivation — the north-star metric in
+``BASELINE.json`` — and, since the sums say how long but not when or inside
+what, every bracket is also one interval in ``telemetry``'s always-on span
+ring (``Recorder.end``), on the Unix clock, where the spans inside
+``train_iter`` and the loader name it as their parent.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional
@@ -32,9 +36,11 @@ IMAGES_PER_REPORT = 5120
 # loader's window producer stages dispatch inputs off the hot path) — the
 # split makes the producer/consumer overlap win visible in records.
 # `compile` = building the iteration functions (worker.py brackets
-# compile_iter_fns): the XLA compile on a cold start, the executable-cache
-# deserialize (~seconds) on a warm one — the bucket makes the AOT cache's
-# win (and a resume recompiling from scratch) visible per run.
+# compile_iter_fns): jit wrappers built and the state placed on the mesh,
+# about a second cold or warm (ring span `compile.place`).  It holds NO XLA
+# compile: the jit is lazy, so the compile, or the load from the persistent
+# cache, lands in the first `train` bracket; the ring span `compile.xla`
+# (telemetry.watch_compiles) times it wherever it happens.
 # The list itself lives in telemetry.PHASES — ONE source of truth for the
 # recorder buckets, the t_<section> record keys below, and the telemetry
 # phase-event names (the tpulint schema-drift checker guards the sync).
@@ -67,7 +73,7 @@ class Recorder:
         self.printFreq: int = config.get("printFreq", 40)
         self.record_dir: str = config.get("record_dir", "./inc")
 
-        self._t0: Optional[float] = None
+        self._open = None             # the ring frame of start()
         self.t_sec: Dict[str, float] = defaultdict(float)  # running, since last print
         self.t_sec_total: Dict[str, float] = defaultdict(float)
 
@@ -87,20 +93,26 @@ class Recorder:
     # -- timing ------------------------------------------------------------
 
     def start(self) -> None:
-        self._t0 = time.time()
+        if self._open is not None:      # one bracket at a time: the new
+            telemetry.drop_bracket(self._open)      # start() wins
+        self._open = telemetry.open_bracket()
 
     def end(self, section: str) -> float:
-        assert self._t0 is not None, "Recorder.end() without start()"
-        dt = time.time() - self._t0
+        """Close the bracket: the sums as ever, and one row in the
+        always-on span ring (``telemetry.spans()``) under the section's
+        name, parent of the spans opened inside it."""
+        assert self._open is not None, "Recorder.end() without start()"
+        b, self._open = self._open, None
+        dt = telemetry.close_bracket(b, section) / 1e9
         self.t_sec[section] += dt
         self.t_sec_total[section] += dt
-        self._t0 = None
         # per-dispatch phase events: one histogram sample + one stream
         # event per bracket — the raw material for telemetry_report's
         # tail percentiles and straggler ranking.  Disabled ≡ one
         # attribute check.
         if self.telemetry.enabled:
-            self.telemetry.phase(section, dt)
+            self.telemetry.phase(section, dt, t0_ns=b.t0,
+                                 tid=threading.get_ident())
         return dt
 
     # -- metric accumulation ----------------------------------------------
@@ -144,11 +156,15 @@ class Recorder:
         k = max(1, -(-self.printFreq // stride))      # ceil division
         if (count // stride) % k != 0:
             return None
-        # materializing device scalars happens HERE, once per printFreq iters
-        cost = float(np.mean([np.asarray(c) for c in self._train_cost[-k:]])) \
-            if self._train_cost else float("nan")
-        err = float(np.mean([np.asarray(e) for e in self._train_error[-k:]])) \
-            if self._train_error else float("nan")
+        # materializing device scalars happens HERE, once per printFreq
+        # iters: the one place the loop waits for the device (span `print`)
+        with telemetry.span("print"):
+            cost = float(np.mean([np.asarray(c)
+                                  for c in self._train_cost[-k:]])) \
+                if self._train_cost else float("nan")
+            err = float(np.mean([np.asarray(e)
+                                 for e in self._train_error[-k:]])) \
+                if self._train_error else float("nan")
         rec = {"iter": count, "cost": cost, "error": err}
         for key, s in zip(RECORD_KEYS, (s for s in SECTIONS if s != "val")):
             rec[key] = self.t_sec[s]

@@ -29,16 +29,29 @@ Three pieces, one process-wide instance (:func:`init` / :func:`active`):
   ``crash_<tag>/`` subdirectory before restarting, so a dead run leaves
   a diagnosable trail that the next attempt cannot overwrite.
 
-**Cost contract**: telemetry is off unless the config enables it
-(``record_dir`` set, or ``telemetry=true`` for an in-memory registry;
-``telemetry=false`` force-disables).  Disabled, :func:`active` returns
-the inert :data:`DISABLED` singleton whose ``enabled`` is ``False`` —
-every hot-path call site guards with that ONE attribute check and skips
-all telemetry work (``tests/test_telemetry.py`` pins the overhead).
+**Cost contract** — two tiers.  The *span ring* (:func:`span`,
+:func:`count`, read through :func:`spans` / :func:`totals`) is ALWAYS on
+and bounded: one row per finished span in a ``deque(maxlen=RING_SPANS)``
+plus a running total per name that is never evicted; two clock reads, one
+tuple, one append, no lock beyond the GIL, no I/O (about a microsecond a
+span, ``tests/test_spans.py`` pins it; at most ~30 spans a step).  The
+benchmark's per-layer metrics and the worker's ``trace_dir`` capture read
+it, so it cannot depend on a configuration key.  *Export* (registry, JSONL
+stream, flight ring) is off unless the config enables it (``record_dir``
+set, or ``telemetry=true`` for an in-memory registry; ``telemetry=false``
+force-disables).  Disabled, :func:`active` returns the inert
+:data:`DISABLED` singleton whose ``enabled`` is ``False`` — every
+hot-path call to the *registry* (``counter``/``gauge``/``observe``/
+``event``...) guards with that ONE attribute check
+(``tests/test_telemetry.py`` pins the overhead; tpulint's
+telemetry-hot-path pass enforces it and knows that ``span``/``count``
+need no guard).  Enabled, a finished span is also a ``phase`` sample: the
+histogram ``phase.<name>`` and one stream event.
 
 This module imports no jax at module scope (scripts read it for
 :data:`PHASES` without dragging a backend in); device probes import
-lazily inside :meth:`Telemetry.system_snapshot`.
+lazily inside :meth:`Telemetry.system_snapshot`, and the ``jax.monitoring``
+listener behind ``compile.xla`` registers in :func:`watch_compiles`.
 """
 
 from __future__ import annotations
@@ -60,6 +73,197 @@ PHASES = ("compile", "train", "comm", "wait", "load", "stage", "val")
 
 SCHEMA_VERSION = 1
 FLIGHT_EVENTS = 256          # ring-buffer length (events, not bytes)
+
+# THE span vocabulary of the always-on ring, beside the recorder's PHASES
+# (``Recorder.end`` writes its bracket into the ring under the phase's own
+# name).  A dotted name is a part of the phase before the dot.  Every
+# ``telemetry.span("...")`` / ``telemetry.count("...")`` literal in the
+# package must be listed here and every listed name must have a site: the
+# tpulint schema-drift checker guards both directions.  PERF.md §3 says
+# which metric or operator reading each one feeds.
+SPANS = (
+    "load.dequeue", "load.result",                 # consumer, inside `load`
+    "train.args", "train.call", "train.reduce",    # inside `train`
+    "exchange", "print",                           # main thread
+    "input.plan", "input.enqueue",                 # producer thread
+    "input.materialize", "input.device_put",       # pool (or producer)
+    "compile.place",                               # compile_iter_fns
+    "compile.xla", "compile.cache_load",           # jax.monitoring
+)
+COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put")
+# a 20 s window at 20 steps/s and 30 spans a step, with room to spare
+RING_SPANS = 16384
+# jax.monitoring duration events -> span names.  jax wraps
+# compile_or_get_cached in the first, so a persistent-cache load is inside
+# it too; the second is the nested part that tells warm from cold.
+COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "compile.xla",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+
+
+# -- the always-on span ring -------------------------------------------------
+# Rows are ``(name, thread_id, t0_ns, t1_ns, parent_frame, batch)`` on the
+# Unix clock (``time.time_ns()``: the clock a profiler session's
+# ``profile_start_time`` is on).  ``parent_frame`` is the frame open on
+# the same thread when the span began (``spans()`` hands out its name): a
+# span's self time is its length less its children's.  Running totals are
+# keyed by (name, thread) so that each cell has ONE writer and needs no
+# lock; ``totals()`` folds them by name.
+
+_ring: deque = deque(maxlen=RING_SPANS)
+_totals: Dict[tuple, list] = {}          # (name, tid) -> [count, ns]
+_tls = threading.local()
+_get_ident = threading.get_ident
+_now_ns = time.time_ns
+
+
+def _stack() -> list:
+    try:
+        return _tls.stack
+    except AttributeError:
+        _tls.stack = []
+        return _tls.stack
+
+
+def _finish(name, t0, t1, parent, batch, export=True) -> None:
+    tid = _get_ident()
+    _ring.append((name, tid, t0, t1, parent, batch))
+    try:
+        tot = _totals[name, tid]
+    except KeyError:
+        tot = _totals[name, tid] = [0, 0]
+    tot[0] += 1
+    tot[1] += t1 - t0
+    if export and _ACTIVE.enabled:
+        _ACTIVE.phase(name, (t1 - t0) / 1e9, t0_ns=t0, tid=tid)
+
+
+class span:
+    """``with telemetry.span("train.call", batch=i):`` — one ring row on
+    exit.  Re-entrant and thread-safe; ``batch`` (the loader's batch id,
+    shared by the spans of one batch across threads) may also be set on
+    the object inside the block, where it is only known after the wait
+    (``load.dequeue``)."""
+
+    __slots__ = ("name", "batch", "t0", "parent")
+
+    def __init__(self, name: str, batch=None):
+        self.name = name
+        self.batch = batch
+
+    def __enter__(self):
+        stack = _stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self)
+        self.t0 = _now_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = _now_ns()
+        _pop(self)
+        _finish(self.name, self.t0, t1, self.parent, self.batch)
+        return False
+
+
+def _pop(frame) -> None:
+    stack = _stack()
+    if stack and stack[-1] is frame:
+        stack.pop()
+    elif frame in stack:             # frames left open above it by an
+        del stack[stack.index(frame):]      # exception: they go with it
+
+
+def open_bracket() -> span:
+    """``Recorder.start()``: push a frame whose name is only known at
+    ``end(section)``.  Spans opened inside it hold the frame, so they learn
+    their parent's name when the bracket closes."""
+    return span(None).__enter__()
+
+
+def close_bracket(b: span, name: str) -> int:
+    """``Recorder.end(section)``: name the frame, write its row; returns
+    its length in ns.  Not forwarded to the registry: the recorder feeds
+    its own ``phase`` sample."""
+    t1 = _now_ns()
+    b.name = name
+    _pop(b)
+    _finish(name, b.t0, t1, b.parent, None, export=False)
+    return t1 - b.t0
+
+
+def drop_bracket(b: span) -> None:
+    """A ``start()`` that a second ``start()`` supersedes."""
+    _pop(b)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Always-on running total of a counter in :data:`COUNTS`."""
+    key = (name, _get_ident())
+    try:
+        _totals[key][0] += n
+    except KeyError:
+        _totals[key] = [n, 0]
+
+
+def spans(t0_ns: Optional[int] = None, t1_ns: Optional[int] = None
+          ) -> List[tuple]:
+    """Rows ``(name, thread_id, t0_ns, t1_ns, parent, batch)`` still in
+    the ring that overlap ``[t0_ns, t1_ns]`` (all of them by default),
+    oldest first, ``parent`` as a name."""
+    out = []
+    for name, tid, t0, t1, parent, batch in list(_ring):
+        if (t0_ns is None or t1 >= t0_ns) and (t1_ns is None or t0 <= t1_ns):
+            out.append((name, tid, t0, t1,
+                        parent.name if parent is not None else None, batch))
+    return out
+
+
+def totals() -> Dict[str, tuple]:
+    """``{name: (count, total_ns)}`` since the process started, spans and
+    counters alike (a counter's ns is 0); never evicted."""
+    out: Dict[str, list] = {}
+    for (name, _tid), (n, ns) in list(_totals.items()):
+        acc = out.setdefault(name, [0, 0])
+        acc[0] += n
+        acc[1] += ns
+    return {k: (v[0], v[1]) for k, v in out.items()}
+
+
+def on_trace_clock(unix_ns: int, profile_start_ns: int) -> int:
+    """A ring time as a time on a profiler trace's clock: the profiler
+    subtracts the session's start (``profile_start_time``, Unix ns, a
+    stat of the plane ``Task Environment``) from every timestamp."""
+    return unix_ns - profile_start_ns
+
+
+_watching = False
+_watch_lock = threading.Lock()       # registration only, off every hot path
+
+
+def watch_compiles() -> None:
+    """Register, once, the ``jax.monitoring`` listener that writes every
+    XLA backend compile (``compile.xla``, persistent-cache loads included)
+    and every cache retrieval (``compile.cache_load``, nested in it) into
+    the ring as a span ending now.  Called from ``compile_iter_fns``, so
+    nothing is imported or registered before a model compiles."""
+    global _watching
+    if _watching:
+        return
+    import jax.monitoring
+
+    def on_duration(event, duration_secs, **_):
+        name = COMPILE_EVENTS.get(event)
+        if name is not None:
+            t1 = _now_ns()
+            stack = _stack()
+            _finish(name, t1 - int(duration_secs * 1e9), t1,
+                    stack[-1] if stack else None, None)
+
+    with _watch_lock:
+        if not _watching:
+            jax.monitoring.register_event_duration_secs_listener(on_duration)
+            _watching = True
 
 
 def host_rss_bytes() -> Optional[int]:
@@ -215,11 +419,18 @@ class Telemetry:
                 h = self.hists[name] = Histogram()
             h.observe(value)
 
-    def phase(self, section: str, dt: float) -> None:
-        """One recorder phase bracket: histogram sample + stream event.
-        Event names/fields are part of the schema (docs/design.md §11)."""
+    def phase(self, section: str, dt: float, t0_ns: Optional[int] = None,
+              tid: Optional[int] = None) -> None:
+        """One recorder phase bracket or finished ring span: histogram
+        sample + stream event (``t0`` in Unix ns and the thread when the
+        caller has them).  Event names/fields are part of the schema
+        (docs/design.md §11)."""
         self.observe("phase." + section, dt)
-        self.event("phase", sec=section, dt=round(dt, 6))
+        if t0_ns is None:
+            self.event("phase", sec=section, dt=round(dt, 6))
+        else:
+            self.event("phase", sec=section, dt=round(dt, 6), t0=t0_ns,
+                       tid=tid)
 
     # -- events -------------------------------------------------------------
 
@@ -360,7 +571,7 @@ class _Disabled:
     def observe(self, name, value):
         pass
 
-    def phase(self, section, dt):
+    def phase(self, section, dt, t0_ns=None, tid=None):
         pass
 
     def event(self, name, /, ring_only=False, **fields):

@@ -26,13 +26,6 @@ from .utils.sentry import TrainingSentry
 from .utils.watchdog import StallWatchdog
 
 
-def _jax_profiler():
-    """Lazy jax.profiler handle (module-cached import — a dict hit per
-    call, no backend work)."""
-    import jax
-    return jax.profiler
-
-
 class Worker(MeshProcess):
     """Generic rule-driven worker (≙ reference ``BSP_Worker`` et al.)."""
 
@@ -58,9 +51,10 @@ class Worker(MeshProcess):
     def run(self, model) -> Recorder:
         """The reference's ``run(model)`` epoch/batch loop (SURVEY.md §3.1)."""
         config = self.config
-        # the compile recorder bucket: XLA compile on a cold start, the
-        # executable-cache deserialize (~seconds) on a warm one — per-epoch
-        # records then show compile going to ~0 on a cache-hit resume
+        # the compile recorder bucket: building the jit wrappers and
+        # placing the state, NOT the XLA compile — the jit is lazy, so that
+        # lands in the first train_iter (ring spans compile.place /
+        # compile.xla; an AOT compile_cache hit or miss is logged below)
         self.recorder.start()
         model.compile_iter_fns(self.exchanger)
         self.recorder.end("compile")
@@ -95,26 +89,36 @@ class Worker(MeshProcess):
         # Timeline tracing (beyond the reference's wall-clock buckets,
         # SURVEY.md §5): trace_dir enables a jax.profiler capture of
         # trace_iters iterations starting at trace_start — view in
-        # TensorBoard / Perfetto.
+        # TensorBoard / Perfetto.  On a TPU the capture holds device
+        # planes only (devprof.profile_options says why); the host side is
+        # the span ring's rows of the same interval, written beside it as
+        # host_spans.jsonl on the trace's clock.
         trace_dir = config.get("trace_dir")
         trace_start = int(config.get("trace_start", 5))
         trace_iters = max(1, int(config.get("trace_iters", 5)))
         trace_pending = trace_dir is not None
         trace_stop_at = None
+        trace_t0_ns = 0
 
         def _stop_trace():
             nonlocal trace_stop_at
             import jax
+            trace_t1_ns = time.time_ns()    # the captured steps end here:
+            # what follows (the drain, the trace's export) is not the loop
             jax.block_until_ready(model.step_state["params"])
             jax.profiler.stop_trace()
             trace_stop_at = None
             if self.verbose:
                 print(f"profiler trace saved to {trace_dir}", flush=True)
             # device-time attribution (utils/devprof): parse the capture
-            # into compute/comm/exposed-comm/overlap and feed the device.*
-            # gauges — the host-side phase.comm bracket goes blind once
-            # collectives overlap backprop; this is the honest breakdown
+            # into compute/comm/exposed-comm/overlap, the device's idle
+            # time by what each host thread was doing, and feed the
+            # device.* gauges — the host-side phase.comm bracket goes
+            # blind once collectives overlap backprop; this is the honest
+            # breakdown
             try:
+                devprof.write_host_spans(trace_dir, trace_t0_ns,
+                                         trace_t1_ns)
                 prof = devprof.profile_dir(trace_dir)
             except Exception as e:
                 prof = None
@@ -238,7 +242,10 @@ class Worker(MeshProcess):
                         count += spc
                         if trace_pending and count >= trace_start:
                             import jax
-                            jax.profiler.start_trace(trace_dir)
+                            trace_t0_ns = time.time_ns()
+                            jax.profiler.start_trace(
+                                trace_dir,
+                                profiler_options=devprof.profile_options())
                             trace_pending = False
                             # clamp the window to the dispatch stride:
                             # count advances by spc per iteration, so the
@@ -247,13 +254,10 @@ class Worker(MeshProcess):
                             # to whole windows instead
                             trace_stop_at = count + max(
                                 1, (trace_iters + spc - 1) // spc) * spc
-                        # dispatch anchor: a devprof capture counts these
-                        # spans so per-dispatch attribution never guesses
-                        # the iteration count from op repetitions (a
-                        # TraceMe no-op while no profiler is active)
-                        with _jax_profiler().TraceAnnotation(
-                                devprof.TRAIN_DISPATCH_SPAN):
-                            model.train_iter(count, self.recorder)
+                        # (devprof counts dispatches from the `train`
+                        # rows train_iter's recorder bracket leaves in
+                        # the span ring)
+                        model.train_iter(count, self.recorder)
                         if not fused:
                             self.exchanger.exchange(self.recorder, count)
                         watchdog.beat(f"epoch {epoch} iter {count}")
