@@ -55,6 +55,33 @@ def test_bsp_replicas_stay_identical():
             np.testing.assert_array_equal(leaf[w], leaf[0])
 
 
+def test_initial_params_leave_the_device_once_placed():
+    """``compile_iter_fns`` places the state from host copies, so the
+    initial parameters move to the host there: a device copy of them kept
+    through training is dead weight beside the step program (553 MB on
+    chip 0 for VGG-16 — PERF.md §6, PR 26).  Same values, same tree, and
+    a recompile starts from them again."""
+    leaves = jax.tree_util.tree_leaves
+    mesh = worker_mesh(2)
+    config = {"mesh": mesh, "size": 2, "rank": 0, "verbose": False,
+              "batch_size": 8}
+    model = TinyModel(config)
+    assert all(isinstance(p, jax.Array) for p in leaves(model.params))
+    drawn = jax.device_get(model.params)
+    for _ in range(2):
+        model.compile_iter_fns(BSP_Exchanger(config))
+        assert jax.tree.structure(model.params) == jax.tree.structure(drawn)
+        assert all(isinstance(p, np.ndarray) for p in leaves(model.params))
+        placed = jax.device_get(model.step_state["params"])
+        for p0, p, boxed in zip(leaves(drawn), leaves(model.params),
+                                leaves(placed)):
+            np.testing.assert_array_equal(p, p0)
+            for w in range(2):
+                np.testing.assert_array_equal(boxed[w], p0)
+        model.data.shuffle_data(0)
+        model.train_iter(1, None)
+
+
 def test_bsp_params_mode_exact_oracle():
     """Pin params-mode semantics exactly: each worker takes a LOCAL momentum
     step on its own shard's gradient, then parameters (not velocities) are

@@ -2,6 +2,7 @@
 Conv/Pool/LRN/FC/Dropout/Softmax/BatchNorm — SURVEY.md §2.7)."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -194,3 +195,140 @@ def test_batchnorm_bf16_norm_dtype_matches_fp32_path():
     np.testing.assert_allclose(np.asarray(yebf, np.float32),
                                np.asarray(ye32, np.float32),
                                rtol=0.05, atol=0.05)
+
+
+# -- Sequential: a max Pool directly after a ReLU layer runs before the ReLU --
+
+def plain_apply(seq, params, x, *, train=False, rng=None):
+    """``Sequential.apply`` in the layer list's own order (stateless layers)."""
+    for k, layer in zip(seq._keys, seq.layers):
+        sub = None
+        if rng is not None:
+            rng, sub = jax.random.split(rng)
+        x = layer.apply(params.get(k), x, train=train, rng=sub)
+    return x
+
+
+def pool_operand_makers(jaxpr):
+    """For every ``reduce_window_max`` of a jaxpr, calls inlined: the name of
+    the primitive that made its operand (None: an input of the jaxpr)."""
+    found = []
+    key = lambda v: v if isinstance(v, jax.extend.core.Var) else None
+
+    def walk(jaxpr, made):            # made: Var -> primitive that made it
+        for eqn in jaxpr.eqns:
+            subs = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                    if hasattr(getattr(v, "jaxpr", v), "eqns")]
+            if len(subs) == 1 and len(subs[0].invars) == len(eqn.invars):
+                sub = subs[0]                   # pjit, custom_jvp_call, ...
+                inner = walk(sub, {i: made.get(key(o))
+                                   for i, o in zip(sub.invars, eqn.invars)})
+                made.update({o: inner.get(key(s))
+                             for o, s in zip(eqn.outvars, sub.outvars)})
+                continue
+            if eqn.primitive.name == "reduce_window_max":
+                found.append(made.get(key(eqn.invars[0])))
+            made.update({o: eqn.primitive.name for o in eqn.outvars})
+        return made
+
+    walk(jaxpr, {})
+    return found
+
+
+def pool_before_relu_count():
+    from theanompi_tpu.utils import telemetry
+    return telemetry.totals().get("pool_before_relu", (0, 0))[0]
+
+
+def _tie_rich(shape, dtype, seed=0):
+    """Half-integers in [-2, 2]: ties and exact zeros in most windows, and a
+    corner where every window is all-negative."""
+    r = np.random.RandomState(seed)
+    x = np.round(r.randn(*shape) * 2) / 2
+    x = np.clip(x, -2, 2)
+    x[:, :5, :5, :] = -np.abs(x[:, :5, :5, :]) - 0.5
+    return jnp.asarray(x, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("size,stride,padding", [
+    (2, 2, "VALID"), (3, 2, "VALID"), (3, 2, "SAME"), (3, 1, "SAME")])
+def test_pool_before_relu_is_relu_before_pool_bit_for_bit(
+        size, stride, padding, dtype):
+    """An identity 1x1 convolution hands the pool the tie-rich input itself
+    as its pre-activation; forward and every gradient equal under ``==``."""
+    c = 3
+    seq = L.Sequential([
+        L.Conv(c, c, 1, compute_dtype=dtype, name="conv"),
+        L.Pool(size, stride, mode="max", padding=padding, name="pool")])
+    assert seq._pool_first == {0}
+    params = {"conv": {"w": jnp.eye(c, dtype=F32).reshape(1, 1, c, c),
+                       "b": jnp.zeros((c,), F32)}}
+    x = _tie_rich((2, 11, 11, c), dtype)
+    np.testing.assert_array_equal(
+        np.asarray(seq.layers[0].pre_activation(params["conv"], x), F32),
+        np.asarray(x, F32))
+    y_new = seq.apply(params, x)[0]
+    y_old = plain_apply(seq, params, x)
+    assert y_new.dtype == y_old.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(y_new, F32),
+                                  np.asarray(y_old, F32))
+    assert (np.asarray(y_old, F32) == 0).any()       # all-negative windows
+
+    w = jnp.asarray(np.random.RandomState(1).randn(*y_old.shape), dtype)
+    new = jax.grad(lambda p, x: jnp.sum(
+        (seq.apply(p, x)[0] * w).astype(F32)), (0, 1))(params, x)
+    old = jax.grad(lambda p, x: jnp.sum(
+        (plain_apply(seq, p, x) * w).astype(F32)), (0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, F32), np.asarray(b, F32))
+    assert (np.asarray(new[1], F32) != 0).any()
+
+
+def _conv(activation="relu"):
+    return L.Conv(2, 4, 3, activation=activation, compute_dtype=F32,
+                  name="conv")
+
+
+@pytest.mark.parametrize("front,expect,pool_reads", [
+    pytest.param([_conv(), L.Pool(3, 2, name="pool")], 1, "add",
+                 id="relu_conv_max_pool"),
+    pytest.param([_conv(), L.Pool(3, 2, mode="avg", name="pool")], 0, None,
+                 id="avg_pool"),
+    pytest.param([_conv("tanh"), L.Pool(3, 2, name="pool")], 0, "tanh",
+                 id="tanh_conv"),
+    pytest.param([_conv(), L.LRN(name="lrn"), L.Pool(3, 2, name="pool")],
+                 0, "mul", id="lrn_between"),
+    pytest.param([_conv(None), L.Activation("relu"),
+                  L.Pool(3, 2, name="pool")], 0, "max",
+                 id="relu_as_its_own_layer"),
+])
+def test_sequential_reorders_only_relu_then_max_pool(front, expect,
+                                                     pool_reads):
+    """The reorder is read off the layer list, counted once per pair where
+    the stack is built, and changes neither the parameter tree, nor the rng
+    each layer is handed (the dropout draw), nor any output."""
+    before = pool_before_relu_count()
+    seq = L.Sequential(front + [
+        L.Flatten(), L.Dropout(0.5, name="drop"),
+        L.FC(5 * 5 * 4, 5, activation=None, compute_dtype=F32, name="fc")])
+    assert len(seq._pool_first) == expect
+    assert pool_before_relu_count() - before == expect
+
+    params = seq.init(KEY)
+    assert {k: sorted(v) for k, v in params.items()} == {
+        "conv": ["b", "w"], "fc": ["b", "w"]}
+    x = _tie_rich((2, 11, 11, 2), F32, seed=2)
+    rng = jax.random.key(7)
+    for train in (False, True):
+        got = seq.apply(params, x, train=train, rng=rng)[0]
+        want = plain_apply(seq, params, x, train=train, rng=rng)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # what the max pool (and so its backward) takes as operand: the bias
+    # add where the pair was reordered, the ReLU's `max` where a ReLU layer
+    # of its own precedes it
+    fed_by = pool_operand_makers(jax.make_jaxpr(
+        lambda p, x: seq.apply(p, x)[0])(params, x).jaxpr)
+    assert fed_by == ([pool_reads] if pool_reads else [])

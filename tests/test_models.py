@@ -118,6 +118,58 @@ def test_resnet_bn_trains_under_async_rules():
     model.train_iter(0, None)
     assert np.isfinite(float(np.asarray(model.current_info["cost"])))
 
+# -- a max Pool directly after a ReLU layer runs before the ReLU --------------
+
+@pytest.mark.parametrize("modelfile,modelclass,pairs", [
+    ("theanompi_tpu.models.vggnet_16", "VGGNet_16", 5),
+    ("theanompi_tpu.models.vggnet_16", "VGGNet_11_shallow", 5),
+    ("theanompi_tpu.models.alex_net", "AlexNet", 1),       # conv5 -> pool5
+    ("theanompi_tpu.models.cifar10", "Cifar10_model", 3),
+    ("theanompi_tpu.models.googlenet", "GoogLeNet", 1),    # conv1 -> pool1
+    ("theanompi_tpu.models.resnet50", "ResNet50", 0),      # ConvBN: left
+])
+def test_pool_before_relu_count(modelfile, modelclass, pairs):
+    from test_layers import pool_before_relu_count
+    before = pool_before_relu_count()
+    _build(modelfile, modelclass, 16)
+    assert pool_before_relu_count() - before == pairs
+
+
+def test_vgg16_no_max_pool_reads_a_relu_output():
+    """The max-pool backward (select-and-scatter on the chip) takes its
+    operand unfused; fed a ReLU's output it forces a second copy of the
+    feature map beside the pre-activation (PERF.md §6, PR 26)."""
+    from test_layers import pool_operand_makers
+    model = _build("theanompi_tpu.models.vggnet_16", "VGGNet_16", 16)
+    batch = {"x": jax.ShapeDtypeStruct((2, 224, 224, 3), jnp.float32),
+             "y": jax.ShapeDtypeStruct((2,), jnp.int32)}
+    jaxpr = jax.make_jaxpr(
+        lambda p, b: model.loss_and_metrics(p, {}, b, jax.random.key(0),
+                                            True)[0])(model.params, batch)
+    assert pool_operand_makers(jaxpr.jaxpr) == ["add"] * 5
+
+
+def test_vgg_train_step_cost_equals_the_plain_order():
+    """Two train steps of VGG-11 at a fixed seed, as built and with the
+    layer list's own order: the same costs."""
+    costs = []
+    for plain in (False, True):
+        model = _build("theanompi_tpu.models.vggnet_16", "VGGNet_11_shallow",
+                       8, seed=3, learning_rate=1e-4)
+        assert len(model.seq._pool_first) == 5
+        if plain:
+            model.seq._pool_first = frozenset()
+        model.compile_iter_fns(BSP_Exchanger(model.config))
+        model.data.shuffle_data(0)
+        got = []
+        for i in range(2):
+            model.train_iter(i + 1, None)
+            got.append(np.asarray(model.current_info["cost"]))
+        costs.append(np.stack(got))
+    assert np.isfinite(costs[0]).all()
+    np.testing.assert_allclose(costs[0], costs[1], rtol=1e-6)
+
+
 # excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
 import pytest as _pytest
 pytestmark = _pytest.mark.slow

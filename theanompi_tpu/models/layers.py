@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import telemetry
+
 
 # ---------------------------------------------------------------------------
 # Weight: init schemes + save/load  (reference: layers2.Weight)
@@ -118,6 +120,18 @@ class Sequential:
             else:
                 seen[n] = 0
             self._keys.append(n)
+        # A ReLU layer directly before a max Pool runs as pre-activation ->
+        # pool -> ReLU.  relu(maxpool(x)) == maxpool(relu(x)) bit for bit,
+        # gradient included (max commutes with a non-decreasing function),
+        # but the max-pool backward (select-and-scatter) takes its operand
+        # unfused: fed the post-ReLU tensor it makes the compiler keep that
+        # beside the pre-activation every other consumer re-ReLUs on the
+        # fly — a second copy of every pooled feature map (PERF.md §6).
+        self._pool_first = frozenset(
+            i for i, (a, b) in enumerate(zip(layers, layers[1:]))
+            if isinstance(a, _Affine) and a.activation == "relu"
+            and isinstance(b, Pool) and b.mode == "max")
+        telemetry.count("pool_before_relu", len(self._pool_first))
 
     def init(self, key) -> Dict[str, Any]:
         params = {}
@@ -139,11 +153,14 @@ class Sequential:
     def apply(self, params, x, *, train=False, rng=None, state=None):
         state = state or {}
         new_state = dict(state)
-        for k, layer in zip(self._keys, self.layers):
+        for i, (k, layer) in enumerate(zip(self._keys, self.layers)):
             if rng is not None:
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
+            if i in self._pool_first:
+                x = layer.pre_activation(params[k], x)
+                continue
             y = layer.apply(params.get(k), x, train=train, rng=sub,
                             state=state.get(k))
             if layer.has_state:
@@ -152,14 +169,30 @@ class Sequential:
                     new_state[k] = st
             else:
                 x = y if not isinstance(y, tuple) else y[0]
+            if i - 1 in self._pool_first:
+                x = jax.nn.relu(x)
         return x, new_state
+
+
+class _Affine(Layer):
+    """``activation(pre_activation(x))``: :class:`Conv`,
+    :class:`ConvTranspose`, :class:`FC`.  :class:`Sequential` calls the two
+    halves apart where a max pool belongs between them."""
+
+    activation: Optional[str] = None
+
+    def pre_activation(self, params, x):
+        raise NotImplementedError
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        return _activate(self.pre_activation(params, x), self.activation)
 
 
 # ---------------------------------------------------------------------------
 # Conv  (reference: layers2.Conv on cuDNN; here lax conv on the MXU)
 # ---------------------------------------------------------------------------
 
-class Conv(Layer):
+class Conv(_Affine):
     def __init__(self, in_ch: int, out_ch: int, kernel: Union[int, Tuple[int, int]],
                  stride: Union[int, Tuple[int, int]] = 1,
                  padding: Union[str, int] = "SAME",
@@ -188,7 +221,7 @@ class Conv(Layer):
         b = init_weight(b_key, (self.out_ch,), self.b_init)
         return {"w": w, "b": b}
 
-    def apply(self, params, x, *, train=False, rng=None, state=None):
+    def pre_activation(self, params, x):
         cd = self.compute_dtype
         # No preferred_element_type here: with bf16 operands the MXU still
         # accumulates in fp32 internally, and requesting an fp32 output breaks
@@ -199,11 +232,10 @@ class Conv(Layer):
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             feature_group_count=self.groups,
         )
-        y = y + params["b"].astype(cd)
-        return _activate(y, self.activation)
+        return y + params["b"].astype(cd)
 
 
-class ConvTranspose(Layer):
+class ConvTranspose(_Affine):
     """Transposed (fractionally-strided) convolution — the DCGAN-style
     generator upsampler used by the reference's GAN models
     (``theanompi/models/wgan.py`` / ``lsgan.py``, SURVEY.md §2.7).  Lowered
@@ -231,18 +263,17 @@ class ConvTranspose(Layer):
         b = init_weight(b_key, (self.out_ch,), self.b_init)
         return {"w": w, "b": b}
 
-    def apply(self, params, x, *, train=False, rng=None, state=None):
+    def pre_activation(self, params, x):
         cd = self.compute_dtype
         y = jax.lax.conv_transpose(
             x.astype(cd), params["w"].astype(cd),
             strides=self.stride, padding=self.padding,
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
         )
-        y = y + params["b"].astype(cd)
-        return _activate(y, self.activation)
+        return y + params["b"].astype(cd)
 
 
-class FC(Layer):
+class FC(_Affine):
     """Fully connected layer (reference: layers2.FC / Softmax head matmul)."""
 
     def __init__(self, n_in: int, n_out: int,
@@ -260,11 +291,10 @@ class FC(Layer):
         return {"w": init_weight(kw, (self.n_in, self.n_out), self.w_init),
                 "b": init_weight(kb, (self.n_out,), self.b_init)}
 
-    def apply(self, params, x, *, train=False, rng=None, state=None):
+    def pre_activation(self, params, x):
         cd = self.compute_dtype
         y = jnp.dot(x.astype(cd), params["w"].astype(cd))
-        y = y + params["b"].astype(cd)
-        return _activate(y, self.activation)
+        return y + params["b"].astype(cd)
 
 
 class Pool(Layer):

@@ -9,6 +9,10 @@ the contract once over the TPU step machinery; concrete models
 (``cifar10.py``, ``alex_net.py``, ...) only define their layer stack, data
 object, and hyperparameters — mirroring how reference model files were layer
 lists plus a module-level hyperparameter dict.
+
+``params`` is the INITIAL parameter tree: device arrays as drawn, a host
+(numpy) tree of the same values once ``compile_iter_fns`` has placed the
+state.  The parameters being trained are ``step_state["params"]``.
 """
 
 from __future__ import annotations
@@ -396,6 +400,12 @@ class ModelBase:
         self.exchanger.prepare(self.mesh, self)
         n = self.mesh.shape[WORKER_AXIS]
 
+        # the state below is placed from host copies (replicate_tree,
+        # chunk_host), and every later reader of self.params wants shapes
+        # or a host tree: pull the initial parameters once and let the
+        # device copy go, instead of holding it on chip 0 through training
+        # (553 MB for VGG-16, the room of two staged batches)
+        self.params = jax.device_get(self.params)
         extra = self.exchanger.extra_state_template()
         if self._fsdp is not None:
             # optimizer state lives on THIS worker's flat chunk (identical

@@ -83,11 +83,10 @@ class RowFC(L.FC):
         super().__init__(*args, **kwargs)
         self.axis = axis
 
-    def apply(self, params, x, *, train=False, rng=None, state=None):
+    def pre_activation(self, params, x):
         cd = self.compute_dtype
         y = jnp.dot(x.astype(cd), params["w"].astype(cd))
-        y = lax.psum(y, self.axis) + params["b"].astype(cd)
-        return L._activate(y, self.activation)
+        return lax.psum(y, self.axis) + params["b"].astype(cd)
 
 
 class TPMultiHeadAttention(L.MultiHeadAttention):

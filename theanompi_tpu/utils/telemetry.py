@@ -90,7 +90,8 @@ SPANS = (
     "compile.place",                               # compile_iter_fns
     "compile.xla", "compile.cache_load",           # jax.monitoring
 )
-COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put")
+COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
+          "pool_before_relu")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
