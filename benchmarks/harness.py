@@ -163,6 +163,12 @@ class Run:
     tables: Optional[trace_lib.TraceTables] = None
     trace_window: Optional[trace_lib.Interval] = None   # the traced stretch
     host_rows: List[trace_lib.Row] = field(default_factory=list)  # Unix ns
+    # --trace 1 only: instruction name -> op_name of the train program
+    scopes: Dict[str, str] = field(default_factory=dict)
+    scope_join_error: Optional[str] = None    # why `scopes` is empty, if so
+    # seconds of what runs once the window has closed and the memory is
+    # read (the scope join, the reference's training steps): in no metric
+    after_window_s: Dict[str, float] = field(default_factory=dict)
     attempted: int = 0
     failed: int = 0
     compiles_in_window: int = 0
@@ -171,6 +177,8 @@ class Run:
     reference: Dict[str, Any] = field(default_factory=dict)
     first_cost: float = float("nan")
     problems: List[str] = field(default_factory=list)   # why not `correct`
+    # every number `correct` compared, beside its limit: name -> [value, limit]
+    compared: Dict[str, List[float]] = field(default_factory=dict)
 
     @property
     def correct(self) -> bool:
@@ -290,10 +298,15 @@ class _Loop:
 
 def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
              trace: bool, t_process_start: Optional[float] = None,
-             early_phases: Optional[Dict[str, float]] = None) -> Run:
+             early_phases: Optional[Dict[str, float]] = None,
+             control: Optional[dict] = None) -> Run:
     """Set up the cell, warm it up, measure a window of ``seconds``.
     ``early_phases`` is what the caller timed between ``t_process_start``
-    and this call (``run.py``: the imports, the runtime's start-up)."""
+    and this call (``run.py``: the imports, the runtime's start-up).
+    ``control`` is worker configuration laid over the cell's: the tests and
+    ``readings.py`` run the cell's control with it (``compute_dtype`` one
+    precision down, a model class with a fault planted); ``run.py`` has no
+    way to pass it."""
     t_start = time.time() if t_process_start is None else t_process_start
     phases: Dict[str, float] = dict(early_phases or {})
     last = [t_start + sum(phases.values())]
@@ -329,9 +342,12 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
     config = dict(cell.config.get("worker_config", {}))
     config.update(cell.traffic["worker_config"])
     config.update(n_workers=cell.chips, seed=int(seed))
+    control = dict(control or {})
+    modelfile = control.pop("modelfile", cell.config["modelfile"])
+    modelclass = control.pop("modelclass", cell.config["modelclass"])
+    config.update(control)
     worker = WORKERS[config.get("rule", "bsp")](config)
-    model = worker.build_model(cell.config["modelfile"],
-                               cell.config["modelclass"])
+    model = worker.build_model(modelfile, modelclass)
     phase("build_model")
     compiles = _CompileCounter()
     try:
@@ -343,9 +359,16 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
             model.scale_lr(worker.size)
         phase("compile_iter_fns")
 
-        run.reference = reference_check(ref_mod, cell.config, model, seed)
-        if not run.reference["ok"]:
-            run.problems.append(f"reference check failed: {run.reference}")
+        # a reference with a training objective is held against the timed
+        # path's own first steps, once the window has closed; one without
+        # against the evaluation-mode forward pass, here, as before
+        follows = getattr(ref_mod, "train_loss", None) is not None
+        if not follows:
+            run.reference = reference_check(ref_mod, cell.config, model,
+                                            seed)
+            if not run.reference["ok"]:
+                run.problems.append(
+                    f"reference check failed: {run.reference}")
         phase("reference_check")
 
         loop = _Loop(run, worker, model)
@@ -353,13 +376,18 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
         run.global_batch = int(model.data.global_batch)
         model.adjust_hyperp(0)
         model.data.shuffle_data(0 + model.seed)
+        first = FirstSteps(model, loop.spc) if follows else None
 
         # -- warm-up: the first step compiles, or loads from the cache
         loop.dispatch()
         run.first_cost = float(loop.costs[0])
+        if first is not None:
+            first.after(1)
         phase("first_step")
-        for _ in range(WARMUP_STEPS - 1):
+        for done in range(2, WARMUP_STEPS + 1):
             loop.dispatch()
+            if first is not None:
+                first.after(done)
         jax.block_until_ready(model.step_state)
         n_warm = len(loop.costs)
         phase("warmup")
@@ -377,6 +405,22 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
         run.compiles_in_window = compiles.between(opened[0],
                                                   time.perf_counter())
         _after_window(run, loop, model, n_warm)
+        # after the clock, the window and the memory reading
+        if trace:
+            t0 = time.time()
+            run.scopes, run.scope_join_error = train_scopes(
+                model, worker.exchanger)
+            run.after_window_s["scope_join"] = time.time() - t0
+        if first is not None:
+            t0 = time.time()
+            model.step_state = None     # the reference wants the room
+            run.reference = train_check(ref_mod, cell, first, loop.costs,
+                                        config)
+            run.after_window_s["train_check"] = time.time() - t0
+            if not run.reference["ok"]:
+                run.problems.append(
+                    f"the first steps left the reference: {run.reference}")
+            run.compared.update(_train_compared(run.reference))
     finally:
         compiles.close()
         # the prefetcher's producer must not outlive the run (it would
@@ -427,6 +471,8 @@ def _after_window(run: Run, loop: _Loop, model, n_warm: int) -> None:
     import jax
     import numpy as np
 
+    from benchmarks.reference import check
+
     cell = run.cell
     stats = [d.memory_stats() for d in model.mesh.devices.flat]
     if all(s and "peak_bytes_in_use" in s for s in stats):
@@ -449,7 +495,42 @@ def _after_window(run: Run, loop: _Loop, model, n_warm: int) -> None:
                             f"inside the window")
     if run.window.steps == 0:
         run.problems.append("no step completed inside the window")
-    run.problems += layout_problems(model, cell)
+    layout = layout_problems(model, cell)
+    run.problems += layout
+    ref = run.reference
+    if ref:         # the forward check of set-up; the training one follows
+        run.compared = {
+            "logit_rel_err": [ref["logit_rel_err"], check.LOGIT_REL_TOL],
+            "loss_err": [ref["loss_err"], ref["loss_tol"]]}
+    if tol is not None:
+        run.compared["first_cost_gap"] = [
+            abs(run.first_cost - math.log(n_class)), tol]
+    run.compared.update(
+        costs_not_finite=[int((~finite).sum()), 0],
+        steps_raised=[loop.raised, 0],
+        compiles_in_window=[run.compiles_in_window, 0],
+        layout_faults=[len(layout), 0])
+
+
+def train_scopes(model, exchanger):
+    """``(scopes, error)``: instruction name -> ``op_name`` of the compiled
+    train program, from the ``Compiled``'s own text where the program holds
+    one, else ``train_fn`` lowered with the model's own avals and compiled
+    again, which is a load from the cache the first step filled.  The join
+    is an aid to reading the trace: if the text cannot be had the run goes
+    on without scopes, and the trace line's ``device`` says why."""
+    import traceback
+
+    fn = model.train_fn
+    try:
+        if not hasattr(fn, "as_text"):
+            spc = max(1, int(getattr(model, "steps_per_call", 1)))
+            fn = fn.lower(*model._train_input_avals(spc, exchanger)).compile()
+        return trace_lib.scopes_from_hlo(fn.as_text()), None
+    except Exception as e:
+        print("benchmarks: no compiled text for the scope join:\n"
+              + traceback.format_exc(), file=sys.stderr)
+        return {}, repr(e)
 
 
 def layout_problems(model, cell: Cell) -> List[str]:
@@ -478,15 +559,19 @@ def layout_problems(model, cell: Cell) -> List[str]:
 
 
 def reference_check(ref_mod, config: dict, model, seed: int) -> dict:
-    """The system's evaluation-mode logits and loss against the plain
-    float32 reference, same parameters, a seeded batch of 8 samples."""
+    """The system's evaluation-mode forward pass against the plain float32
+    reference, same parameters, a seeded batch: the reference's own
+    (``batch(config, rng)``), else 8 image crops."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.reference import check
 
-    x, y = check.image_batch(config, np.random.RandomState(seed), 8)
+    rng = np.random.RandomState(seed)
+    make_batch = getattr(ref_mod, "batch", None)
+    x, y = make_batch(config, rng) if make_batch is not None \
+        else check.image_batch(config, rng, 8)
     params = model.canonical_host_params()
     bn = model.bn_state
 
@@ -505,6 +590,93 @@ def reference_check(ref_mod, config: dict, model, seed: int) -> dict:
         ref_logits, ref_loss = jax.jit(reference)(params, x, y)
     return check.compare(np.asarray(ref_logits), np.asarray(sys_logits),
                          float(ref_loss), float(sys_loss))
+
+
+def _replica0(tree):
+    """Host copy of the first replica of a boxed ``[n_workers, ...]`` tree."""
+    import jax
+    import numpy as np
+
+    return jax.tree.map(lambda x: np.asarray(x)[0], tree)
+
+
+class FirstSteps:
+    """What the timed path took and gave in its first steps, kept for the
+    reference to follow once the window has closed: the batches the loader
+    fed (it is tapped for those steps and let go again), the optimizer's
+    first moment after one step, the parameters after the last.  The steps
+    themselves go through the window's own ``dispatch``."""
+
+    def __init__(self, model, steps_per_call: int):
+        from benchmarks.reference import check
+
+        if steps_per_call != 1:
+            raise Refused("the training comparison follows single steps; "
+                          f"the traffic sets steps_per_call {steps_per_call}")
+        self.model, self.n = model, check.TRAIN_STEPS
+        self.params0 = model.params     # the host tree the state was placed
+        self.batches: List[Any] = []    # from, not the program's state
+        self.first_moment = self.params = None
+        feed = model.data.next_train_batch
+
+        def tapped(count):
+            batch = feed(count)
+            self.batches.append(batch)
+            return batch
+
+        model.data.next_train_batch = tapped
+
+    def after(self, step: int) -> None:
+        import numpy as np
+
+        state = self.model.step_state
+        if step == 1:
+            moments = state["opt_state"]
+            if not isinstance(moments, dict) or "m" not in moments:
+                raise Refused("the training comparison reads Adam's first "
+                              "moment; the program's optimizer keeps none")
+            self.first_moment = _replica0(moments["m"])
+        if step == self.n:
+            self.params = _replica0(state["params"])
+            del self.model.data.next_train_batch        # the tap
+            self.batches = [(np.asarray(b["x"]), np.asarray(b["y"]))
+                            for b in self.batches]
+
+
+def train_check(ref_mod, cell: Cell, first: FirstSteps, costs,
+                worker_config: dict) -> dict:
+    """The reference follows the timed path's first steps and the two are
+    compared (``check.compare_steps``).  The optimizer and its learning
+    rate are what the configuration file states under ``check.optimizer``,
+    scaled by the chips as the harness scales the program's."""
+    import jax
+    import numpy as np
+
+    from benchmarks.reference import check, plain_opt
+
+    stated = cell.config.get("check", {})
+    optimizer = stated.get("optimizer")
+    if optimizer is None:
+        raise Refused("a reference with train_loss needs check.optimizer "
+                      "in its configuration file")
+    lr = float(optimizer["learning_rate"])
+    if worker_config.get("scale_lr", True) and cell.chips > 1:
+        lr *= cell.chips
+    got = {"losses": [float(c) for c in
+                      np.asarray(jax.device_get(costs[:first.n]))],
+           "first_grad": plain_opt.first_gradient(
+               first.first_moment, plain_opt.hyper(optimizer)["b1"]),
+           "params": first.params}
+    ref = check.follow_steps(ref_mod.train_loss, first.params0,
+                             first.batches, cell.chips, lr, optimizer)
+    return check.compare_steps(ref, got, first.params0,
+                               stated.get("grad_leaves", ()))
+
+
+def _train_compared(ref: dict) -> Dict[str, List[float]]:
+    return {name: [ref[name], ref[tol]] for name, tol in (
+        ("grad_norm_gap", "grad_norm_tol"),
+        ("change_norm_gap", "change_norm_tol"))}
 
 
 # ---------------------------------------------------------------------------
@@ -531,12 +703,22 @@ def result_line(manifest: dict, run: Run, trace: bool) -> dict:
     device = dict(run.device, memory_peak_bytes=run.memory_peak_bytes)
     line = {"correct": run.correct, "attempted": run.attempted,
             "failed": run.failed, "metrics": {}, "device": device}
-    if not on_chip:
-        return line
+    if on_chip:
+        _device_metrics(manifest, run, trace, line)
+    # the last key of the line; a reading that is no number (a NaN cost)
+    # goes as null, so that the line stays JSON
+    line["checks"] = {k: [v if math.isfinite(v) else None, limit]
+                      for k, (v, limit) in run.compared.items()}
+    return line
+
+
+def _device_metrics(manifest: dict, run: Run, trace: bool,
+                    line: dict) -> None:
+    device = line["device"]
     if not trace:
         line["metrics"] = read_metrics(manifest, run.cell.end_to_end,
                                        "end_to_end", run)
-        return line
+        return
     line["metrics"] = read_metrics(manifest, run.cell.per_layer,
                                    "layer_metrics", run)
     t, window = run.tables, run.trace_window
@@ -544,24 +726,30 @@ def result_line(manifest: dict, run: Run, trace: bool) -> dict:
         run.problems.append("the trace holds no device plane or no start "
                             "time")
         line["correct"] = False
-        return line
+        return
     used = t.devices[:run.cell.chips]
     device["busy_s"] = sum(trace_lib.busy_ns(d.ops, window)
                            for d in used) / len(used) / 1e9
     device["window_s"] = (window[1] - window[0]) / 1e9
+    if run.scopes:
+        device["unscoped_share"] = trace_lib.unscoped_share(
+            t.devices[0].ops, window, run.scopes)
+    elif run.scope_join_error:
+        device["scope_join_error"] = run.scope_join_error
     line["breakdown"] = breakdown(run)
-    return line
 
 
 def breakdown(run: Run) -> dict:
-    """Chip 0: the instructions that took most time, and the idle time by
-    what the host was doing (harness span, and inside ``train_iter`` the
-    recorder bracket)."""
+    """Chip 0: the instructions that took most time, each with the tail of
+    its ``op_name`` where the scope join knows it, and the idle time by what
+    the host was doing (harness span, and inside ``train_iter`` the recorder
+    bracket)."""
     t, window = run.tables, run.trace_window
     dev = t.devices[0]
     host = [(name, t.on_trace_clock(s), t.on_trace_clock(e))
             for name, s, e in run.host_rows]
     busy = trace_lib.union((s, e) for _, s, e in dev.ops)
-    return {"device_ops": trace_lib.top_device_ops(dev.ops, window),
+    return {"device_ops": trace_lib.top_device_ops(dev.ops, window,
+                                                   scopes=run.scopes),
             "idle_gaps": trace_lib.attribute_gaps(
                 trace_lib.gaps(busy, window), host)}

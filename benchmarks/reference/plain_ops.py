@@ -61,7 +61,11 @@ def fc(x, p, relu=True):
 
 
 def softmax_loss(logits, labels):
-    """Mean negative log-likelihood of integer ``labels``."""
+    """Mean negative log-likelihood of integer ``labels``.  Logits of any
+    rank: the leading axes are flattened (``[B, T, V]`` with ``[B, T]``
+    labels is the mean over all ``B * T`` positions)."""
+    logits = logits.reshape(-1, logits.shape[-1])
+    labels = labels.reshape(-1)
     logz = jnp.log(jnp.sum(jnp.exp(logits - logits.max(-1, keepdims=True)),
                            axis=-1)) + logits.max(-1)
     picked = logits[jnp.arange(logits.shape[0]), labels]

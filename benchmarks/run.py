@@ -74,7 +74,12 @@ def main(argv=None) -> int:
                       "first_cost": run.first_cost,
                       "window": vars(run.window),
                       "traced": run.traced and vars(run.traced),
+                      "after_window_s": run.after_window_s,
                       "problems": run.problems}), file=sys.stderr)
+    # every number `correct` compared beside its limit: the last lines here
+    for name, (value, limit) in run.compared.items():
+        print(f"check {name} {value!r} limit {limit!r}", file=sys.stderr)
+    sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
     return 0

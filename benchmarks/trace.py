@@ -18,6 +18,13 @@ every timestamp and keeps that start, in Unix nanoseconds, as the stat
 ``profile_start_time`` of the plane ``Task Environment``; a host
 ``time.time_ns()`` minus it is a time on the trace's clock.
 
+An event says nothing of the layer its instruction belongs to: that is in
+the compiled program's HLO text, where an instruction's ``metadata`` holds
+the ``op_name`` jax gave it, the path of transforms, named scopes and the
+primitive (``jit(per_worker)/transpose(jvp(mla))/dot_general``).
+:func:`scopes_from_hlo` makes the join PR 26 made by hand, instruction name
+to ``op_name``; :func:`scope_busy_ns` reads a named scope's device time.
+
 All times are integer nanoseconds on the trace's clock.  An interval is a
 ``(start, end)`` pair, a table row ``(name, start, end)``.
 """
@@ -140,6 +147,178 @@ def collective_base(op: str) -> Optional[str]:
         if op in (base, base + "-start", base + "-done"):
             return base
     return None
+
+
+# ---------------------------------------------------------------------------
+# scopes: the compiled program's text joined to the trace's instructions
+# ---------------------------------------------------------------------------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%?[\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?(%?[\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'metadata=\{[^{}]*?op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=(%?[\w.\-]+)")
+_OPERAND = re.compile(r"%[\w.\-]+")
+_OUTER = re.compile(r"^(p?jit\([^()]*\)|shard_map)$")   # the program's wrap
+TAIL_CHARS = 64
+
+
+def scopes_from_hlo(text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` over every computation of a compiled
+    module's text (names are unique in a module; the ``%`` is dropped).  A
+    fusion that carries no ``op_name`` of its own gets its root's, that is
+    the one of the fused computation's ``ROOT`` or, where the root is a
+    tuple or a bitcast the compiler made, of the nearest instruction behind
+    it that has one.  An instruction with none is left out: unscoped."""
+    own: Dict[str, str] = {}            # instruction -> its own op_name
+    calls: Dict[str, str] = {}          # fusion -> computation it calls
+    operands: Dict[str, List[str]] = {}
+    roots: Dict[str, str] = {}          # computation -> its ROOT
+    comp = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group(1).lstrip("%")
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(2).lstrip("%")
+        if m.group(1) and comp is not None:
+            roots[comp] = name
+        body = line[m.end():]
+        op = _OP_NAME.search(body)
+        if op:
+            own[name] = op.group(1).replace("\\'", "'")
+        called = _CALLS.search(body)
+        if called:
+            calls[name] = called.group(1).lstrip("%")
+        operands[name] = [o.lstrip("%") for o in _OPERAND.findall(
+            body.split(", metadata=")[0])]
+
+    def behind(name: str) -> Optional[str]:
+        """The op_name of ``name`` or of the nearest instruction behind it
+        (breadth first over operands, a few hops)."""
+        seen, front = {name}, [name]
+        for _ in range(4):
+            found = next((own[n] for n in front if n in own), None)
+            if found is not None:
+                return found
+            front = list(dict.fromkeys(
+                o for n in front for o in operands.get(n, ())
+                if o not in seen))
+            seen.update(front)
+        return None
+
+    scopes = dict(own)
+    for name, comp in calls.items():
+        if name not in scopes and comp in roots:
+            found = behind(roots[comp])
+            if found is not None:
+                scopes[name] = found
+    return scopes
+
+
+def _split_path(op_name: str) -> List[str]:
+    """The path cut at the ``/`` that stand outside parentheses."""
+    parts, depth, at = [], 0, 0
+    for i, ch in enumerate(op_name + "/"):
+        depth += ch == "("
+        depth -= ch == ")"
+        if ch == "/" and depth == 0:
+            parts.append(op_name[at:i])
+            at = i + 1
+    return parts
+
+
+def path_parts(op_name: str) -> List[Tuple[Tuple[str, ...], str]]:
+    """``jit(f)/transpose(jvp(mla))/dot_general`` -> ``[(("jit",), "f"),
+    (("transpose", "jvp"), "mla"), ((), "dot_general")]``: the path cut at
+    the ``/`` outside parentheses, each part with its transform wrappers
+    stripped."""
+    out = []
+    for part in _split_path(op_name):
+        wrappers = []
+        while True:
+            m = re.match(r"^(\w+)\((.*)\)$", part)
+            if not m:
+                break
+            wrappers.append(m.group(1))
+            part = m.group(2)
+        out.append((tuple(wrappers), part))
+    return out
+
+
+def _way(parts) -> str:
+    wrappers = {w for ws, _ in parts for w in ws}
+    return "backward" if "transpose" in wrappers \
+        else "forward" if "jvp" in wrappers else "other"
+
+
+def direction(op_name: str) -> str:
+    """``backward`` where a part of the path is under ``transpose`` (the
+    transposed linearisation, rematerialised forward work included),
+    ``forward`` where one is under ``jvp`` and none under ``transpose``,
+    else ``other``: the update, the exchange, what no gradient passes."""
+    return _way(path_parts(op_name))
+
+
+def in_scope(op_name: str, component: str,
+             way: Optional[str] = None) -> bool:
+    """Whether ``component`` is one of the path's parts once the wrappers
+    are stripped (``jvp(mla)`` and ``transpose(jvp(mla))`` both belong to
+    ``mla``) and, where ``way`` is given, the path runs that direction."""
+    parts = path_parts(op_name)
+    return any(inner == component for _, inner in parts) \
+        and (way is None or _way(parts) == way)
+
+
+def scope_of(scopes: Dict[str, str], event_name: str) -> Optional[str]:
+    """The ``op_name`` of a trace event (named by its instruction text)."""
+    return scopes.get(instruction_name(event_name).lstrip("%"))
+
+
+def scope_busy_ns(ops: Iterable[Row], window: Interval,
+                  scopes: Dict[str, str], component: str,
+                  direction: Optional[str] = None) -> int:
+    """Nanoseconds of ``window`` in which an instruction of the named scope
+    ran: the union of the intervals, not their sum."""
+    members = {name for name, op_name in scopes.items()
+               if in_scope(op_name, component, direction)}
+    return busy_ns([r for r in ops
+                    if instruction_name(r[0]).lstrip("%") in members],
+                   window)
+
+
+def unscoped_share(ops: Sequence[Row], window: Interval,
+                   scopes: Dict[str, str]) -> Optional[float]:
+    """The share of the busy time in which only instructions that the join
+    found no ``op_name`` for ran."""
+    busy = busy_ns(ops, window)
+    if not busy:
+        return None
+    scoped = busy_ns([r for r in ops if scope_of(scopes, r[0])], window)
+    return 1.0 - scoped / busy
+
+
+def scope_tail(op_name: str, room: int) -> str:
+    """The end of the path in at most ``room`` characters: the leading
+    ``jit(...)`` and ``shard_map`` parts dropped (every instruction of the
+    program has them), then whole parts between the first, where it
+    carries a transform (it says forward from backward), and the last (the
+    primitive), ``..`` in their place."""
+    parts = _split_path(op_name)
+    while len(parts) > 1 and _OUTER.match(parts[0]):
+        parts.pop(0)
+    tail = "/".join(parts)
+    if len(tail) <= room:
+        return tail
+    head = [parts.pop(0)] if "(" in parts[0] and len(parts) > 1 else []
+    while len(parts) > 1 and len("/".join(head + [".."] + parts)) > room:
+        parts.pop(0)
+    cut = "/".join(head + [".."] + parts)
+    # nothing between to drop, or still too long: from the left, so that
+    # the direction stays
+    return cut if len(cut) <= room else "/".join(head + parts[-1:])[:room]
 
 
 # ---------------------------------------------------------------------------
@@ -313,18 +492,28 @@ def exposed_collective_ns(ops: Sequence[Row]) -> int:
 # breakdown
 # ---------------------------------------------------------------------------
 
-def top_device_ops(ops: Iterable[Row], window: Interval,
-                   n: int = 10) -> List[List]:
+def top_device_ops(ops: Iterable[Row], window: Interval, n: int = 10,
+                   scopes: Optional[Dict[str, str]] = None) -> List[List]:
     """``[[name, seconds], ...]``: the instructions that took most device
-    time, grouped by opcode and the instruction's own name."""
+    time, grouped by opcode and the instruction's own name; where the join
+    knows the instruction, the name ends in the tail of its ``op_name``
+    (``fusion %fusion.573 transpose(jvp())/conv_general_dilated``), the
+    whole at most ``TAIL_CHARS`` characters."""
     by: Dict[str, int] = {}
     for name, s, e in ops:
         lo, hi = max(s, window[0]), min(e, window[1])
         if hi > lo:
             key = f"{opcode(name)} {instruction_name(name)}"
             by[key] = by.get(key, 0) + hi - lo
+
+    def with_tail(key: str) -> str:
+        scope = (scopes or {}).get(key.split(" %", 1)[-1])
+        room = TAIL_CHARS - len(key) - 1
+        return f"{key} {scope_tail(scope, room)}" if scope and room > 0 \
+            else key
+
     top = sorted(by.items(), key=lambda kv: -kv[1])[:n]
-    return [[k, v / 1e9] for k, v in top]
+    return [[with_tail(k), v / 1e9] for k, v in top]
 
 
 def attribute_gaps(idle: Sequence[Interval], host: Sequence[Row],

@@ -1,6 +1,7 @@
 """BENCHMARK.json against the contract it is written to, and against the
 files it names."""
 
+import copy
 import os
 import re
 
@@ -88,16 +89,152 @@ def test_every_cells_files_exist_and_load(manifest):
                                                     m["name"]).read)
 
 
+# Size keys that are widths, by the names configurations use (the catalog's
+# and the program's): a cut may take depth, experts held, heads held or rows
+# of the vocabulary, never one of these.
+WIDTH_KEYS = {
+    "hidden_size", "d_model", "n_embd", "dim", "intermediate_size",
+    "moe_intermediate_size", "ffn_hidden_size", "d_ff", "mlp_ratio",
+    "expansion_factor", "expand", "head_dim", "head_size", "d_head",
+    "d_state", "state_size", "d_conv", "conv_kernel", "sliding_window",
+    "num_experts_per_tok", "experts_per_token", "moe_topk", "top_k",
+    "input_hw", "input_channels"}
+WIDTH_PARTS = ("intermediate", "latent", "state_size", "proj_")
+WIDTH_ENDS = ("_dim", "_rank", "_width", "hidden_size", "head_size")
+
+
+def is_width(key: str) -> bool:
+    return key in WIDTH_KEYS or key.endswith(WIDTH_ENDS) \
+        or any(part in key for part in WIDTH_PARTS)
+
+
+def check_config(entry: dict, cfg: dict) -> None:
+    """A manifest entry against its configuration file: the same source and
+    the same ``reduced``; every reduced key held in the file beside the
+    source's value under ``published``; the deployment the cut stands for
+    in one line; no width cut."""
+    assert cfg["source"] == entry["source"]
+    assert cfg["reduced"] == entry["reduced"]
+    reduced = cfg["reduced"]
+    assert isinstance(reduced, list) and len(reduced) <= 16
+    assert len(set(reduced)) == len(reduced)
+    for key in reduced:
+        assert NAME.match(key), key
+        assert not is_width(key), f"{key} is a width: never cut"
+        assert key in cfg, f"{key} is reduced but not in the file"
+        assert key in cfg.get("published", {}), \
+            f"{key} is reduced but the source's value is not stated"
+        assert cfg[key] != cfg["published"][key], \
+            f"{key} is listed as reduced and equals the source's"
+    if reduced:
+        assert _one_line(cfg.get("deployment", "")), \
+            "a cut configuration states the deployment it stands for"
+    else:
+        assert "published" not in cfg or cfg["published"] == {}
+
+
 def test_configs_are_used_published_and_unreduced(manifest):
+    """Every configuration is used and its ``reduced`` is held honest
+    (``check_config``); ``vgg16`` is whole."""
     used = {w["config"] for w in manifest["workloads"]}
     files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
     for c in manifest["configs"]:
         assert c["name"] in used
         assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
-        cfg = harness.load_json(os.path.join(harness.ROOT, c["file"]))
-        assert cfg["source"] == c["source"]
-        assert cfg["reduced"] == c["reduced"] == []
+        check_config(c, harness.load_json(os.path.join(harness.ROOT,
+                                                       c["file"])))
+    vgg = next(c for c in manifest["configs"] if c["name"] == "vgg16")
+    assert vgg["reduced"] == []
+
+
+def test_the_toy_configurations_pass_whole_and_cut(toy_lm_manifest):
+    by = {c["name"]: c for c in toy_lm_manifest["configs"]}
+    assert by["toy"]["reduced"] == [] and by["toy_lm"]["reduced"]
+    for c in by.values():
+        check_config(c, harness.load_json(os.path.join(harness.ROOT,
+                                                       c["file"])))
+
+
+def _cut(toy_lm_manifest):
+    entry = copy.deepcopy(next(c for c in toy_lm_manifest["configs"]
+                               if c["name"] == "toy_lm"))
+    return entry, harness.load_json(os.path.join(harness.ROOT,
+                                                 entry["file"]))
+
+
+def _no_published(entry, cfg):
+    del cfg["published"]
+
+
+def _one_value_not_published(entry, cfg):
+    del cfg["published"]["vocab"]
+
+
+def _key_not_in_the_file(entry, cfg):
+    del cfg["moe_experts"]
+
+
+def _a_width(entry, cfg):
+    for c in (entry, cfg):
+        c["reduced"] = c["reduced"] + ["d_model"]
+    cfg["published"]["d_model"] = 2048
+
+
+def _an_unnamed_width(entry, cfg):
+    for c in (entry, cfg):
+        c["reduced"] = c["reduced"] + ["kv_lora_rank"]
+    cfg["kv_lora_rank"], cfg["published"]["kv_lora_rank"] = 8, 512
+
+
+def _experts_per_token(entry, cfg):
+    for c in (entry, cfg):
+        c["reduced"] = c["reduced"] + ["moe_topk"]
+    cfg["published"]["moe_topk"] = 8
+
+
+def _manifest_says_less(entry, cfg):
+    entry["reduced"] = entry["reduced"][:-1]
+
+
+def _manifest_says_whole(entry, cfg):
+    entry["reduced"] = []
+
+
+def _no_deployment(entry, cfg):
+    del cfg["deployment"]
+
+
+def _listed_but_unchanged(entry, cfg):
+    cfg["published"]["n_layer"] = cfg["n_layer"]
+
+
+def _another_source(entry, cfg):
+    entry["source"] = "https://example.org/another"
+
+
+@pytest.mark.parametrize("break_it", [
+    _no_published, _one_value_not_published, _key_not_in_the_file, _a_width,
+    _an_unnamed_width, _experts_per_token, _manifest_says_less,
+    _manifest_says_whole, _no_deployment, _listed_but_unchanged,
+    _another_source], ids=lambda f: f.__name__.strip("_"))
+def test_a_dishonest_reduced_fails(toy_lm_manifest, break_it):
+    entry, cfg = _cut(toy_lm_manifest)
+    check_config(entry, cfg)
+    break_it(entry, cfg)
+    with pytest.raises(AssertionError):
+        check_config(entry, cfg)
+
+
+@pytest.mark.parametrize("key,width", [
+    ("hidden_size", True), ("moe_intermediate_size", True),
+    ("q_lora_rank", True), ("qk_rope_head_dim", True), ("v_head_dim", True),
+    ("num_experts_per_tok", True), ("mlp_ratio", True), ("d_model", True),
+    ("num_hidden_layers", False), ("n_layer", False), ("vocab_size", False),
+    ("vocab", False), ("n_routed_experts", False), ("moe_experts", False),
+    ("num_attention_heads", False)])
+def test_which_keys_are_widths(key, width):
+    assert is_width(key) is width
 
 
 def test_every_cell_reports_setup_another_metric_and_a_layer(manifest):

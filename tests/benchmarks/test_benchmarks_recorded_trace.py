@@ -96,3 +96,105 @@ def test_breakdown_names_instructions(recorded):
     top = trace.top_device_ops(tables.devices[0].ops, window)
     assert len(top) == 10 and top[0][1] >= top[-1][1] > 0
     assert all(" %" in name for name, _ in top)
+
+
+# -- the scope join: the recorded instructions against a compiled text --------------
+
+SCOPED = {   # instruction -> (op_name, how the join finds it)
+    "broadcast_maximum_fusion":
+        "jit(per_worker)/jvp()/conv_general_dilated",           # its own
+    "select_and_scatter.54":
+        "jit(per_worker)/transpose(jvp())/select_and_scatter",  # its own
+    "fusion.606":
+        "jit(per_worker)/transpose(jvp())/conv_general_dilated",
+    "psum_invariant.219": "jit(per_worker)/psum_invariant",
+    # no metadata of its own; the root is a tuple the compiler made, and
+    # the nearest instruction behind it is the update's `add`
+    "fusion.185": "jit(per_worker)/add",
+    # no metadata of its own; behind the root's tuple, the ReLU
+    "broadcast_maximum_fusion.remat": "jit(per_worker)/jvp()/jit(relu)/max",
+}
+
+
+@pytest.fixture(scope="module")
+def scopes():
+    with open(os.path.join(DATA, "v5e_vgg16_b384_bsp_4chip.hlo.txt")) as f:
+        return trace.scopes_from_hlo(f.read())
+
+
+def _named(ops, *names):
+    want = {"%" + n for n in names}
+    return [r for r in ops if trace.instruction_name(r[0]) in want]
+
+
+def test_an_instruction_gets_its_own_op_name_a_fusion_its_roots(scopes):
+    for name, op_name in SCOPED.items():
+        assert scopes[name] == op_name, name
+    # in the text, without metadata anywhere behind them: unscoped
+    for name in ("copy-start.145", "copy-done.145", "slice_bitcast_fusion.9",
+                 "tuple.1"):
+        assert name not in scopes
+    # instructions inside fused computations are in the map too (their
+    # names are unique in a module and no trace event carries them)
+    assert scopes["maximum.7"] == "jit(per_worker)/jvp()/jit(relu)/max"
+
+
+def test_the_recorded_events_find_their_scopes(recorded, scopes):
+    tables, window, _ = recorded
+    ops = tables.devices[0].ops
+    for name in SCOPED:
+        rows = _named(ops, name)
+        assert len(rows) == 2, name                 # two steps in the cut
+        assert trace.scope_of(scopes, rows[0][0]) == SCOPED[name]
+    # in the text without metadata, and absent from the text: unscoped
+    assert trace.scope_of(scopes, _named(ops, "copy-done.145")[0][0]) is None
+    assert "fusion.607" not in scopes
+    assert trace.scope_of(scopes, _named(ops, "fusion.607")[0][0]) is None
+
+
+def test_scope_busy_time_of_the_recorded_cut(recorded, scopes):
+    tables, window, _ = recorded
+    ops = tables.devices[0].ops
+    by_hand = lambda *names: trace.total(trace.union(trace.clip(  # noqa: E731
+        ((s, e) for _, s, e in _named(ops, *names)), window)))
+    assert trace.scope_busy_ns(ops, window, scopes, "per_worker") \
+        == by_hand(*SCOPED) > 100e6
+    assert trace.scope_busy_ns(ops, window, scopes, "per_worker",
+                               "backward") \
+        == by_hand("select_and_scatter.54", "fusion.606") > 50e6
+    assert trace.scope_busy_ns(ops, window, scopes, "relu") \
+        == trace.scope_busy_ns(ops, window, scopes, "relu", "forward") \
+        == by_hand("broadcast_maximum_fusion.remat")
+    assert trace.scope_busy_ns(ops, window, scopes, "relu", "backward") == 0
+    assert trace.scope_busy_ns(ops, window, scopes, "per_work") == 0
+    assert trace.scope_busy_ns(ops, window, {}, "per_worker") == 0
+    # most of this trace is absent from the short text
+    share = trace.unscoped_share(ops, window, scopes)
+    assert share == pytest.approx(
+        1 - by_hand(*SCOPED) / trace.busy_ns(ops, window))
+    assert 0.5 < share < 1
+    assert trace.unscoped_share(ops, window, {}) == 1.0
+    assert trace.unscoped_share([], window, scopes) is None
+
+
+def test_breakdown_names_gain_the_tail_of_the_path(recorded, scopes):
+    tables, window, _ = recorded
+    ops = tables.devices[0].ops
+    bare = trace.top_device_ops(ops, window)
+    top = trace.top_device_ops(ops, window, scopes=scopes)
+    assert [v for _, v in top] == [v for _, v in bare]
+    assert all(len(name) <= trace.TAIL_CHARS for name, _ in top)
+    names = [name for name, _ in top]
+    assert names[0] == \
+        "fusion %fusion.606 transpose(jvp())/conv_general_dilated"
+    assert "fusion %fusion.185 add" in names
+    assert "fusion %broadcast_maximum_fusion.remat jvp()/jit(relu)/max" \
+        in names
+    assert "fusion %broadcast_maximum_fusion jvp()/conv_general_dilated" \
+        in names
+    # 64 characters: the direction stays, the primitive is cut
+    assert "select-and-scatter %select_and_scatter.54 " \
+           "transpose(jvp())/selec" in names
+    assert "fusion %fusion.607" in names            # unscoped: as it was
+    for (was, _), (now, _) in zip(bare, top):
+        assert now == was or now.startswith(was + " ")

@@ -21,7 +21,11 @@ from benchmarks import harness
 from benchmarks.reference import check
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
+# the readers of the program's span ring that need no device plane
+RING_ON_CPU = {"xla_compile_s", "produce_ms", "device_put_ms",
+               "batch_queue_wait_ms", "pool_busy_share",
+               "unready_dequeue_share", "train_call_ms", "small_programs_ms"}
 EARLY = {"import_jax": 1.0, "import_program": 3.0, "runtime_start": 5.0}
 
 
@@ -79,7 +83,28 @@ def test_a_cpu_line_has_the_keys_and_no_device_metric(runs, toy_manifest,
     assert line["device"]["platform"] == "cpu"
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
+    # every number `correct` compared beside its limit, as the last key
+    assert list(line)[-1] == "checks"
+    checks = line["checks"]
+    assert {"logit_rel_err", "loss_err", "first_cost_gap",
+            "costs_not_finite", "compiles_in_window",
+            "layout_faults"} <= set(checks)
+    assert checks["logit_rel_err"] == [run.reference["logit_rel_err"],
+                                       check.LOGIT_REL_TOL]
+    assert all(value <= limit for value, limit in checks.values())
     json.dumps(line)
+
+
+def test_a_reading_that_is_no_number_leaves_the_line_json(runs,
+                                                          toy_manifest):
+    import dataclasses
+    run = dataclasses.replace(runs["one"], compared=dict(
+        runs["one"].compared, first_cost_gap=[float("nan"), 0.25],
+        grad_norm_gap=[float("inf"), 0.1]))
+    line = harness.result_line(toy_manifest, run, trace=False)
+    assert line["checks"]["first_cost_gap"] == [None, 0.25]
+    assert line["checks"]["grad_norm_gap"] == [None, 0.1]
+    assert "NaN" not in json.dumps(line) and "Infinity" not in json.dumps(line)
 
 
 def test_window_counts_steps_and_recorder_buckets(runs):
@@ -119,7 +144,7 @@ def test_every_reader_runs_on_the_traced_toy_run(runs, toy_manifest):
                                "layer_metrics", run)
     # host-side readers read, device readers return nothing (no TPU plane)
     assert {"compile_s", "first_step_s", "load_wait_share",
-            "host_dispatch_ms", "toy_traced_steps"} == set(got)
+            "host_dispatch_ms", "toy_traced_steps"} | RING_ON_CPU == set(got)
     assert got["toy_traced_steps"]["value"] == run.traced.steps
     assert 0 <= got["load_wait_share"]["value"] <= 100
     e2e = harness.read_metrics(toy_manifest, run.cell.end_to_end,
@@ -132,6 +157,85 @@ def test_untraced_run_gives_the_layer_readers_nothing(runs, toy_manifest):
     got = harness.read_metrics(toy_manifest, run.cell.per_layer,
                                "layer_metrics", run)
     assert set(got) == {"compile_s", "first_step_s"}
+
+
+def test_the_traced_run_keeps_the_train_programs_scopes(runs):
+    """After the window the traced run joins the compiled train program's
+    instructions to their ``op_name``; the untraced run does not pay it."""
+    from benchmarks import trace
+    assert runs["one"].scopes == {} and runs["four"].scopes == {}
+    assert runs["one"].after_window_s == {}
+    scopes = runs["traced"].scopes
+    # its seconds are kept apart: after the window, in no metric
+    assert runs["traced"].after_window_s["scope_join"] > 0
+    assert runs["traced"].scope_join_error is None
+    assert len(scopes) > 50
+    ways = {trace.direction(v) for v in scopes.values()}
+    assert ways == {"forward", "backward", "other"}
+    convs = {trace.direction(v) for v in scopes.values()
+             if v.endswith("/conv_general_dilated")}
+    assert convs == {"forward", "backward"}
+    assert any(trace.in_scope(v, "per_worker") for v in scopes.values())
+
+
+def test_a_join_that_cannot_be_made_is_said_and_the_run_goes_on():
+    class NoText:
+        steps_per_call = 1
+
+        class train_fn:
+            @staticmethod
+            def lower(*avals):
+                raise RuntimeError("no lowering here")
+
+        def _train_input_avals(self, spc, exchanger):
+            return ()
+
+    scopes, error = harness.train_scopes(NoText(), None)
+    assert scopes == {} and "no lowering here" in error
+
+
+def test_an_image_model_is_checked_as_on_the_parent(runs, toy_manifest):
+    """A reference module without ``batch`` or ``train_loss`` goes through
+    the parent's statements: the same fields, the same values."""
+    import jax
+    import jax.numpy as jnp
+
+    ref_mod = harness.load_module(toy_manifest, "reference", "toy")
+    assert not hasattr(ref_mod, "batch") and not hasattr(ref_mod,
+                                                         "train_loss")
+    cfg = runs["one"].cell.config
+
+    from theanompi_tpu.worker import WORKERS
+    config = dict(cfg["worker_config"], rule="bsp", batch_size=8,
+                  n_workers=1, seed=3, synthetic_batches=4)
+    model = WORKERS["bsp"](config).build_model(cfg["modelfile"],
+                                               cfg["modelclass"])
+
+    def parent(ref_mod, config, model, seed):   # PR 26's reference_check
+        x, y = check.image_batch(config, np.random.RandomState(seed), 8)
+        params, bn = model.canonical_host_params(), model.bn_state
+
+        def system(params, bn, x, y):
+            logits, _ = model.apply_model(params, model.stage_input(x),
+                                          train=False, rng=None, state=bn)
+            cost, _ = model.val_metrics(params, bn, {"x": x, "y": y})
+            return logits.astype(jnp.float32), cost
+
+        def reference(params, x, y):
+            logits = ref_mod.forward(params, x)
+            return logits, check.plain_softmax_loss(logits, y)
+
+        sys_logits, sys_loss = jax.jit(system)(params, bn, x, y)
+        with jax.default_matmul_precision("highest"):
+            ref_logits, ref_loss = jax.jit(reference)(params, x, y)
+        return check.compare(np.asarray(ref_logits), np.asarray(sys_logits),
+                             float(ref_loss), float(sys_loss))
+
+    now = harness.reference_check(ref_mod, cfg, model, 3)
+    assert now == parent(ref_mod, cfg, model, 3)
+    assert list(now) == ["ok", "logit_rel_err", "logit_scale", "loss_err",
+                         "loss_tol", "ref_loss", "sys_loss"]
+    assert now == runs["one"].reference     # and what the run itself held
 
 
 def test_four_device_layout_check_sees_a_broken_replica(runs):

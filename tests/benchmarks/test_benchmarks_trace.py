@@ -197,3 +197,102 @@ planes { name: "Task Environment"
     assert dev.modules == [("jit_per_worker(7)", 1000, 6000)]
     assert trace.opcode(dev.ops[0][0]) == "all-reduce"
     assert dev.ops[0][1:] == (2000, 4000)
+
+
+# -- scopes: op_name paths as jax 0.9.0 writes them --------------------------------
+
+FWD = "jit(per_worker)/jvp(block0)/mla/dot_general"
+BWD = "jit(per_worker)/transpose(jvp(block0))/mla/dot_general"
+REMAT = "jit(step)/vmap(transpose(jvp(head)))/vmap(jvp(head))/checkpoint/cos"
+UPDATE = "jit(per_worker)/mul"
+
+
+def test_path_parts_cut_outside_parentheses_and_strip_wrappers():
+    assert trace.path_parts(BWD) == [
+        (("jit",), "per_worker"), (("transpose", "jvp"), "block0"),
+        ((), "mla"), ((), "dot_general")]
+    assert trace.path_parts(REMAT)[1] == (("vmap", "transpose", "jvp"),
+                                          "head")
+    # an unscoped primitive under a transform: jax writes an empty name
+    assert trace.path_parts("jit(f)/jvp()/conv_general_dilated")[1] == \
+        (("jvp",), "")
+    # a `/` inside parentheses does not cut
+    assert [p for _, p in trace.path_parts("jit(f)/jvp(a/b)/c")] == \
+        ["f", "a/b", "c"]
+
+
+@pytest.mark.parametrize("op_name,way", [
+    (FWD, "forward"), (BWD, "backward"), (REMAT, "backward"),
+    (UPDATE, "other"), ("state['params']['fc6']['w']", "other"),
+    ("jit(f)/jvp()/conv_general_dilated", "forward"),
+    ("jit(f)/transpose(jvp())/conv_general_dilated", "backward")])
+def test_direction_reads_the_transforms(op_name, way):
+    assert trace.direction(op_name) == way
+
+
+def test_in_scope_matches_a_whole_part_in_either_direction():
+    for name in (FWD, BWD):
+        assert trace.in_scope(name, "mla") and trace.in_scope(name, "block0")
+        assert not trace.in_scope(name, "ml")
+        assert not trace.in_scope(name, "block")
+    assert trace.in_scope(FWD, "mla", "forward")
+    assert not trace.in_scope(FWD, "mla", "backward")
+    assert trace.in_scope(BWD, "mla", "backward")
+    assert not trace.in_scope(UPDATE, "mla")
+    assert not trace.in_scope("", "mla")
+
+
+def test_scope_tail_keeps_the_direction_and_the_primitive():
+    assert trace.scope_tail(BWD, 64) == \
+        "transpose(jvp(block0))/mla/dot_general"
+    assert trace.scope_tail("jit(a)/jit(main)/mul", 64) == "mul"
+    # on a mesh every path runs through shard_map: it goes with the jits
+    assert trace.scope_tail(
+        "jit(per_worker)/shard_map/transpose(jvp())/select_and_scatter_add",
+        23) == "transpose(jvp())/select"
+    assert trace.scope_tail("jit(per_worker)/shard_map", 64) == "shard_map"
+    long = "jit(f)/transpose(jvp(block0))/" + "/".join(
+        f"scope_number_{i}" for i in range(8)) + "/dot_general"
+    cut = trace.scope_tail(long, 48)
+    assert len(cut) <= 48
+    assert cut.startswith("transpose(jvp(block0))/../")
+    assert cut.endswith("/dot_general")
+    assert len(trace.scope_tail("x" * 100, 20)) == 20
+
+
+def test_scope_busy_is_a_union_and_not_a_sum():
+    scopes = {"a.1": FWD, "a.2": BWD, "u.1": UPDATE}
+    ops = [("%a.1 = f32[] fusion(...)", 0, 10),
+           ("%a.2 = f32[] fusion(...)", 5, 20),      # overlaps a.1
+           ("%u.1 = f32[] fusion(...)", 15, 40),
+           ("%x.9 = f32[] copy-start(...)", 40, 50)]  # not in the text
+    w = (0, 100)
+    assert trace.scope_busy_ns(ops, w, scopes, "mla") == 20      # not 25
+    assert trace.scope_busy_ns(ops, w, scopes, "mla", "forward") == 10
+    assert trace.scope_busy_ns(ops, w, scopes, "mla", "backward") == 15
+    assert trace.scope_busy_ns(ops, (8, 12), scopes, "mla") == 4
+    assert trace.scope_busy_ns(ops, w, scopes, "per_worker") == 40
+    # busy 50, of which only x.9's 10 ran with no scoped instruction
+    assert trace.unscoped_share(ops, w, scopes) == pytest.approx(0.2)
+
+
+def test_scopes_from_hlo_on_a_cpu_style_text():
+    text = """
+HloModule jit_f
+
+%fused_computation.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  %t = f32[4]{0} tanh(%p), metadata={op_name="jit(f)/jvp(mla)/tanh" stack_frame_id=3}
+  ROOT %c = f32[4]{0} convert(%t)
+}
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0), metadata={op_name="w[\\'a\\']"}
+  %fusion.1 = f32[4]{0} fusion(%x), kind=kLoop, calls=%fused_computation.1
+  ROOT %copy.2 = f32[4]{0} copy(%fusion.1)
+}
+"""
+    scopes = trace.scopes_from_hlo(text)
+    assert scopes == {"t": "jit(f)/jvp(mla)/tanh",
+                      "fusion.1": "jit(f)/jvp(mla)/tanh", "x": "w['a']"}
+    assert trace.scopes_from_hlo("") == {}
