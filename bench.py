@@ -41,7 +41,7 @@ async start/done buckets; the row JSON then carries ``bucket_bytes`` +
 adds the columns to control rows), ``BENCH_FUSE`` (=0 forces the jnp
 oracles of the compression kernels — the control row of the fused-kernel
 A/B), ``BENCH_REAL_DATA`` (=1 drives the whole disk→augment→device
-pipeline; + ``BENCH_DATA_DIR``, ``BENCH_WIRE_U8``), ``BENCH_WINLOAD`` (=1,
+pipeline; + ``BENCH_DATA_DIR``), ``BENCH_WINLOAD`` (=1,
 with BENCH_SPC>1: para_load window mode — the producer stacks+stages whole
 spc windows off the hot path and the timed loop dequeues mesh-resident
 windows), ``BENCH_TRACE`` (=1 captures a ``jax.profiler`` window of
@@ -210,10 +210,6 @@ def bench_row_config(environ=None):
         # bucketed overlap-scheduled collectives (parallel/buckets.py):
         # every exchange wire splits into ~N-byte async start/done pairs
         config["bucket_bytes"] = int(env["BENCH_BUCKET_BYTES"])
-    if env.get("BENCH_WIRE_U8") == "1":
-        # u8-wire staging: host ships uint8 crops, device casts+subtracts
-        # (4× smaller host→device transfers — the real-data lever)
-        config["aug_wire_u8"] = True
     if env.get("BENCH_USHARD") == "1":
         # leaf-wise update-plane sharding (parallel/update_sharding.py):
         # optimizer moments + shardable exchanger state chunked over the
@@ -703,7 +699,7 @@ def _apply_flagship_defaults() -> None:
     invocation gets this config."""
     shaping = ("BENCH_MODEL", "BENCH_RULE", "BENCH_BATCH", "BENCH_STRATEGY",
                "BENCH_CFG", "BENCH_SPC", "BENCH_SYNTH_BATCHES",
-               "BENCH_BN_DTYPE", "BENCH_REAL_DATA", "BENCH_WIRE_U8",
+               "BENCH_BN_DTYPE", "BENCH_REAL_DATA",
                "BENCH_WINLOAD", "BENCH_BUCKET_BYTES", "BENCH_USHARD",
                "BENCH_FUSE")
     if any(k in os.environ for k in shaping):

@@ -2,14 +2,15 @@
 """Host-side input-pipeline throughput — no accelerator required.
 
 The reference's flagship was its parallel loader feeding real ``.hkl``
-batches at AlexNet rates (SURVEY.md §2.8/§7: at 14k img/s that is ~1.1 GB/s
-of augmented float32).  This measures exactly that capability in isolation:
-disk → ``.hkl`` read → fused native crop/mirror/mean/cast →
+batches at AlexNet rates (SURVEY.md §2.8/§7: at 14k img/s that was ~1.1 GB/s
+of augmented float32; the wire here is uint8, a quarter of that).  This
+measures exactly that capability in isolation:
+disk → ``.hkl`` read → native crop/mirror gather →
 (optionally) the PrefetchLoader producer — images/sec and GB/s out of the
 host pipeline, the ceiling it can feed a chip at.
 
     python scripts/loader_bench.py [--batches 32] [--batch-size 128]
-                                   [--u8-wire] [--prefetch]
+                                   [--prefetch] [--workers N]
 
 Writes one JSON line; nothing here touches a TPU: the numbers are the host
 pipeline's own, not a device metric.
@@ -36,8 +37,6 @@ def main(argv=None) -> int:
     p.add_argument("--batch-size", type=int, default=128)
     p.add_argument("--epochs", type=int, default=3,
                    help="timed passes over the shard set")
-    p.add_argument("--u8-wire", action="store_true",
-                   help="measure the aug_wire_u8 path (crop+mirror only)")
     p.add_argument("--prefetch", action="store_true",
                    help="pull through the PrefetchLoader producer thread")
     p.add_argument("--workers", type=int, default=1,
@@ -64,8 +63,6 @@ def main(argv=None) -> int:
     from theanompi_tpu.models.data.imagenet import ImageNet_data
 
     cfg = {"size": 1, "data_dir": d}
-    if args.u8_wire:
-        cfg["aug_wire_u8"] = True
     if args.windows > 1:
         return _bench_windows(args, cfg)
     data = ImageNet_data(cfg, batch_size=args.batch_size)
@@ -91,7 +88,6 @@ def main(argv=None) -> int:
     ips = n_imgs / dt
     out = {
         "metric": "host_loader_images_per_sec"
-                  + (" (u8-wire)" if args.u8_wire else " (fused f32)")
                   + (f" via PrefetchLoader x{args.workers}"
                      if (args.prefetch or args.workers > 1) else ""),
         "value": round(ips, 1),
@@ -166,9 +162,7 @@ def _bench_windows(args, cfg) -> int:
 
     out = {
         "metric": f"staged_window_dequeue_vs_serial_assembly (k={k}, "
-                  f"batch {args.batch_size}"
-                  + (", u8-wire" if args.u8_wire else "")
-                  + f", pool x{args.workers})",
+                  f"batch {args.batch_size}, pool x{args.workers})",
         "value": round(deq_ms, 3),
         "unit": "ms/window dequeue",
         "serial_assembly_ms": round(serial_ms, 3),

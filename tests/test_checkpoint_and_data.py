@@ -430,7 +430,9 @@ def test_hkl_batch_files_read_via_h5py(tmp_path):
     data.shuffle_data(0)
     b = data.next_train_batch(0)
     assert b["x"].shape == (4, 227, 227, 3)
-    assert b["x"].dtype == np.float32
+    assert b["x"].dtype == np.uint8
+    # a mean image and a shared window: the window's offsets ride along
+    assert b["crop_off"].shape == (4, 2)
     v = data.next_val_batch(0)
     assert v["y"].shape == (4,)
 

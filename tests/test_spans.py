@@ -294,7 +294,7 @@ def test_every_producer_leaves_the_same_input_spans(producer):
     tot = telemetry.totals()
     assert tot["input.dequeues"][0] == 6
     assert 0 <= tot.get("input.unready_dequeues", (0, 0))[0] <= 6
-    per_put = 4 * 16 * 16 * 3 * 4 + 4 * 4          # float32 x, int32 y
+    per_put = 4 * 16 * 16 * 3 + 4 * 4              # uint8 x, int32 y
     assert tot["input.bytes_put"][0] >= 6 * per_put * \
         (2 if producer == "window" else 1)
     assert tot["input.bytes_put"][0] % per_put == 0

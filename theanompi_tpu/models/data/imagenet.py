@@ -10,6 +10,8 @@ This rebuild keeps that on-disk contract so existing data prep works:
 ``config['data_dir']`` (or ``$IMAGENET_DIR``) must contain ``train_hkl/`` and
 ``val_hkl/`` of batch files plus ``img_mean.npy``.  ``.hkl`` is read via
 hickle when installed, with a ``.npy``/``.npz`` fallback per file extension.
+Batches leave the host as uint8 crops; the step program casts them and
+subtracts the mean (``ModelBase.stage_input``).
 Without a data dir it synthesizes deterministic random uint8 image batches —
 enough for throughput benchmarking (bench.py) and pipeline tests, where only
 shapes and rates matter.
@@ -303,70 +305,32 @@ class ImageNet_data:
                  train: bool) -> Dict[str, np.ndarray]:
         """Reference augmentation: random 256→crop window + horizontal
         mirror at train time (one draw per batch, as the reference's
-        per-batch ``param_rand``); center crop at val; mean subtraction.
+        per-batch ``param_rand``); center crop at val.
         ``aug_per_image=True`` in config upgrades to independent per-image
-        draws.  The fused crop/mirror/mean/cast pass runs in the native C++
-        library when available (``theanompi_tpu.native``), NumPy otherwise.
-        """
+        draws."""
         return self._transform(
             x, y, self._draw(x.shape[0], x.shape[1], x.shape[2], train))
 
     def _transform(self, x: np.ndarray, y: np.ndarray,
                    draws) -> Dict[str, np.ndarray]:
-        """Stateless tail of the augmentation (thread-safe given draws)."""
+        """Stateless tail of the augmentation (thread-safe given draws).
+
+        The wire is uint8: the host only gathers (crop window + mirror, one
+        native pass, NumPy without a compiler); the cast and the mean
+        subtraction are the step program's (``ModelBase.stage_input``),
+        which computes the reference's ``float32(pixel) - mean``.  A mean
+        IMAGE is subtracted under the drawn window when the batch shares
+        one: the batch then carries the window's offsets as ``crop_off``,
+        ``int32 [n, 2]`` rows of ``(oy, ox)`` that shard like ``y``.  With
+        per-image windows no such leaf exists and the mean's center crop
+        serves every image, as it always has."""
         from ... import native
-        n, h, w = x.shape[0], x.shape[1], x.shape[2]
-        c = self.crop
         oy, ox, flip = draws
-        assert int(oy.max()) + c <= h and int(ox.max()) + c <= w, (
-            f"crop window ({int(oy.max())},{int(ox.max())})+{c} exceeds the "
-            f"loaded batch's {h}x{w} — heterogeneous batch-file sizes?")
-        if self.config.get("aug_wire_u8", False):
-            # u8-wire mode (round-4 perf lever): host does ONLY crop+mirror
-            # on uint8 (a gather); mean-subtract+cast happen ON DEVICE,
-            # fused into the first conv by XLA — the host→device transfer
-            # shrinks 4×.  Mean semantics (ModelBase.stage_input): always
-            # the mean image's CENTER-crop window — bit-equal to the fused
-            # f32 pass for scalar means and for aug_per_image mode; a
-            # DOCUMENTED deviation for shared-window draws with a full mean
-            # image, where the f32 pass subtracts the window-exact mean
-            # (shipping the per-batch window would need a replicated batch
-            # leaf; the center window is the aug_per_image approximation).
-            m = oy.shape[0]
-            if m == 1:                     # shared window: one vector slice
-                win = x[:, oy[0]:oy[0] + c, ox[0]:ox[0] + c, :]
-                if flip[0]:
-                    win = win[:, :, ::-1, :]
-                out = np.ascontiguousarray(win)
-            else:
-                out = np.empty((n, c, c, x.shape[3]), np.uint8)
-                for i in range(n):
-                    win = x[i, oy[i]:oy[i] + c, ox[i]:ox[i] + c, :]
-                    out[i] = win[:, ::-1, :] if flip[i] else win
-            return {"x": out,
-                    "y": np.ascontiguousarray(y, dtype=np.int32)}
-        mean, mean_scalar = None, 0.0
-        m_img = self.img_mean
-        if isinstance(m_img, np.ndarray) and m_img.size > 1:
-            if m_img.ndim == 3:
-                full = self._mean_to_hwc(m_img)
-                if oy.shape[0] == 1:
-                    mean = full[oy[0]:oy[0] + c, ox[0]:ox[0] + c, :]
-                else:
-                    # per-image windows: use the mean image's center crop for
-                    # all (window-exact per-image mean would defeat the fused
-                    # pass)
-                    cy, cx = (h - c) // 2, (w - c) // 2
-                    mean = full[cy:cy + c, cx:cx + c, :]
-            else:
-                # per-channel mean (shape (C,) or broadcastable): expand to
-                # the window shape the fused pass expects
-                n_chan = x.shape[-1]
-                mean = np.broadcast_to(
-                    np.asarray(m_img, np.float32).reshape(-1)[:n_chan],
-                    (c, c, n_chan))
-        else:
-            mean_scalar = float(m_img)
-        out = native.augment_batch(x, oy, ox, flip, c, mean=mean,
-                                   mean_scalar=mean_scalar)
-        return {"x": out, "y": np.ascontiguousarray(y, dtype=np.int32)}
+        # augment_batch refuses a window that leaves the loaded images
+        # (plan-time draws against heterogeneous batch-file sizes)
+        batch = {"x": native.augment_batch(x, oy, ox, flip, self.crop),
+                 "y": np.ascontiguousarray(y, dtype=np.int32)}
+        if np.ndim(self.img_mean) == 3 and oy.shape[0] == 1:
+            batch["crop_off"] = np.tile(
+                np.array([oy[0], ox[0]], np.int32), (x.shape[0], 1))
+        return batch

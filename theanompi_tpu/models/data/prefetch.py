@@ -145,7 +145,7 @@ class PrefetchLoader:
 
     def __getattr__(self, name):
         # duck-typed passthrough for anything the wrapper doesn't override
-        # (img_mean/crop for the u8-wire device mean, synthetic, …) —
+        # (img_mean for stage_input's device mean, synthetic, …) —
         # __getattr__ fires only for MISSING attributes, so the wrapper's
         # own surface wins.  Private/dunder lookups raise normally (also
         # prevents recursion before __init__ sets _data).

@@ -177,7 +177,8 @@ class GoogLeNet(ModelBase):
 
     def loss_and_metrics(self, params, bn_state, batch, rng, train):
         logits, t4a, t4d, rng = self._trunk(
-            params, self.stage_input(batch["x"]), train, rng)
+            params, self.stage_input(batch["x"], batch.get("crop_off")),
+            train, rng)
         ls = self._label_smoothing(train)
         cost = L.softmax_cross_entropy(logits, batch["y"], ls)
         if train:

@@ -262,44 +262,39 @@ class ModelBase:
         return float(self.config.get("label_smoothing", 0.0)) if train \
             else 0.0
 
-    def _u8_input_mean(self):
-        """Constant for the u8-wire input path: the mean image's
-        center-crop window (or the scalar mean).  The HOST numpy value is
-        cached per model; the jnp conversion happens per call so each
-        trace owns its constant — caching the jnp array on ``self`` leaks
-        a tracer on jax versions that stage constant creation (first
-        touched inside the train trace, reused by the val trace →
-        UnexpectedTracerError; this was the u8-wire smoke seed failure).
-        NOTE: for shared-window crops with a full mean image this deviates
-        from the f32 pass's window-exact mean (see data/imagenet.py)."""
-        m = getattr(self, "__u8_mean_host", None)
-        if m is None:
-            d = getattr(self, "data", None)
-            mi = getattr(d, "img_mean", np.float32(122.0))
-            if isinstance(mi, np.ndarray) and mi.ndim == 3:
-                c = int(getattr(d, "crop", mi.shape[0]))
-                cy, cx = (mi.shape[0] - c) // 2, (mi.shape[1] - c) // 2
-                m = np.asarray(mi[cy:cy + c, cx:cx + c, :], np.float32)
-            else:
-                m = np.float32(mi)
-            setattr(self, "__u8_mean_host", m)
-        return jnp.asarray(m, jnp.float32)
-
-    def stage_input(self, x):
+    def stage_input(self, x, crop_off=None):
         """Shared input staging for EVERY loss/metrics path (models with
-        custom heads call this too): u8-wire batches (data/imagenet.py
-        aug_wire_u8) are cast and mean-subtracted on device — the same
-        float32 arithmetic as the host fused pass, fused into the first
-        conv by XLA.  Float inputs pass through untouched."""
-        if x.dtype == jnp.uint8:
-            return x.astype(jnp.float32) - self._u8_input_mean()
-        return x
+        custom heads call this too).  The image wire is uint8
+        (data/imagenet.py): the cast and the mean subtraction happen here,
+        inside the step program — ``float32(pixel) - mean``, one float32
+        subtraction, the reference's arithmetic.  Where the mean comes
+        from is read off the data object: a scalar, a per-channel vector,
+        or a mean image — cut under the batch's shared crop window when
+        the batch carries its offsets (``crop_off``: ``int32 [rows, 2]``
+        of ``(oy, ox)``, every row alike), else at its center, which is
+        what per-image windows have always used.  Float inputs pass
+        through untouched."""
+        if x.dtype != jnp.uint8:
+            return x
+        mean = np.asarray(getattr(self.data, "img_mean", 122.0), np.float32)
+        if mean.ndim == 3:
+            c = x.shape[-2]
+            if crop_off is None:
+                oy, ox = (mean.shape[0] - c) // 2, (mean.shape[1] - c) // 2
+                mean = mean[oy:oy + c, ox:ox + c]
+            else:
+                mean = jax.lax.dynamic_slice(
+                    jnp.asarray(mean), (crop_off[0, 0], crop_off[0, 1], 0),
+                    (c, c, mean.shape[2]))
+        elif mean.ndim:
+            mean = mean.reshape(-1)[:x.shape[-1]]
+        return x.astype(jnp.float32) - mean
 
     def loss_and_metrics(self, params, bn_state, batch, rng, train):
         """Default head: softmax cross-entropy + top-1 error."""
-        logits, new_bn = self.apply_model(params, self.stage_input(batch["x"]),
-                                          train=train, rng=rng,
-                                          state=bn_state)
+        logits, new_bn = self.apply_model(
+            params, self.stage_input(batch["x"], batch.get("crop_off")),
+            train=train, rng=rng, state=bn_state)
         cost = L.softmax_cross_entropy(logits, batch["y"],
                                        self._label_smoothing(train))
         err = L.errors(logits, batch["y"])
@@ -330,8 +325,9 @@ class ModelBase:
         return new_params, new_opt
 
     def val_metrics(self, params, bn_state, batch):
-        logits, _ = self.apply_model(params, self.stage_input(batch["x"]),
-                                     train=False, rng=None, state=bn_state)
+        logits, _ = self.apply_model(
+            params, self.stage_input(batch["x"], batch.get("crop_off")),
+            train=False, rng=None, state=bn_state)
         cost = L.softmax_cross_entropy(logits, batch["y"])
         return cost, (L.errors(logits, batch["y"]),
                       L.errors_top_x(logits, batch["y"], 5))

@@ -4,9 +4,10 @@ The reference's runtime leaned on native code in two places of its own
 (SURVEY.md §2.9): runtime-compiled PyCUDA kernels in the exchanger (on TPU
 those became Pallas kernels — ``theanompi_tpu/ops/compress.py``) and the
 parallel-loader child process that augmented batches on CPU and pushed them
-into the GPU over CUDA IPC (§2.8).  The CPU half of that loader — the fused
-crop/mirror/mean-subtract/cast pass — is this module: ``loader.cc`` compiled
-at first use with the system ``g++`` (mirroring the reference's
+into the GPU over CUDA IPC (§2.8).  The CPU half of that loader is this
+module: the crop/mirror gather on uint8 (the cast and the mean belong to the
+step program, ``ModelBase.stage_input``), ``loader.cc`` compiled at first
+use with the system ``g++`` (mirroring the reference's
 compile-on-first-run PyCUDA habit) and called through ctypes.  No pybind11 in
 this environment; the C ABI + ctypes keeps the binding dependency-free.
 
@@ -104,11 +105,10 @@ def get_lib():
                     ctypes.c_int, ctypes.c_int, ctypes.c_int,  # n, h, w
                     ctypes.c_int, ctypes.c_int, ctypes.c_int,  # c, crop, nchw
                     ctypes.c_void_p, ctypes.c_void_p,          # oy, ox
-                    ctypes.c_void_p, ctypes.c_void_p,          # flip, mean
-                    ctypes.c_float, ctypes.c_int,              # mean_scalar, threads
+                    ctypes.c_void_p, ctypes.c_int,             # flip, threads
                 ]
                 lib.tmpi_loader_abi_version.restype = ctypes.c_int
-                assert lib.tmpi_loader_abi_version() == 1
+                assert lib.tmpi_loader_abi_version() == 2
                 _lib = lib
             except (OSError, AssertionError):
                 _lib = None
@@ -131,31 +131,24 @@ def is_nchw(x: np.ndarray) -> bool:
     return x.ndim == 4 and x.shape[1] in (1, 3) and x.shape[-1] not in (1, 3)
 
 
-def _augment_numpy(x, oy, ox, flip, crop, mean, mean_scalar):
+def _augment_numpy(x, oy, ox, flip, crop):
     n = x.shape[0]
     if is_nchw(x):
         x = x.transpose(0, 2, 3, 1)
-    c = x.shape[-1]
-    out = np.empty((n, crop, crop, c), np.float32)
+    out = np.empty((n, crop, crop, x.shape[-1]), np.uint8)
     for i in range(n):
         win = x[i, oy[i]:oy[i] + crop, ox[i]:ox[i] + crop, :]
-        if flip[i]:
-            win = win[:, ::-1, :]
-        out[i] = win
-    out -= mean if mean is not None else np.float32(mean_scalar)
+        out[i] = win[:, ::-1, :] if flip[i] else win
     return out
 
 
 def augment_batch(x: np.ndarray, oy, ox, flip, crop: int,
-                  mean: Optional[np.ndarray] = None,
-                  mean_scalar: float = 0.0,
                   n_threads: Optional[int] = None) -> np.ndarray:
-    """Fused crop+mirror+mean-subtract+cast: uint8 batch → float32 NHWC.
+    """Crop + mirror in one pass: uint8 batch → uint8 NHWC crops.
 
     ``x``: uint8 ``[n,h,w,c]`` (NHWC) or ``[n,c,h,w]`` (NCHW — the
     reference's bc01 batch files); ``oy``/``ox``/``flip``: per-image crop
-    offsets and mirror flags (scalars broadcast); ``mean``: optional float32
-    ``[crop,crop,c]`` pre-cropped mean image, else ``mean_scalar``.
+    offsets and mirror flags (scalars and one-element arrays broadcast).
     """
     assert x.dtype == np.uint8 and x.ndim == 4, (x.dtype, x.shape)
     n = x.shape[0]
@@ -164,24 +157,24 @@ def augment_batch(x: np.ndarray, oy, ox, flip, crop: int,
     flip = np.broadcast_to(np.asarray(flip, np.uint8), (n,))
     nchw = is_nchw(x)
     c = x.shape[1] if nchw else x.shape[-1]
-    if mean is not None:
-        mean = np.ascontiguousarray(mean, np.float32)
-        assert mean.shape == (crop, crop, c), (mean.shape, (crop, crop, c))
+    h, w = (x.shape[2], x.shape[3]) if nchw else (x.shape[1], x.shape[2])
+    if n and (oy.min() < 0 or ox.min() < 0 or oy.max() + crop > h
+              or ox.max() + crop > w):
+        # the native pass reads what the offsets say: never outside x
+        raise ValueError(f"crop windows of side {crop} at offsets up to "
+                         f"({oy.max()},{ox.max()}) leave the {h}x{w} images")
 
     lib = get_lib()
     if lib is None:
-        return _augment_numpy(x, oy, ox, flip, crop, mean, mean_scalar)
+        return _augment_numpy(x, oy, ox, flip, crop)
 
-    h, w = (x.shape[2], x.shape[3]) if nchw else (x.shape[1], x.shape[2])
     x = np.ascontiguousarray(x)
     oy = np.ascontiguousarray(oy)
     ox = np.ascontiguousarray(ox)
     flip = np.ascontiguousarray(flip)
-    out = np.empty((n, crop, crop, c), np.float32)
+    out = np.empty((n, crop, crop, c), np.uint8)
     lib.tmpi_augment_u8(
         x.ctypes.data, out.ctypes.data, n, h, w, c, crop, int(nchw),
         oy.ctypes.data, ox.ctypes.data, flip.ctypes.data,
-        mean.ctypes.data if mean is not None else None,
-        ctypes.c_float(mean_scalar),
         n_threads if n_threads is not None else DEFAULT_THREADS)
     return out
