@@ -9,7 +9,7 @@ chunks and FSDP parameter chunks re-partition on load (the chunk layout
 is recorded in the checkpoint meta).  Per-worker exchange-strategy state
 (onebit/topk/powersgd error-feedback buffers, async diverged replicas)
 has NO refit path — resuming such a run on a different worker count
-raises a targeted error from ``load()`` (round-4 ADVICE #3).  This
+raises a targeted error from ``load()`` (round-4 review).  This
 script trains 1 epoch on 8 workers with FSDP + adam, checkpoints,
 rebuilds on 4 workers, resumes, and shows the val accuracy carrying
 over.

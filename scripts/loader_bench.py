@@ -19,6 +19,8 @@ pipeline's own, not a device metric.
 import argparse
 import json
 import os
+import shutil
+import subprocess
 import sys
 import time
 
@@ -29,6 +31,32 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+def _ensure_dataset(n_batches: int, batch_size: int,
+                    data_dir: str = None) -> str:
+    """Generate (once) an on-disk batch-file dataset in the reference's
+    .hkl layout; ~25 MB per 128-image file."""
+    d = data_dir or f"/tmp/loader_bench_imagenet_{batch_size}x{n_batches}"
+    # img_mean.npy is written LAST by make_batch_dataset.py — its presence
+    # marks a complete dataset; a generation killed mid-write leaves
+    # train_hkl/ without it, so wipe and redo
+    if os.path.isdir(os.path.join(d, "train_hkl")) and \
+            not os.path.exists(os.path.join(d, "img_mean.npy")):
+        print(f"loader_bench: {d} is half-generated — regenerating",
+              file=sys.stderr)
+        shutil.rmtree(d)
+    if not os.path.isdir(os.path.join(d, "train_hkl")):
+        print(f"loader_bench: generating {n_batches}x{batch_size}-image "
+              f"dataset at {d}", file=sys.stderr)
+        subprocess.run(
+            [sys.executable,
+             os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "make_batch_dataset.py"),
+             "--synthetic", str(n_batches), "--batch-size", str(batch_size),
+             "--out", d],
+            check=True, stdout=sys.stderr)
+    return d
 
 
 def main(argv=None) -> int:
@@ -55,10 +83,7 @@ def main(argv=None) -> int:
     p.add_argument("--data-dir", default=None)
     args = p.parse_args(argv)
 
-    # shared generator (half-generated-dir wipe included) — bench.py's
-    # module level touches no jax backend
-    from bench import _ensure_bench_dataset
-    d = _ensure_bench_dataset(args.batches, args.batch_size, args.data_dir)
+    d = _ensure_dataset(args.batches, args.batch_size, args.data_dir)
 
     from theanompi_tpu.models.data.imagenet import ImageNet_data
 
@@ -95,9 +120,8 @@ def main(argv=None) -> int:
         "gb_per_sec_out": round(ips * bytes_per_img / 1e9, 3),
         "images": n_imgs,
         "seconds": round(dt, 2),
-        "note": "host pipeline only (disk->.hkl->augment); the rate it can "
-                "feed a chip at — AlexNet v5e needs ~14k img/s "
-                "(BASELINE.md)",
+        "note": "host pipeline only (disk->.hkl->augment): the rate it can "
+                "feed a chip at (ROADMAP S7)",
     }
     print(json.dumps(out))
     return 0

@@ -45,9 +45,8 @@ A monolithic wire is the n = 1 case: fill = t_comm, credit = 0 — the
 ``eff_no_overlap`` bound, recovered exactly.  Each row reports the
 monolithic and the 4 MiB-bucketed prediction side by side, and
 ``pred_exposed_comm_secs`` is emitted per row so the measured
-``exposed_comm_secs`` BENCH_TRACE column of the r9 matrix rows
-(bucketed + monolithic controls, scripts/rows.py) can be compared
-prediction-vs-trace per config.
+``exposed_comm_secs`` trace column of the r9 matrix rows (bucketed +
+monolithic controls) can be compared prediction-vs-trace per config.
 
 Wire-bytes-per-step models (P = param count, b = wire bytes/elem,
 N = chips; ring collectives over a 1D ICI ring, per-chip bytes):
@@ -67,7 +66,7 @@ N = chips; ring collectives over a 1D ICI ring, per-chip bytes):
                                      _compressible gate accepts, and
                                      ``dense`` counts the elements of the
                                      leaves it sends as a plain psum
-                                     (round-5 ADVICE: the old
+                                     (round-5 review: the old
                                      shape[0]+size//shape[0] estimate
                                      overstated vgg16 wire bytes ~60×)
 
@@ -156,7 +155,7 @@ def pipeline_bubble(pp: int, v: int, m: int, t_chunk: float = 1.0,
             "bubble_fraction": round(1.0 - busy / span, 4)}
 
 
-# staged r10 pipeline rows (scripts/rows.py) -> (matrix label, pp, v, M);
+# staged r10 pipeline rows -> (matrix label, pp, v, M);
 # t_chunk/t_hop default to the uniform-tick model — the measured join
 # below reports both the tick-count and wall-time measured bubbles next
 # to the prediction
@@ -179,16 +178,16 @@ def update_state_bytes_per_chip(replicated_bytes: float, n: int) -> float:
     return replicated_bytes / n
 
 
-# staged r11 update-sharding rows (scripts/rows.py): sharded row joined
-# against its replicated control (which carries the same report columns
-# via BENCH_USHARD_REPORT=1) -> (ushard label, control label, N)
+# staged r11 update-sharding rows: sharded row joined against its
+# replicated control (which carries the same report columns)
+# -> (ushard label, control label, N)
 USHARD_CONFIGS = [
     ("transformer_lm-b8-n2-ushard", "transformer_lm-b8-n2", 2),
     ("transformer_lm-b8-n4-ushard", "transformer_lm-b8-n4", 4),
 ]
 
 
-# staged r12 fused-compression rows (scripts/rows.py): fuse row (Pallas
+# staged r12 fused-compression rows: fuse row (Pallas
 # kernel pipeline) joined against its forced-oracle control ->
 # (fuse label, control label, strategy)
 COMPRESS_CONFIGS = [
@@ -256,7 +255,7 @@ def _param_counts(models: list) -> dict:
     if os.path.exists(cache):
         with open(cache) as f:
             have = json.load(f)
-    # powersgd_dense marks the corrected-schema entries (round-5 ADVICE);
+    # powersgd_dense marks the corrected-schema entries (round-5 review);
     # entries cached under the old rows_plus_cols formula recount
     missing = [m for m in models
                if m not in have or "powersgd_dense" not in have[m]]
@@ -369,7 +368,7 @@ def main() -> int:
         dense = counts[model].get("powersgd_dense", 0)
         row.update(measured_ips_per_chip=ips, t_step_s=round(t_step, 6),
                    params=P)
-        # measured overlap evidence (BENCH_TRACE columns) when the r9
+        # measured overlap evidence (trace columns) when the r9
         # matrix rows exist — the prediction-vs-trace comparison per row
         for m_res, key in ((measured.get(cfg + "-trace") or res,
                             "measured_monolithic"),

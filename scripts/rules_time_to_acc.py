@@ -30,7 +30,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # CPU sim is the default (the rule comparison wants 8 visible devices,
 # more than one chip or one four-chip host offers); TMPI_FORCE_TPU=1
 # opts out so the documented real-chip path is actually reachable
-# (round-4 ADVICE: the previous `or True` made the env guard dead code)
+# (round-4 review: the previous `or True` made the env guard dead code)
 if not os.environ.get("TMPI_FORCE_TPU"):
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:

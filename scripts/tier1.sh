@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# The ROADMAP tier-1 verify gate, wrapped verbatim so the builder and the
-# reviewer run the SAME command (one place to keep the pytest flags, the
+# The tier-1 verify gate with the driver's pytest flags, so the builder and
+# the reviewer run the SAME command (one place to keep the pytest flags, the
 # timeout, and the DOTS_PASSED accounting in sync).
 #   scripts/tier1.sh
 # Exits with pytest's return code; prints DOTS_PASSED=<n> as the last line.
@@ -20,13 +20,9 @@
 # retry-verdict/close-taxonomy checks, membership state-machine
 # exhaustiveness incl. reactor hooks and the versioned wire-header
 # field vocabulary), and the compile-surface pass (design.md §26:
-# cache-key completeness — config knobs that shape a traced program
-# reachable from the AOT surfaces must reach a guarded
-# compile_cache.key_extra stamp, cross-checked against a live stamping
-# probe — plus retrace hazards like fresh-lambda jit identity,
-# jit-in-loop, non-static shape params and .lower() on an installed
-# Compiled, and bf16-wire dtype-flow discipline incl. the per-module
-# NONBITEXACT round-trip registry).  Any finding not covered by
+# retrace hazards like fresh-lambda jit identity, jit-in-loop and
+# non-static shape params, and bf16-wire dtype-flow discipline incl.
+# the per-module NONBITEXACT round-trip registry).  Any finding not covered by
 # tpulint_baseline.json — or a stale baseline entry — fails the gate
 # here, without importing jax, before pytest.  An unchanged tree is a
 # .tpulint_cache/ hit: the gate costs well under a second.
@@ -40,4 +36,8 @@ python scripts/lint.py --check-baseline || { echo "tier1: tpulint gate FAILED (r
 # no jax execution — it runs before pytest so a broken survivability
 # refactor fails in seconds.
 python scripts/simfleet_run.py --gate --budget 120 || { echo "tier1: simfleet gate FAILED (run scripts/simfleet_run.py --gate for details)" >&2; exit 8; }
-set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c); exit $rc
+# The driver's pytest flags (`commands` in /root/TESTS_LAST_RUN.json): six
+# xdist workers, one file to one worker, junit counts.  The driver also sets
+# ALLOW_MULTIPLE_LIBTPU_LOAD=1 for its own run here; no file of the
+# repository does.
+set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c); exit $rc

@@ -59,6 +59,7 @@ def test_3d_training_matches_dense(mesh8):
     np.testing.assert_allclose(c_3d, c_dense, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.slow
 def test_compressed_strategies_on_pipe_and_3d_meshes(mesh8):
     """EF compression and the explicit ring wire compose with pipeline (and
     pipe×model) sharding: per-stage EF shards, replicated leaves pmean'd
@@ -105,12 +106,13 @@ def test_3d_val_and_checkpoint(tmp_path, mesh8):
 
 
 def test_worker_mesh_warns_on_idle_remainder(mesh8):
-    """ADVICE r3: flooring n_workers must not silently idle chips."""
+    """Review r3: flooring n_workers must not silently idle chips."""
     del mesh8
     with pytest.warns(UserWarning, match="left idle"):
         worker_mesh(None, tp=3, devices=jax.devices())   # 8 % 3 = 2 idle
 
 
+@pytest.mark.slow
 def test_4axis_tp_pp_sp_matches_dense(mesh8):
     """round-4: ALL model-parallel axes at once — pipeline stages of
     head-sharded ring-attention blocks over sequence-sharded microbatches
@@ -127,7 +129,3 @@ def test_4axis_tp_pp_sp_matches_dense(mesh8):
     m4.begin_val()
     m4.val_iter(0)
     m4.end_val()
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

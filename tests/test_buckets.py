@@ -23,7 +23,7 @@ from theanompi_tpu.parallel.exchanger import (ASGD_Exchanger, BSP_Exchanger,
                                               EASGD_Exchanger,
                                               GOSGD_Exchanger)
 from theanompi_tpu.parallel.mesh import worker_mesh
-from theanompi_tpu.utils import compile_cache, devprof
+from theanompi_tpu.utils import devprof
 
 
 # -- the planner ------------------------------------------------------------
@@ -266,33 +266,5 @@ def test_bucketed_bsp_window_collective_count():
 
 # -- AOT cache key sensitivity ----------------------------------------------
 
-def test_aot_key_extras_sensitive_to_bucket_bytes():
-    """Two builds of the same rule at different bucket_bytes must never
-    share an executable-cache entry (belt-and-braces over the HLO hash:
-    key_extra carries the knob)."""
-    mesh = worker_mesh(4)
-    base = {"mesh": mesh, "size": 4, "rank": 0, "verbose": False,
-            "batch_size": 8}
-    model = TinyModel(base)
-    e0 = BSP_Exchanger(base)
-    e4 = BSP_Exchanger({**base, "bucket_bytes": 4 << 20})
-    e1 = BSP_Exchanger({**base, "bucket_bytes": 1 << 20})
-    x0 = compile_cache.key_extra("train", model, e0, spc=1)
-    x4 = compile_cache.key_extra("train", model, e4, spc=1)
-    x1 = compile_cache.key_extra("train", model, e1, spc=1)
-    assert "bucket_bytes" not in x0              # monolithic: legacy key,
-    #                                              prewarmed entries survive
-    assert x4["bucket_bytes"] == 4 << 20 and x1["bucket_bytes"] == 1 << 20
-    assert len({str(sorted(x.items())) for x in (x0, x4, x1)}) == 3
 
 
-def test_bench_row_config_carries_bucket_bytes():
-    """The one BENCH_* → config assembly hands the knob through, so the
-    prewarm venue and the measurement request byte-identical programs."""
-    import bench
-    _, _, config, _ = bench.bench_row_config(
-        {"BENCH_MODEL": "alexnet", "BENCH_BUCKET_BYTES": "4194304"})
-    assert config["bucket_bytes"] == 4194304
-    assert bench._bucket_label(4194304) == "4m"
-    assert bench._bucket_label(65536) == "64k"
-    assert bench._bucket_label(1000) == "1000"

@@ -7,9 +7,7 @@ Three layers, all runnable on the CPU venue:
   replaced in ``parallel/strategies.py`` — the oracles are the non-TPU
   dispatch targets, so these identities are what keeps every CPU/
   forced-oracle run on the pre-fusion numbers;
-* the dispatch plumbing: the memoized ``THEANOMPI_TPU_NO_PALLAS`` gate,
-  the ``no_pallas`` AOT-key stamp, and ``bench_row_config``'s shared
-  control-row side effect;
+* the dispatch plumbing: the memoized ``THEANOMPI_TPU_NO_PALLAS`` gate;
 * the traffic model: :data:`devprof.COMPRESS_ROW_COLUMNS` schema (pinned
   disjoint from the other row vocabularies), the modeled ≥2× HBM
   shrinks the acceptance gates on, and the live-model report.
@@ -19,7 +17,6 @@ The kernel-vs-oracle bit-equality tests live in tests/test_strategies.py
 that every ``PALLAS_ORACLES`` entry has one.
 """
 
-import importlib
 import os
 import sys
 import types
@@ -31,7 +28,7 @@ import pytest
 
 from theanompi_tpu.ops import _pallas_util, compress, factor_pack
 from theanompi_tpu.parallel import strategies
-from theanompi_tpu.utils import compile_cache, devprof
+from theanompi_tpu.utils import devprof
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -167,7 +164,7 @@ def test_matmul_pack_oracle_pads_with_exact_zeros():
 
 
 # ---------------------------------------------------------------------------
-# dispatch plumbing: env gate, memo, AOT key stamp, bench labels
+# dispatch plumbing: env gate, memo
 # ---------------------------------------------------------------------------
 
 def test_public_dispatchers_match_with_no_pallas_toggled(clean_dispatch):
@@ -205,8 +202,7 @@ def test_public_dispatchers_match_with_no_pallas_toggled(clean_dispatch):
 
 def test_dispatch_gate_is_memoized_until_reset(clean_dispatch):
     assert _pallas_util.dispatch_pallas() is False      # CPU venue
-    # flipping the env WITHOUT a reset must not re-read it: bench sets the
-    # var once per process through bench_row_config, and a per-call
+    # flipping the env WITHOUT a reset must not re-read it: a per-call
     # os.environ lookup was the satellite this memo removed
     clean_dispatch.setenv("THEANOMPI_TPU_NO_PALLAS", "1")
     assert _pallas_util.dispatch_pallas() is False
@@ -214,32 +210,6 @@ def test_dispatch_gate_is_memoized_until_reset(clean_dispatch):
     _pallas_util.reset_dispatch_cache()
     assert _pallas_util._DISPATCH_MEMO is None
     assert _pallas_util.dispatch_pallas() is False
-
-
-def test_aot_key_extra_stamps_no_pallas_only_when_forced(clean_dispatch):
-    base = compile_cache.key_extra("train")
-    assert "no_pallas" not in base       # pre-existing keys stay byte-stable
-    clean_dispatch.setenv("THEANOMPI_TPU_NO_PALLAS", "1")
-    forced = compile_cache.key_extra("train")
-    assert forced.pop("no_pallas") == 1
-    assert forced == base                # the stamp is the ONLY delta
-
-
-def test_bench_row_config_control_rows_force_oracle(clean_dispatch):
-    """BENCH_FUSE=0 must flow through the ONE shared env→config assembly
-    (bench_row_config) so prewarm and measurement agree on the forced-
-    oracle key stamp — and must reset the dispatch memo in-process."""
-    bench = importlib.import_module("bench")
-    clean_dispatch.delenv("THEANOMPI_TPU_NO_PALLAS", raising=False)
-    _pallas_util.dispatch_pallas()      # prime the memo pre-control
-    bench.bench_row_config({"BENCH_MODEL": "transformer_lm",
-                            "BENCH_FUSE": "0"})
-    try:
-        assert os.environ.get("THEANOMPI_TPU_NO_PALLAS") == "1"
-        assert _pallas_util._DISPATCH_MEMO is None or \
-            _pallas_util.dispatch_pallas() is False
-    finally:
-        os.environ.pop("THEANOMPI_TPU_NO_PALLAS", None)
 
 
 def test_topk_chunk_over_int16_range_raises():
@@ -276,8 +246,6 @@ def test_onebit_scale_uses_true_length_only():
 
 def test_compress_row_columns_disjoint_from_other_vocabularies():
     vocabularies = {
-        "TRACE_ROW_COLUMNS": devprof.TRACE_ROW_COLUMNS,
-        "BUCKET_ROW_COLUMNS": devprof.BUCKET_ROW_COLUMNS,
         "PIPELINE_ROW_COLUMNS": devprof.PIPELINE_ROW_COLUMNS,
         "USHARD_ROW_COLUMNS": devprof.USHARD_ROW_COLUMNS,
     }
@@ -333,6 +301,6 @@ def test_traffic_report_from_live_model_stub():
     want = devprof.compress_traffic_model(
         "topk", 64 * 32 + 32, 2, chunk=4096, k_c=strat._k_c())
     assert rep["compress_hbm_shrink"] == want["compress_hbm_shrink"]
-    # non-compression strategy → None, so bench rows stay clean
+    # non-compression strategy → None
     model.exchanger.strategy = strategies.get_strategy("allreduce")
     assert devprof.compress_traffic_report(model) is None

@@ -235,20 +235,6 @@ def test_feed_telemetry_emits_device_gauges():
     devprof.feed_telemetry(prof, telemetry.DISABLED)
 
 
-def test_profile_row_fields_columns_and_device_mfu():
-    prof = devprof.attribute([_op(0, 50, "fusion.1"),
-                              _op(40, 20, "all-reduce.1")])
-    fields = devprof.profile_row_fields(prof)
-    assert set(fields) == set(devprof.TRACE_ROW_COLUMNS)
-    assert fields["device_mfu"] is None          # no flops/peak given
-    # 1 lane, 50us compute; 1e9 flops over the window vs 1e15 peak:
-    # mfu = 1e9 / 50e-6 / 1e15 = 0.02
-    fields = devprof.profile_row_fields(prof, total_flops=1e9,
-                                        peak_flops=1e15)
-    assert fields["device_mfu"] == pytest.approx(0.02)
-    assert fields["overlap_ratio"] == pytest.approx(0.5)
-
-
 def test_bubble_fraction_per_lane_idle_gaps():
     """ISSUE 14 satellite (ROADMAP item 2's bench column): per-lane idle
     gaps between compute intervals inside the dispatch window, span-
@@ -271,13 +257,9 @@ def test_bubble_fraction_per_lane_idle_gaps():
     ])
     assert prof2["bubble_fraction"] == pytest.approx(10.0 / 70.0,
                                                      abs=1e-4)
-    # no compute at all → None (and the row column carries it verbatim)
+    # no compute at all → None
     prof3 = devprof.attribute([_op(0, 5, "all-reduce.1")])
     assert prof3["bubble_fraction"] is None
-    assert devprof.profile_row_fields(prof3)["bubble_fraction"] is None
-    assert "bubble_fraction" in devprof.TRACE_ROW_COLUMNS
-    assert devprof.profile_row_fields(prof)["bubble_fraction"] == \
-        prof["bubble_fraction"]
     # a perfectly packed single lane is bubble-free
     assert devprof.attribute([_op(0, 50, "fusion.1")])[
         "bubble_fraction"] == pytest.approx(0.0)
@@ -481,50 +463,3 @@ def test_telemetry_report_trace_export(tmp_path):
     assert "sentry anomalies" in r2.stdout and "loss_spike" in r2.stdout
     assert "device-time attribution" in r2.stdout
     assert "80.0% overlap" in r2.stdout
-
-
-# -- explain_program over the cost manifest ---------------------------------
-
-def test_compile_cache_manifest_carries_cost_summary(tmp_path):
-    """A cache write records the executable's cost/memory summary; the
-    explain CLI prints and diffs it from the manifest alone."""
-    import jax
-    import jax.numpy as jnp
-    from theanompi_tpu.utils.compile_cache import CompileCache
-
-    cc = CompileCache(str(tmp_path))
-
-    def big(x):
-        return (x @ x).sum()
-
-    def small(x):
-        return (x * 2.0).sum()
-
-    xb = jnp.zeros((64, 64), jnp.float32)
-    _, info_a = cc.get_or_compile(jax.jit(big).lower(xb), label="prog:big")
-    _, info_b = cc.get_or_compile(jax.jit(small).lower(xb),
-                                  label="prog:small")
-    manifest = json.load(open(os.path.join(str(tmp_path), "manifest.json")))
-    cost_a = manifest[info_a["key"]].get("cost", {})
-    cost_b = manifest[info_b["key"]].get("cost", {})
-    assert cost_a.get("flops", 0) > cost_b.get("flops", 0) > 0
-    script = os.path.join(REPO, "scripts/explain_program.py")
-    r = subprocess.run([sys.executable, script, str(tmp_path)],
-                       capture_output=True, text=True)
-    assert r.returncode == 0, r.stderr
-    assert "prog:big" in r.stdout and "prog:small" in r.stdout
-    r = subprocess.run([sys.executable, script, str(tmp_path),
-                        "--diff", "prog:big", "prog:small"],
-                       capture_output=True, text=True)
-    assert r.returncode == 0, r.stderr
-    assert "flops" in r.stdout and "B/A" in r.stdout
-    r = subprocess.run([sys.executable, script, str(tmp_path), "--json"],
-                       capture_output=True, text=True)
-    assert json.loads(r.stdout)[info_a["key"]]["label"] == "prog:big"
-    # unresolvable diff token → exit 2, stderr explains
-    r = subprocess.run([sys.executable, script, str(tmp_path),
-                        "--diff", "prog:big", "nope"],
-                       capture_output=True, text=True)
-    assert r.returncode == 2 and "cannot resolve" in r.stderr
-
-

@@ -162,7 +162,3 @@ def test_ema_zero_tp_shadow_matches_plain_ema(mesh8):
     zero.begin_val()
     zero.val_iter(0)
     zero.end_val()
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

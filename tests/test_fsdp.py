@@ -310,7 +310,7 @@ def test_fsdp_transformer_trains(mesh8):
 
 def test_per_worker_strategy_state_rejects_worker_count_change(
         tmp_path, mesh4, mesh8):
-    """Round-4 ADVICE #3: exchange-strategy error-feedback state (onebit/
+    """Round-4 review: exchange-strategy error-feedback state (onebit/
     topk/powersgd) is boxed per-worker with NO refit path — resuming on a
     different worker count must fail with the targeted message naming the
     limitation, not a raw leaf-shape mismatch."""
@@ -330,7 +330,3 @@ def test_per_worker_strategy_state_rejects_worker_count_change(
     m4b, cfg4b = _make_tiny(False, mesh4, exch_strategy="topk")
     m4b.compile_iter_fns(get_exchanger("bsp", cfg4b))
     assert m4b.load(d) == 0
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

@@ -183,7 +183,7 @@ def test_gan_rejects_zero_opt_but_composes_with_ema():
 
 
 def test_wgan_rejects_ema_plus_zero_opt():
-    """ADVICE r3: zero_opt nests the EMA shadow as flat chunks the clip
+    """Review r3: zero_opt nests the EMA shadow as flat chunks the clip
     projection can't reach — the combination must fail loudly, not score an
     unclipped critic shadow silently."""
     with pytest.raises(AssertionError, match="EMA shadow"):

@@ -3330,85 +3330,8 @@ def test_oracle_pair_repo_is_clean_and_jax_free():
 
 
 # ---------------------------------------------------------------------------
-# compile-surface discipline (PR 20): cache-key / retrace-hazard /
-# dtype-flow
+# compile-surface discipline (PR 20): retrace-hazard / dtype-flow
 # ---------------------------------------------------------------------------
-
-CACHEKEY_BAD = """
-import jax.numpy as jnp
-
-def build_train_step(model):
-    warm = int(model.config.get("scan_warm_steps", 0) or 0)
-    tbl = jnp.arange(warm + 1)
-    return tbl
-"""
-
-CACHEKEY_GOOD = """
-import jax.numpy as jnp
-
-def build_train_step(model):
-    n = int(model.config.get("n_subb", 1))
-    tbl = jnp.arange(n + 1)
-    probe = int(model.config.get("host_probe_rows", 0))  # tpulint: disable=cache-key
-    tbl2 = jnp.arange(probe + 1)
-    return tbl, tbl2
-"""
-
-
-def test_cache_key_bad_fixture(tmp_path):
-    """An uncovered knob flowing into a shape slot inside an AOT surface
-    is exactly one finding, anchored at the read."""
-    found = lint_snippet(tmp_path, "bad.py", CACHEKEY_BAD, "cache-key")
-    assert len(found) == 1, [f.render() for f in found]
-    m = found[0].message
-    assert "'scan_warm_steps'" in m and "build_train_step" in m
-    assert "key_extra stamp" in m and "only-when-on" in m
-    assert found[0].check == "cache-key"
-
-
-def test_cache_key_good_fixture(tmp_path):
-    """A STAMP_KNOBS-covered knob and a disable-comment exemption both
-    stay silent."""
-    assert lint_snippet(tmp_path, "good.py", CACHEKEY_GOOD,
-                        "cache-key") == []
-
-
-KEYEXTRA_UNGUARDED = """
-def key_extra(fn, model=None, spc=None):
-    extra = {"fn": str(fn)}
-    extra["spc"] = spc
-    return extra
-"""
-
-KEYEXTRA_GUARDED = """
-def key_extra(fn, model=None, spc=None):
-    extra = {"fn": str(fn)}
-    if spc is not None:
-        extra["spc"] = int(spc)
-    return extra
-"""
-
-
-def test_cache_key_unguarded_stamp(tmp_path):
-    """Every stamp except `fn` must sit under a guard (only-when-on):
-    an unconditional stamp churns every pre-existing cache key."""
-    found = lint_snippet(tmp_path, "ke.py", KEYEXTRA_UNGUARDED,
-                         "cache-key")
-    assert len(found) == 1, [f.render() for f in found]
-    assert "stamp 'spc' is unconditional" in found[0].message
-
-
-def test_cache_key_guarded_stamp(tmp_path):
-    assert lint_snippet(tmp_path, "ke.py", KEYEXTRA_GUARDED,
-                        "cache-key") == []
-
-
-def test_cache_key_non_literal_stamp_key(tmp_path):
-    code = KEYEXTRA_GUARDED.replace('extra["spc"]', 'extra[name]')
-    found = lint_snippet(tmp_path, "ke.py", code, "cache-key")
-    assert len(found) == 1 and "non-literal key_extra stamp key" in \
-        found[0].message, [f.render() for f in found]
-
 
 RETRACE_BAD = """
 import time
@@ -3418,14 +3341,12 @@ import jax.numpy as jnp
 def step(x):
     return x * 2
 
-def install(cache, key):
+def install():
     probe = jax.jit(lambda s: s)
     fns = []
     for i in range(4):
         fns.append(jax.jit(step))
-    compiled = cache.get_or_compile(key, step)
-    lowered = compiled.lower()
-    return probe, fns, lowered
+    return probe, fns
 
 def shaped(x, n):
     return x + jnp.arange(n)
@@ -3449,25 +3370,19 @@ def shaped(x, n):
     return x + jnp.arange(n)
 
 run = jax.jit(shaped, static_argnums=(1,))
-
-def install(cache, key):
-    compiled = cache.get_or_compile(key, step)
-    return compiled
 """
 
 
 def test_retrace_hazard_bad_fixture(tmp_path):
-    """All five hazard classes fire on one file: fresh lambda identity,
-    jit-in-loop, .lower() on an installed Compiled, a non-static shape
-    param, and a host clock feeding shape arithmetic."""
+    """All four hazard classes fire on one file: fresh lambda identity,
+    jit-in-loop, a non-static shape param, and a host clock feeding
+    shape arithmetic."""
     found = lint_snippet(tmp_path, "bad.py", RETRACE_BAD,
                          "retrace-hazard")
     msgs = [f.message for f in found]
-    assert len(found) == 5, msgs
+    assert len(found) == 4, msgs
     assert any("fresh lambda at a jax.jit boundary" in m for m in msgs)
     assert any("jax.jit called inside a loop" in m for m in msgs)
-    assert any("`.lower()` on `compiled`" in m and "PR 3" in m
-               for m in msgs)
     assert any("spends parameter `n` in a shape-static slot" in m
                for m in msgs)
     assert any("host value `time.time()` feeds shape arithmetic" in m
@@ -3476,8 +3391,8 @@ def test_retrace_hazard_bad_fixture(tmp_path):
 
 
 def test_retrace_hazard_good_fixture(tmp_path):
-    """Hoisted defs, static_argnums coverage, loop-free jit, and a
-    get_or_compile result left alone are all silent."""
+    """Hoisted defs, static_argnums coverage and a loop-free jit are
+    all silent."""
     assert lint_snippet(tmp_path, "good.py", RETRACE_GOOD,
                         "retrace-hazard") == []
 
@@ -3584,29 +3499,10 @@ def test_dtype_flow_registry_must_be_literal(tmp_path):
         [f.render() for f in found]
 
 
-# -- the three real-file injections, through the CLI gate -------------------
+# -- the two real-file injections, through the CLI gate --- -------------------
 
 def _gate(tmp_path):
     return _lint_cli(tmp_path, "--check-baseline", "--no-cache")
-
-
-def test_injection_unstamped_knob_in_steps_cli(tmp_path):
-    """A config knob feeding jnp.arange inside build_train_step fails
-    the baseline gate (rc 1) and the revert restores rc 0."""
-    rel = _inject(
-        tmp_path, "theanompi_tpu/parallel/steps.py",
-        '    n_subb = getattr(model, "n_subb", 1)\n',
-        '    n_subb = getattr(model, "n_subb", 1)\n'
-        '    warm = int(model.config.get("scan_warm_steps", 0) or 0)\n'
-        '    _warm_tbl = jnp.arange(warm + 1)\n')
-    bad = _gate(tmp_path)
-    assert bad.returncode == 1, bad.stdout + bad.stderr
-    assert "scan_warm_steps" in bad.stdout
-    assert "cache-key" in bad.stdout
-    (tmp_path / rel).write_text(
-        open(os.path.join(REPO, rel)).read())
-    good = _gate(tmp_path)
-    assert good.returncode == 0, good.stdout + good.stderr
 
 
 def test_injection_fresh_lambda_in_model_base_cli(tmp_path):
@@ -3647,40 +3543,13 @@ def test_injection_low_precision_accumulate_in_strategies_cli(tmp_path):
 def test_disk_scoped_is_a_checker_attribute():
     """The partial-run disk probes are declared per checker (one
     attribute the runner folds in), not a CLI carve-out list."""
-    from theanompi_tpu.analysis.checkers.compile_surface import \
-        COMPILE_CACHE_PATH
+    from theanompi_tpu.analysis.checkers.schema_drift import RECORDER_PATH
     from theanompi_tpu.analysis.core import CHECKERS, Checker
     assert Checker.disk_scoped == ()
-    assert CHECKERS["cache-key"].disk_scoped == (COMPILE_CACHE_PATH,)
-    assert COMPILE_CACHE_PATH in CHECKERS["schema-drift"].disk_scoped
+    assert CHECKERS["retrace-hazard"].disk_scoped == ()
+    assert RECORDER_PATH in CHECKERS["schema-drift"].disk_scoped
     assert any("*" in pat
                for pat in CHECKERS["oracle-pair"].disk_scoped)
-
-
-def test_cache_key_result_cache_tracks_compile_cache(tmp_path):
-    """disk_scoped keys the result cache: a cached --only cache-key run
-    over steps.py alone is invalidated by an edit to compile_cache.py,
-    which the checker reads from disk for the stamp vocabulary."""
-    import shutil
-    for rel in ("theanompi_tpu/parallel/steps.py",
-                "theanompi_tpu/utils/compile_cache.py"):
-        p = tmp_path / rel
-        p.parent.mkdir(parents=True, exist_ok=True)
-        shutil.copy(os.path.join(REPO, rel), p)
-    rel = "theanompi_tpu/parallel/steps.py"
-    cold = _lint_cli(tmp_path, rel, "--only", "cache-key",
-                     "--format", "json")
-    assert json.loads(cold.stdout)["cache"] == "miss"
-    warm = _lint_cli(tmp_path, rel, "--only", "cache-key",
-                     "--format", "json")
-    w = json.loads(warm.stdout)
-    assert w["cache"] == "hit"
-    assert w["findings"] == json.loads(cold.stdout)["findings"]
-    cc = tmp_path / "theanompi_tpu" / "utils" / "compile_cache.py"
-    cc.write_text(cc.read_text() + "\n# vocabulary touched\n")
-    edited = _lint_cli(tmp_path, rel, "--only", "cache-key",
-                       "--format", "json")
-    assert json.loads(edited.stdout)["cache"] == "miss"
 
 
 # -- group alias, warm cache, jax-free --------------------------------------
@@ -3720,74 +3589,6 @@ def test_compile_surface_stays_jax_free():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
-# -- key_extra byte-stability + schema-drift live probe ---------------------
-
-def test_key_extra_byte_stability():
-    """§26 floor pinned directly: a knob-less config's extras are frozen
-    by this PR — every pre-existing cache key stays byte-stable."""
-    from theanompi_tpu.utils import compile_cache as cc
-    saved = os.environ.pop("THEANOMPI_TPU_NO_PALLAS", None)
-    try:
-        assert cc.key_extra("val") == {"fn": "val"}
-
-        class _Bare:
-            config = {}
-
-        assert cc.key_extra("train", model=_Bare()) == {
-            "fn": "train", "model": "_Bare", "n_subb": 1}
-    finally:
-        if saved is not None:
-            os.environ["THEANOMPI_TPU_NO_PALLAS"] = saved
-
-
-def test_key_extra_schema_probe_clean_on_repo():
-    assert sd.key_extra_schema_errors() == []
-
-
-def test_key_extra_schema_probe_ignores_ambient_no_pallas():
-    """The probe pins THEANOMPI_TPU_NO_PALLAS itself — a host process
-    that happens to export it (bench control rows do) must not flip the
-    verdict, which the result cache would then store."""
-    saved = os.environ.get("THEANOMPI_TPU_NO_PALLAS")
-    os.environ["THEANOMPI_TPU_NO_PALLAS"] = "1"
-    try:
-        assert sd.key_extra_schema_errors() == []
-        assert os.environ.get("THEANOMPI_TPU_NO_PALLAS") == "1", \
-            "the probe must restore the ambient value"
-    finally:
-        if saved is None:
-            os.environ.pop("THEANOMPI_TPU_NO_PALLAS", None)
-        else:
-            os.environ["THEANOMPI_TPU_NO_PALLAS"] = saved
-
-
-def test_key_extra_schema_probe_catches_drift():
-    """A stamping path that drifts from the static vocabulary (or the
-    byte-stability floor) trips all three probe checks."""
-
-    class _Drifted:
-        @staticmethod
-        def key_extra(fn, model=None, exchanger=None, spc=None):
-            return {"fn": str(fn), "surprise": 1}
-
-    errs = sd.key_extra_schema_errors(compile_cache_mod=_Drifted)
-    msgs = " | ".join(m for _p, m in errs)
-    assert len(errs) == 3, errs
-    assert "extraction rules drifted" in msgs
-    assert "STAMP_KNOBS" in msgs
-    assert "byte-stable" in msgs
-
-
-def test_key_extra_schema_probe_catches_backend_dependence():
-    class _Raising:
-        @staticmethod
-        def key_extra(fn, model=None, exchanger=None, spc=None):
-            raise RuntimeError("needs a backend")
-
-    errs = sd.key_extra_schema_errors(compile_cache_mod=_Raising)
-    assert len(errs) == 1 and "callable" in errs[0][1], errs
-
-
 # -- SARIF emitter ----------------------------------------------------------
 
 def test_sarif_format_findings(tmp_path):
@@ -3820,45 +3621,3 @@ def test_sarif_format_clean_tree(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     assert json.loads(r.stdout)["runs"][0]["results"] == []
 
-
-# -- explain_program --diff key_extra ---------------------------------------
-
-EXPLAIN = os.path.join(REPO, "scripts", "explain_program.py")
-
-
-def _explain_diff(tmp_path, a, b):
-    return subprocess.run(
-        [sys.executable, EXPLAIN, str(tmp_path), "--diff", a, b],
-        capture_output=True, text=True, timeout=60)
-
-
-def test_explain_program_diff_names_the_knob(tmp_path):
-    """The structured key_extra diff names WHICH stamp split the key,
-    with the checker's one-line meaning — and degrades honestly for
-    pre-extras entries and identical stamp dicts."""
-    entry = {"label": "train:Net:spc1", "platform": "tpu", "created": 1,
-             "compile_secs": 1.0, "bytes": 10, "cost": {"flops": 1.0},
-             "extra": {"fn": "train", "model": "Net", "n_subb": 1,
-                       "spc": 1}}
-    import copy
-    b = copy.deepcopy(entry)
-    b["label"], b["created"], b["extra"]["spc"] = "train:Net:spc4", 2, 4
-    old = {"label": "old", "platform": "tpu", "created": 0,
-           "compile_secs": 1.0, "bytes": 10, "cost": {}}
-    (tmp_path / "manifest.json").write_text(json.dumps(
-        {"aaaa1111": entry, "bbbb2222": b, "cccc3333": old}))
-
-    r = _explain_diff(tmp_path, "aaaa", "bbbb")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "key_extra:" in r.stdout
-    assert "spc" in r.stdout and "1 -> 4" in r.stdout
-    assert "fused steps per compiled call" in r.stdout
-
-    r2 = _explain_diff(tmp_path, "aaaa", "cccc")
-    assert r2.returncode == 0
-    assert "predate the extras manifest" in r2.stdout
-
-    r3 = _explain_diff(tmp_path, "aaaa1111", "aaaa1111")
-    assert r3.returncode == 0
-    assert "identical — the key split came from the traced program" in \
-        r3.stdout

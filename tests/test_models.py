@@ -13,10 +13,13 @@ from theanompi_tpu.parallel.mesh import worker_mesh
 ZOO = [
     ("theanompi_tpu.models.cifar10", "Cifar10_model", 10),
     ("theanompi_tpu.models.alex_net", "AlexNet", 16),
-    ("theanompi_tpu.models.googlenet", "GoogLeNet", 16),
+    pytest.param("theanompi_tpu.models.googlenet", "GoogLeNet", 16,
+                 marks=pytest.mark.slow),
     ("theanompi_tpu.models.vggnet_16", "VGGNet_16", 16),
-    ("theanompi_tpu.models.vggnet_16", "VGGNet_11_shallow", 16),
-    ("theanompi_tpu.models.resnet50", "ResNet50", 16),
+    pytest.param("theanompi_tpu.models.vggnet_16", "VGGNet_11_shallow", 16,
+                 marks=pytest.mark.slow),
+    pytest.param("theanompi_tpu.models.resnet50", "ResNet50", 16,
+                 marks=pytest.mark.slow),
 ]
 
 
@@ -44,7 +47,8 @@ def test_forward_shapes_and_finite(modelfile, modelclass, n_class):
 
 @pytest.mark.parametrize("modelfile,modelclass,n_class", [
     ("theanompi_tpu.models.cifar10", "Cifar10_model", 10),
-    ("theanompi_tpu.models.resnet50", "ResNet50", 8),
+    pytest.param("theanompi_tpu.models.resnet50", "ResNet50", 8,
+                 marks=pytest.mark.slow),
 ])
 def test_full_train_step(modelfile, modelclass, n_class):
     """One compiled SPMD train step end-to-end (ResNet covers the BN-state
@@ -64,6 +68,7 @@ def test_full_train_step(modelfile, modelclass, n_class):
         assert any((m != 0).any() for m in means)
 
 
+@pytest.mark.slow
 def test_train_decreases_loss_alexnet_tiny():
     """AlexNet trains on its synthetic data (labels are random, but the
     model can still fit them — loss must drop within a few steps)."""
@@ -78,6 +83,7 @@ def test_train_decreases_loss_alexnet_tiny():
     assert costs[-1] < costs[0], costs
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("n_workers", [1, 4])
 def test_resnet_bn_composes_with_steps_per_call(n_workers):
     """Round-5 regression (found pre-hardware by the AOT compile of the
@@ -102,6 +108,7 @@ def test_resnet_bn_composes_with_steps_per_call(n_workers):
     assert any((m != 0).any() for m in means)
 
 
+@pytest.mark.slow
 def test_resnet_bn_trains_under_async_rules():
     """Round-5 review regression: the async rules' sync_bn is the
     identity (replicas diverge on purpose), so their BN stats reach
@@ -125,7 +132,8 @@ def test_resnet_bn_trains_under_async_rules():
     ("theanompi_tpu.models.vggnet_16", "VGGNet_11_shallow", 5),
     ("theanompi_tpu.models.alex_net", "AlexNet", 1),       # conv5 -> pool5
     ("theanompi_tpu.models.cifar10", "Cifar10_model", 3),
-    ("theanompi_tpu.models.googlenet", "GoogLeNet", 1),    # conv1 -> pool1
+    pytest.param("theanompi_tpu.models.googlenet", "GoogLeNet", 1,
+                 marks=pytest.mark.slow),                  # conv1 -> pool1
     ("theanompi_tpu.models.resnet50", "ResNet50", 0),      # ConvBN: left
 ])
 def test_pool_before_relu_count(modelfile, modelclass, pairs):
@@ -149,6 +157,7 @@ def test_vgg16_no_max_pool_reads_a_relu_output():
     assert pool_operand_makers(jaxpr.jaxpr) == ["add"] * 5
 
 
+@pytest.mark.slow
 def test_vgg_train_step_cost_equals_the_plain_order():
     """Two train steps of VGG-11 at a fixed seed, as built and with the
     layer list's own order: the same costs."""
@@ -168,8 +177,3 @@ def test_vgg_train_step_cost_equals_the_plain_order():
         costs.append(np.stack(got))
     assert np.isfinite(costs[0]).all()
     np.testing.assert_allclose(costs[0], costs[1], rtol=1e-6)
-
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

@@ -82,6 +82,7 @@ def test_moe_capacity_drops_overflow_tokens():
     assert (np.abs(np.asarray(y_inf)).sum(axis=1) > 0).all()  # no zero rows
 
 
+@pytest.mark.slow
 def test_moe_ep4_matches_ep1(mesh8):
     """Expert-parallel ep=4 training must trace the dense-layout ep=1 loss
     curve (same seed/data): routing is replicated, only the expert placement
@@ -105,6 +106,7 @@ def test_moe_converges_and_validates(mesh8):
     model.end_val()
 
 
+@pytest.mark.slow
 def test_moe_pp_matches_dense_layout(mesh8):
     """A homogeneous all-MoE stack (moe_every=1) pipelines over 'pipe': same
     init (stacked from the same keys) and — with drop-free capacity and the
@@ -209,6 +211,7 @@ def test_moe_sp_a2a_layer_exact_vs_dense(mesh8):
                                rtol=1e-6, atol=1e-7)
 
 
+@pytest.mark.slow
 def test_moe_sp_model_close_to_dense_dropfree(mesh8):
     """Model-level: ring-vs-dense attention reorders fp32 sums by ~1e-6,
     and the ARGMAX router amplifies borderline flips into different expert
@@ -250,6 +253,7 @@ def test_moe_sp_tp_3d_smoke(mesh8):
     m.end_val()
 
 
+@pytest.mark.slow
 def test_moe_sp_uses_global_positions(mesh8):
     """Regression (round-4 review): MoE's _forward must offset position ids
     by the seq rank, like the base model.  With an amplified position table
@@ -337,7 +341,3 @@ def test_moe_top2_lm_trains_and_composes_with_ep(mesh8):
         costs = _train_steps(m, 5)
         assert np.isfinite(costs).all()
         assert np.mean(costs[-2:]) < np.mean(costs[:2])
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

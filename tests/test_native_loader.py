@@ -288,7 +288,7 @@ def test_aug_wire_u8_is_read_nowhere():
     """The uint8 wire is the only wire: a configuration that still sets
     the old switch finds no reader to honour it in some other way."""
     hits = []
-    for top in ("theanompi_tpu", "scripts", "bench.py"):
+    for top in ("theanompi_tpu", "scripts"):
         path = os.path.join(REPO, top)
         files = [path] if os.path.isfile(path) else [
             os.path.join(d, f) for d, _, fs in os.walk(path) for f in fs

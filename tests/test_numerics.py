@@ -17,10 +17,7 @@ The acceptance contract, pinned here:
   declared gauge/histogram/event vocabulary under one ``enabled`` check;
 * **sentry detectors** — grad_overflow / replica_divergence /
   update_ratio_collapse ordering, the latest-sample-carry iter dedupe,
-  and ``notice_discontinuity`` consuming exactly one report;
-* **compile-cache identity** — the train key stamps the plane only when
-  it is effectively on, so every pre-existing (and every numerics-off)
-  key stays byte-stable.
+  and ``notice_discontinuity`` consuming exactly one report.
 """
 
 import math
@@ -34,7 +31,7 @@ from tests.conftest import TinyModel
 from theanompi_tpu.parallel.exchanger import (BSP_Exchanger,
                                               EASGD_Exchanger)
 from theanompi_tpu.parallel.mesh import worker_mesh
-from theanompi_tpu.utils import compile_cache, numerics, telemetry
+from theanompi_tpu.utils import numerics, telemetry
 from theanompi_tpu.utils.sentry import TrainingSentry
 
 N = 4
@@ -305,37 +302,3 @@ def test_sentry_none_report_is_noop():
     s = TrainingSentry({"verbose": False}, telemetry=telemetry.DISABLED)
     assert s.observe_numerics(None) is None
     assert s.anomalies == []
-
-
-# -- compile-cache identity --------------------------------------------------
-
-class _FakeModel:
-    n_subb = 1
-    pp_interleave = 1
-    _fsdp = None
-
-    def __init__(self, cfg):
-        self.config = cfg
-
-
-def test_compile_key_stamps_numerics_only_when_on():
-    base = compile_cache.key_extra("train", _FakeModel({}), spc=1)
-    off = compile_cache.key_extra(
-        "train", _FakeModel({"numerics": False}), spc=1)
-    assert base == off and "numerics" not in base      # byte-stable keys
-    on = compile_cache.key_extra(
-        "train", _FakeModel({"numerics": True}), spc=1)
-    assert on["numerics"] == numerics.DEFAULT_EVERY
-    on2 = compile_cache.key_extra(
-        "train", _FakeModel({"numerics": True, "numerics_every": 5}),
-        spc=1)
-    assert on2["numerics"] == 5 and on2 != on
-    # the plane only reshapes the TRAIN step; spc-independent programs
-    # (and fsdp builds, where the plane stands down) stay unstamped
-    val = compile_cache.key_extra(
-        "val", _FakeModel({"numerics": True}))
-    assert "numerics" not in val
-    fsdp_model = _FakeModel({"numerics": True})
-    fsdp_model._fsdp = object()
-    assert "numerics" not in compile_cache.key_extra(
-        "train", fsdp_model, spc=1)

@@ -91,6 +91,7 @@ def test_pp_init_identical_to_dense(mesh8):
             stacked, dense.params[blk.name])
 
 
+@pytest.mark.slow
 def test_pp_bsp_training_matches_dense(mesh8):
     dense = _make(dp=2, pp=1)
     pp = _make(dp=2, pp=4)
@@ -192,6 +193,7 @@ def test_pp_interleaved_init_identical_to_dense(mesh8):
             gathered[blk.name], dense.params[blk.name])
 
 
+@pytest.mark.slow
 def test_pp_interleaved_training_matches_v1_exact(mesh8):
     """v=2 walks each chunk's microbatches in the same order as v=1, so
     even the fp summation order matches — training costs are IDENTICAL,
@@ -201,12 +203,14 @@ def test_pp_interleaved_training_matches_v1_exact(mesh8):
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
 
 
+@pytest.mark.slow
 def test_pp_interleaved_v4_matches_v1_exact(mesh8):
     c1 = _train_steps(_make(dp=2, pp=4, n_layer=16), 4)
     c4 = _train_steps(_make(dp=2, pp=4, n_layer=16, pp_interleave=4), 4)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c4))
 
 
+@pytest.mark.slow
 def test_pp_interleaved_training_matches_dense(mesh8):
     """Same tolerance the v=1 pin uses (fp noise only)."""
     c_dense = _train_steps(_make(dp=2, pp=1, n_layer=8), 5)
@@ -214,6 +218,7 @@ def test_pp_interleaved_training_matches_dense(mesh8):
     np.testing.assert_allclose(c_v2, c_dense, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.slow
 def test_pp_interleaved_spc_fused_exact(mesh8):
     """The fused multi-step dispatch (steps_per_call) composes with the
     interleaved schedule: same costs as v=1 under the same cadence."""
@@ -223,6 +228,7 @@ def test_pp_interleaved_spc_fused_exact(mesh8):
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
 
 
+@pytest.mark.slow
 def test_pp_interleaved_moe_aux_exact(mesh8):
     """with_aux masking stays exact over real ticks under interleaving:
     the MoE load-balance aux (psummed over the schedule) matches v=1
@@ -250,7 +256,3 @@ def test_pp_interleave_validation_errors(mesh8):
         mesh = worker_mesh(2, pp=1)
         TransformerLM({**LM_CFG, "mesh": mesh, "size": 2, "rank": 0,
                        "pp": 1, "pp_interleave": 2})
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

@@ -1,7 +1,7 @@
 """Interleaved pipeline schedule TABLE (round 10, ISSUE 16): pure-python /
 numpy pins on ``parallel.pipeline.build_schedule`` and everything that
-consumes it — the devprof busy-count mirror, the predict_scaling bubble
-model, the r10 row manifest, and the compile-cache key extra.
+consumes it — the devprof busy-count mirror and the predict_scaling
+bubble and wire models.
 
 Unlike tests/test_pipeline.py (slow: real meshes, real training), this file
 never traces or compiles anything, so it rides the tier-1 gate and keeps
@@ -9,13 +9,16 @@ the schedule contract pinned on every run.
 """
 
 import json
+import os
 
 import numpy as np
 import pytest
 
 from theanompi_tpu.parallel.pipeline import (_validate, build_schedule,
                                              stage_permutation)
-from theanompi_tpu.utils import compile_cache, devprof
+from theanompi_tpu.utils import devprof
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # (pp, v, m) grid: v=1 legacy shapes plus every interleave branch corner —
 # pp|m, v up to pp, non-power-of-two pp
@@ -157,36 +160,35 @@ def test_pipeline_bubble_model():
     assert (pipeline_bubble(4, 2, 8, t_chunk=1.0, t_hop=0.25)
             ["bubble_fraction"]
             > pipeline_bubble(4, 2, 8)["bubble_fraction"])
-    # the prediction table covers exactly the r10 matrix rows
-    from scripts.rows import rows
-    assert [c[0] for c in PIPELINE_CONFIGS] == [r.label for r in rows("r10")]
     for (_, pp, v, m) in PIPELINE_CONFIGS:
         assert pipeline_bubble(pp, v, m)["bubble_fraction"] == \
             pytest.approx((pp - 1) / (v * m + pp - 1), abs=1e-4)
 
 
-# -- r10 row manifest -------------------------------------------------------
-
-def test_r10_rows():
-    from scripts.rows import rows
-    r10 = rows("r10")
-    labels = [r.label for r in r10]
-    assert labels == ["transformer_lm-b16-pp4-trace",
-                      "transformer_lm-b16-pp4-v2-trace",
-                      "transformer_lm-b16-pp4-v4-trace"]
-    for r in r10:
-        cfg = json.loads(r.env["BENCH_CFG"])
-        assert cfg["pp"] == 4 and cfg["pp_microbatches"] == 8
-        assert r.env["BENCH_TRACE"] == "1"
-    assert json.loads(r10[1].env["BENCH_CFG"])["pp_interleave"] == 2
-    assert json.loads(r10[2].env["BENCH_CFG"])["pp_interleave"] == 4
+def test_powersgd_wire_bytes_uses_real_factorization():
+    """The wire model must follow PowerSGD's own
+    [prod(shape[:-1]), shape[-1]] per-leaf factorization gated by
+    _compressible, plus a dense psum term for the rejected leaves."""
+    from scripts.predict_scaling import wire_bytes
+    with open(os.path.join(REPO, "model_param_counts.json")) as f:
+        counts = json.load(f)
+    vgg = counts["vgg16"]
+    assert 60_000 < vgg["rows_plus_cols"] < 120_000, vgg
+    assert vgg["powersgd_dense"] > 0
+    wb = wire_bytes("powersgd4", vgg["params"], vgg["rows_plus_cols"], 8,
+                    vgg["powersgd_dense"])
+    ring = 2.0 * 7 / 8
+    assert wb == ring * (4 * vgg["rows_plus_cols"]
+                         + vgg["powersgd_dense"]) * 4
+    assert wb < 0.05 * wire_bytes("allreduce", vgg["params"], 0, 8)
 
 
 def test_pipeline_row_columns_distinct():
-    # the row vocabularies must not collide — a bench row is one flat dict
+    # the report vocabularies must not collide: predict_scaling joins
+    # them into one flat row
     cols = set(devprof.PIPELINE_ROW_COLUMNS)
-    assert not cols & set(devprof.TRACE_ROW_COLUMNS)
-    assert not cols & set(devprof.BUCKET_ROW_COLUMNS)
+    assert not cols & set(devprof.USHARD_ROW_COLUMNS)
+    assert not cols & set(devprof.COMPRESS_ROW_COLUMNS)
 
 
 # -- pipeline_schedule_report on synthetic traces ---------------------------
@@ -281,25 +283,3 @@ def test_schedule_occupancy_classifies_lanes():
     # formatted view renders every lane plus the aggregate
     txt = devprof.format_schedule(occ)
     assert "t0:7/0" in txt and "bubble_fraction" in txt
-
-
-# -- compile-cache key extra ------------------------------------------------
-
-def test_key_extra_sensitive_to_pp_interleave():
-    class _M:
-        n_subbatches = 1
-
-    def fn():
-        pass
-
-    base = compile_cache.key_extra(fn, model=_M())
-    assert "pp_interleave" not in base            # fill/drain keys stay
-    m1 = _M(); m1.pp_interleave = 1
-    assert compile_cache.key_extra(fn, model=m1) == base   # byte-stable
-    m2 = _M(); m2.pp_interleave = 2
-    e2 = compile_cache.key_extra(fn, model=m2)
-    assert e2.get("pp_interleave") == 2
-    m4 = _M(); m4.pp_interleave = 4
-    e4 = compile_cache.key_extra(fn, model=m4)
-    assert e4.get("pp_interleave") == 4
-    assert len({str(sorted(x.items())) for x in (base, e2, e4)}) == 3

@@ -149,3 +149,19 @@ def test_sections_and_record_keys_single_source_of_truth():
     r.print_train_info(1)
     rec = r._all_records[-1]
     assert {k for k in rec if k.startswith("t_")} == set(RECORD_KEYS)
+
+
+def test_recorder_compile_bucket():
+    rec = Recorder({"verbose": False, "printFreq": 1})
+    rec.start()
+    rec.end("compile")
+    rec.start()
+    rec.end("train")
+    rec.train_error(1, 0.5, 0.1, 8)
+    rec.print_train_info(1)
+    r = rec._all_records[-1]
+    assert r["t_compile"] >= 0 and "t_train" in r
+    # bucket resets after the print, like every section
+    assert rec.t_sec["compile"] == 0.0
+    ep = rec.print_val_info(1)
+    assert "t_compile" in ep        # cumulative, for resume-goes-to-~0

@@ -32,6 +32,7 @@ def test_ring_matches_full_attention(mesh8, causal):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_grads_match(mesh8, causal):
     """The whole point is TRAINING long sequences: gradients through the
@@ -140,7 +141,3 @@ def test_ring_attention_jit_compiles_multichip():
     ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

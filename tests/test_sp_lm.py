@@ -58,6 +58,7 @@ def test_sp_init_identical_to_dense(mesh8):
         np.asarray(a), np.asarray(b)), dense.params, sp.params)
 
 
+@pytest.mark.slow
 def test_sp_bsp_training_matches_dense(mesh8):
     dense = _make(dp=2, sp=1)
     sp = _make(dp=2, sp=4)
@@ -134,6 +135,7 @@ def test_sp_with_async_rule_smoke(mesh8):
     model.end_val()
 
 
+@pytest.mark.slow
 def test_sp_composes_with_steps_per_call(mesh8):
     """round-4 (verdict #4): the multi-step dispatch stacks sequence-
     parallel batches P(None, workers, seq) and must trace the same params
@@ -154,6 +156,7 @@ def test_sp_composes_with_steps_per_call(mesh8):
         np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-7), p1, p2)
 
 
+@pytest.mark.slow
 def test_sp_composes_with_tp_3d_mesh(mesh8):
     """round-4: dp=2 × tp=2 × sp=2 — head-sharded ring attention,
     vocab-parallel CE + seq-mean loss — must match the dense model (same
@@ -183,6 +186,7 @@ def test_sp_composes_with_tp_3d_mesh(mesh8):
     m3.end_val()
 
 
+@pytest.mark.slow
 def test_sp_composes_with_pp(mesh8):
     """round-4: dp=2 × pp=2 × sp=2 — pipeline stages of ring-attention
     blocks over sequence-sharded microbatches — matches the dense model."""
@@ -202,6 +206,7 @@ def test_sp_composes_with_pp(mesh8):
     m3.end_val()
 
 
+@pytest.mark.slow
 def test_moe_sp_pp_trains(mesh8):
     """MoE under sp×pp (round-4): the homogeneous all-MoE pipeline with
     sequence-sharded microbatches trains finite/decreasing and validates
@@ -218,7 +223,3 @@ def test_moe_sp_pp_trains(mesh8):
     m.begin_val()
     m.val_iter(0)
     m.end_val()
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

@@ -231,19 +231,6 @@ def test_exchanger_records_per_exchange_histograms():
     assert tm.hists["phase.train"].count >= 1
 
 
-def test_compile_cache_counters_mirror_into_telemetry(tmp_path):
-    from theanompi_tpu.utils.compile_cache import CompileCache
-
-    tm = telemetry.init({"telemetry": True})
-    cc = CompileCache(str(tmp_path))
-    cc._tick("hits")
-    cc._tick("misses")
-    cc._tick("misses")
-    assert tm.counters["compile_cache.hits"] == 1
-    assert tm.counters["compile_cache.misses"] == 2
-    assert cc.counters["misses"] == 2               # the local view too
-
-
 def test_watchdog_stall_message_includes_flight_tail(capfd):
     from theanompi_tpu.utils.watchdog import StallWatchdog
 

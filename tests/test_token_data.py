@@ -118,7 +118,7 @@ def test_missing_files_error(tmp_path):
 
 
 def test_vocab_guard_fires_with_model_default_vocab(tmp_path):
-    """ADVICE r3: the out-of-range check must fire even when the user relies
+    """Review r3: the out-of-range check must fire even when the user relies
     on the model's class-default vocab (no 'vocab' in config) — the model
     passes its RESOLVED vocab into TokenFileData."""
     root = _write_corpus(tmp_path, vocab=64)

@@ -107,6 +107,7 @@ def test_tp_val_matches_dense(mesh8):
     assert e5d == pytest.approx(e5t, abs=1e-6)
 
 
+@pytest.mark.slow
 def test_tp_easgd_and_gosgd_smoke(mesh8):
     """Async rules compose with tp: the extra state (EASGD center / GoSGD α)
     inherits the params' sharded layout and the exchange collective runs."""
@@ -145,6 +146,7 @@ def test_tp_checkpoint_roundtrip(tmp_path, mesh8):
     assert np.isfinite(float(model2.current_info["cost"]))
 
 
+@pytest.mark.slow
 def test_tp_with_grad_accumulation_and_multi_step_dispatch(mesh8):
     """n_subb (microbatch scan) and steps_per_call (multi-step dispatch)
     compose with tp: the tp=4 run must trace dense dp=2 exactly as in the
@@ -230,7 +232,3 @@ def test_tp_loss_head_matches_dense_oracle(mesh8):
     g_dense = jax.grad(L.softmax_cross_entropy)(logits, labels)
     np.testing.assert_allclose(np.asarray(g_tp), np.asarray(g_dense),
                                rtol=1e-5, atol=1e-7)
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

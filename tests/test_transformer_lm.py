@@ -99,7 +99,3 @@ def test_remat_is_loss_equivalent(mesh4):
         return costs
 
     np.testing.assert_allclose(run(True), run(False), rtol=1e-6, atol=1e-8)
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

@@ -246,6 +246,7 @@ def test_zero1_state_sharded_over_workers_and_model(mesh8):
     assert m.addressable_shards[0].data.shape == (1, chunk)
 
 
+@pytest.mark.slow
 def test_zero1_bit_equal_under_pp(mesh8):
     """Pipeline composition: zero chunks each stage's local stack shard;
     bit-equal to the replicated optimizer on the same pp layout."""
@@ -264,6 +265,7 @@ def test_zero1_bit_equal_under_pp(mesh8):
         np.asarray(a), np.asarray(b)), p0, p1)
 
 
+@pytest.mark.slow
 def test_zero1_bit_equal_under_3d_mesh(mesh8):
     """dp=2 × pp=2 × tp=2: leaves sharded over ONE model axis but replicated
     over the other must anchor per-axis (the all-or-nothing anchor failed
@@ -278,7 +280,3 @@ def test_zero1_bit_equal_under_3d_mesh(mesh8):
     c0 = _train(base, BSP_Exchanger(base.config), 3)
     c1 = _train(zero, BSP_Exchanger(zero.config), 3)
     np.testing.assert_array_equal(np.asarray(c0), np.asarray(c1))
-
-# excluded from the 870s-budgeted tier-1 gate; see pytest.ini (slow marker)
-import pytest as _pytest
-pytestmark = _pytest.mark.slow

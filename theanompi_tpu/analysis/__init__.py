@@ -3,8 +3,8 @@ paths.
 
 docs/design.md §6 promises the invariants are machine-checked; §12 lists
 the ones a static pass can hold: tracing safety inside fused ``lax.scan``
-bodies, ``jax.random`` key discipline, and donation rules around the AOT
-cache — each closed over the repo-wide call graph
+bodies, ``jax.random`` key discipline, and donation rules — each
+closed over the repo-wide call graph
 (``analysis/engine.py``) — plus SPMD collective discipline (axis-name
 validity, rank-divergent branches, async start/done pairing),
 PartitionSpec/shard_map schema checks, ``exchange_body`` collective

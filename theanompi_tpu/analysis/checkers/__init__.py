@@ -12,9 +12,9 @@ compat-boundary and telemetry-hot-path stay per-file (their invariants
 are lexical); schema-drift is the live-object project probe, and
 oracle-pair is the disk-scoped project probe pinning every ops/ Pallas
 kernel to a registered jnp oracle with an equality test.  The
-compile-surface pass (cache-key, retrace-hazard, dtype-flow) guards the
-AOT executable-cache contract: key_extra completeness, silent-recompile
-call shapes, and low-precision wire numerics (docs/design.md §26).
+compile-surface pass (retrace-hazard, dtype-flow) guards the traced
+programs: silent-recompile call shapes and low-precision wire numerics
+(docs/design.md §26).
 """
 
 from . import (  # noqa: F401
@@ -38,7 +38,7 @@ from . import (  # noqa: F401
 #: protocol conformance pass (scripts/lint.py expands these before
 #: checker-name validation, so the cache keys on the real names).
 CHECK_GROUPS = {
-    "compile-surface": ("cache-key", "dtype-flow", "retrace-hazard"),
+    "compile-surface": ("dtype-flow", "retrace-hazard"),
     "concurrency": ("daemon-discipline", "lock-ordering",
                     "shared-state-race", "signal-safety"),
     "protocol": ("wire-contract", "retry-safety", "state-machine"),

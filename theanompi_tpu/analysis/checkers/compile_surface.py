@@ -1,28 +1,8 @@
-"""Compile-surface discipline: cache-key completeness, retrace hazards,
-mixed-precision dtype flow (docs/design.md §26).
+"""Compile-surface discipline: retrace hazards and mixed-precision dtype
+flow (docs/design.md §26).
 
-The AOT/prewarm strategy (§10) rests on a contract every PR since 3 has
-re-pinned by hand: a config knob that shapes a traced program must be
-stamped into ``compile_cache.key_extra`` — stamped *only-when-on* so
-pre-existing cache keys stay byte-stable — and trace-reachable code must
-not silently recompile per step or silently change numerics.  Three
-checkers make the contract machine-checked:
-
-``cache-key``
-    Taints config reads (``config["x"]`` / ``self.config.get("x")`` /
-    ``parse_kv`` outputs) in functions reachable from the AOT surfaces
-    (:data:`AOT_SURFACES`) and requires any knob that flows into a
-    trace-shaping slot (scan lengths, ``lax.cond`` predicates, schedule
-    builders, bucket planners, PartitionSpec construction, jit
-    donation/static signatures — ``engine.TRACE_SHAPE_SLOTS`` /
-    ``TRACE_PRED_SLOTS``) to be covered by a ``key_extra`` stamp.
-    Coverage is the union of the knobs lexically read inside
-    ``key_extra`` itself and :data:`STAMP_KNOBS`, this checker's
-    pure-literal stamp→knobs registry; the registry is cross-validated
-    against the statically-extracted stamp vocabulary (stale or missing
-    entries are findings), and every stamp except ``fn`` must sit under
-    a guard (the only-when-on rule).  Deliberate exemptions carry
-    ``# tpulint: disable=cache-key`` at the read site.
+Trace-reachable code must not silently recompile per step or silently
+change numerics.  Two checkers hold that:
 
 ``retrace-hazard``
     Call shapes that silently recompile per step: a fresh
@@ -30,10 +10,8 @@ checkers make the contract machine-checked:
     caches by function identity), ``jax.jit`` invoked inside a loop, a
     jit-boundary parameter spent in a shape-static slot without
     ``static_argnums`` (concretization-error-or-per-value-retrace bait),
-    host values (clocks, ``os.environ``, host RNG) feeding shape
-    arithmetic in trace-reachable code, and ``.lower()`` on a program
-    that already came out of ``CompileCache.get_or_compile`` (the PR 3
-    regression class).
+    and host values (clocks, ``os.environ``, host RNG) feeding shape
+    arithmetic in trace-reachable code.
 
 ``dtype-flow``
     Low-precision wire numerics: a collective whose operand is
@@ -55,287 +33,21 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 from ..core import (Checker, Finding, ImportResolver, SourceFile,
                     register)
 from ..engine import (HOST_CLOCKS, LOW_PRECISION_DTYPES, ProgramIndex,
-                      bare_names, body_walk, collective_name, config_knob,
-                      static_dtype)
+                      body_walk, collective_name, static_dtype)
 
-COMPILE_CACHE_PATH = "theanompi_tpu/utils/compile_cache.py"
-
-#: Function simple names whose bodies build traced programs — the taint
-#: seeds.  Matched by simple name so single-file fixture runs resolve
-#: the same way the repo tree does.
-AOT_SURFACES = (
-    "aot_train_program", "_aot_from_cache", "compile_iter_fns",
-    "build_train_step", "build_val_step", "build_schedule", "plan_tree",
-    "exchange_body",
+#: Function simple names whose bodies build traced programs — the
+#: retrace-hazard seeds beside the jit boundaries.  Matched by simple
+#: name so single-file fixture runs resolve the same way the repo tree
+#: does.
+TRACE_SURFACES = (
+    "compile_iter_fns", "build_train_step", "build_val_step",
+    "build_schedule", "plan_tree", "exchange_body",
 )
-
-#: Stamps ``key_extra`` writes unconditionally by design.  Everything
-#: else must be guarded by its knob's truthiness (only-when-on): a new
-#: stamp that fires for knob-less configs would churn every pre-existing
-#: cache key (the §19/§22–§25 byte-stability rule).
-UNGUARDED_STAMPS_OK = ("fn",)
-
-#: Pure-literal coverage registry: stamp name -> the config knobs it
-#: covers.  Knobs read lexically inside ``key_extra`` itself (e.g.
-#: ``numerics``, ``update_sharding``) are extracted statically; this map
-#: carries the coverage the extraction cannot see — model/exchanger
-#: attributes that mirror config knobs set elsewhere.  The cache-key
-#: checker cross-validates the keys against the extracted stamp
-#: vocabulary, and the schema-drift live probe pins both against the
-#: keys a real ``key_extra`` run stamps.
-STAMP_KNOBS = {
-    "fn": (),
-    "model": (),
-    "n_subb": ("n_subb",),
-    "pp_interleave": ("pp_interleave", "pp", "pp_microbatches",
-                      "n_layer"),
-    "numerics": ("numerics", "numerics_every"),
-    "ushard": ("update_sharding", "ushard_min_bytes"),
-    "spc": ("steps_per_call",),
-    "rule": ("exch_strategy", "exch_mode", "sync_freq",
-             "exchange_freq"),
-    "bucket_bytes": ("bucket_bytes",),
-    "no_pallas": (),
-}
-
-#: One-line meanings, reused by ``scripts/explain_program.py --diff`` to
-#: name the knob that produced a cache-key split.
-STAMP_MEANING = {
-    "fn": "program family (train/val/exchange/zero_shadow/fsdp_val)",
-    "model": "model class",
-    "n_subb": "gradient-accumulation sub-batches per step",
-    "pp_interleave": "virtual pipeline stages per worker",
-    "numerics": "numerics health-plane cadence",
-    "ushard": "update-plane sharding min bucket bytes",
-    "spc": "fused steps per compiled call",
-    "rule": "exchange rule (Type:mode:strategy:freq)",
-    "bucket_bytes": "wire bucket size in bytes",
-    "no_pallas": "Pallas kernels disabled (jnp fallbacks traced)",
-}
 
 NONBITEXACT_NAME = "NONBITEXACT"
 
 _SHARD_MAPS = ("jax.shard_map", "jax.experimental.shard_map.shard_map",
                "theanompi_tpu.jax_compat.shard_map")
-
-
-# ---------------------------------------------------------------------------
-# key_extra static extraction (shared with the schema-drift live probe)
-# ---------------------------------------------------------------------------
-
-def key_extra_def(sf: SourceFile) -> Optional[ast.FunctionDef]:
-    """The module-level ``key_extra`` definition in one file, or None."""
-    for node in sf.tree.body:
-        if isinstance(node, ast.FunctionDef) and node.name == "key_extra":
-            return node
-    return None
-
-
-def key_extra_vocabulary(sf: SourceFile):
-    """Statically extract ``key_extra``'s stamp vocabulary.
-
-    Returns ``(stamps, knobs, problems)``: ``stamps`` maps each
-    ``extra["name"] = …`` stamp to ``(line, guarded)`` (guarded = every
-    assignment of it sits under an ``if``), ``knobs`` is every config
-    knob read lexically inside the function, ``problems`` is a list of
-    ``(line, message)`` for non-literal stamp keys (an unextractable
-    vocabulary would let the whole contract go stale silently)."""
-    fn = key_extra_def(sf)
-    if fn is None:
-        return {}, set(), []
-    stamps: Dict[str, Tuple[int, bool]] = {}
-    problems: List[Tuple[int, str]] = []
-    knobs: Set[str] = set()
-
-    def add(name: str, line: int, guarded: bool) -> None:
-        if name in stamps:
-            old_line, old_g = stamps[name]
-            stamps[name] = (old_line, old_g and guarded)
-        else:
-            stamps[name] = (line, guarded)
-
-    def visit(node: ast.AST, guarded: bool) -> None:
-        for child in ast.iter_child_nodes(node):
-            child_guarded = guarded or isinstance(node, ast.If)
-            if isinstance(child, (ast.Assign, ast.AnnAssign)):
-                targets = child.targets if isinstance(child, ast.Assign) \
-                    else [child.target]
-                for t in targets:
-                    if isinstance(t, ast.Subscript) and \
-                            isinstance(t.value, ast.Name) and \
-                            t.value.id == "extra":
-                        if isinstance(t.slice, ast.Constant) and \
-                                isinstance(t.slice.value, str):
-                            add(t.slice.value, child.lineno,
-                                child_guarded)
-                        else:
-                            problems.append((
-                                child.lineno,
-                                "non-literal key_extra stamp key — the "
-                                "stamp vocabulary must be statically "
-                                "extractable (docs/design.md §26)"))
-                    elif isinstance(t, ast.Name) and t.id == "extra" \
-                            and isinstance(child.value, ast.Dict):
-                        # the initializer: extra = {"fn": str(fn), ...}
-                        for k in child.value.keys:
-                            if isinstance(k, ast.Constant) and \
-                                    isinstance(k.value, str):
-                                add(k.value, child.lineno, child_guarded)
-                            else:
-                                problems.append((
-                                    child.lineno,
-                                    "non-literal key_extra stamp key — "
-                                    "the stamp vocabulary must be "
-                                    "statically extractable "
-                                    "(docs/design.md §26)"))
-            visit(child, child_guarded)
-
-    visit(fn, False)
-    for node in ast.walk(fn):
-        k = config_knob(node)
-        if k is not None:
-            knobs.add(k)
-    return stamps, knobs, problems
-
-
-# ---------------------------------------------------------------------------
-# cache-key completeness
-# ---------------------------------------------------------------------------
-
-def _parse_kv_locals(fn_node: ast.AST) -> Set[str]:
-    """Local names bound from ``parse_kv(...)`` — config dicts too."""
-    out: Set[str] = set()
-    for sub in ast.walk(fn_node):
-        if isinstance(sub, ast.Assign) and \
-                isinstance(sub.value, ast.Call):
-            f = sub.value.func
-            fname = f.attr if isinstance(f, ast.Attribute) else \
-                getattr(f, "id", None)
-            if fname == "parse_kv":
-                out.update(t.id for t in sub.targets
-                           if isinstance(t, ast.Name))
-    return out
-
-
-def tainted_knob_reads(rec, index: ProgramIndex):
-    """``(line, col, knob, why)`` for every config-knob read in ``rec``
-    (nested defs included — closure flows) whose value reaches a
-    trace-shaping slot, directly or through a one-assignment local."""
-    cfg_locals = _parse_kv_locals(rec.node)
-    reads: Dict[int, Tuple[str, int, int]] = {}
-    for sub in ast.walk(rec.node):
-        knob = config_knob(sub, cfg_locals)
-        if knob is not None:
-            reads[id(sub)] = (knob, sub.lineno, sub.col_offset)
-    if not reads:
-        return []
-    var_knobs: Dict[str, List[Tuple[str, int, int]]] = {}
-    for sub in ast.walk(rec.node):
-        if not isinstance(sub, ast.Assign):
-            continue
-        contained = [reads[id(n)] for n in ast.walk(sub.value)
-                     if id(n) in reads]
-        if contained:
-            for t in sub.targets:
-                if isinstance(t, ast.Name):
-                    var_knobs.setdefault(t.id, []).extend(contained)
-    out = []
-    for expr, why in index.shaping_use_sites(rec, preds=True, deep=True):
-        for n in ast.walk(expr):
-            if id(n) in reads:
-                knob, line, col = reads[id(n)]
-                out.append((line, col, knob, why))
-        for nm in bare_names(expr):
-            for knob, line, col in var_knobs.get(nm.id, ()):
-                out.append((line, col, knob, why))
-    return out
-
-
-@register
-class CacheKeyChecker(Checker):
-    name = "cache-key"
-    description = ("config knobs that shape a traced program reachable "
-                   "from an AOT surface must reach a "
-                   "compile_cache.key_extra stamp, guarded only-when-on")
-    needs_engine = True
-    disk_scoped = (COMPILE_CACHE_PATH,)
-
-    def check_program(self, index: ProgramIndex) -> Iterable[Finding]:
-        findings: List[Finding] = []
-        sf = index.by_path.get(COMPILE_CACHE_PATH)
-        if sf is None:
-            # --diff partial runs: the canonical vocabulary still gates
-            # the taint pass, so load it from disk (keyed into the
-            # result cache via ``disk_scoped``)
-            root = index.files[0].root if index.files else "."
-            try:
-                sf = SourceFile(root, COMPILE_CACHE_PATH)
-            except (OSError, SyntaxError, ValueError):
-                sf = None
-        if sf is None or key_extra_def(sf) is None:
-            # fixture trees: any in-scope module-level key_extra
-            sf = next((c for c in index.files
-                       if key_extra_def(c) is not None), None)
-
-        covered: Set[str] = set()
-        for ks in STAMP_KNOBS.values():
-            covered.update(ks)
-        if sf is not None:
-            stamps, knobs, problems = key_extra_vocabulary(sf)
-            covered |= knobs
-            for line, msg in problems:
-                findings.append(Finding(self.name, sf.path, line, 0, msg))
-            for stamp in sorted(stamps):
-                line, guarded = stamps[stamp]
-                if not guarded and stamp not in UNGUARDED_STAMPS_OK:
-                    findings.append(Finding(
-                        self.name, sf.path, line, 0,
-                        f"key_extra stamp '{stamp}' is unconditional — "
-                        f"stamp only-when-on (guard with the knob's "
-                        f"truthiness) so knob-less configs keep "
-                        f"byte-stable cache keys"))
-            if sf.path == COMPILE_CACHE_PATH:
-                # the coverage registry must track the real vocabulary
-                for stamp in sorted(set(stamps) - set(STAMP_KNOBS)):
-                    findings.append(Finding(
-                        self.name, sf.path, stamps[stamp][0], 0,
-                        f"key_extra stamp '{stamp}' has no STAMP_KNOBS "
-                        f"entry in analysis/checkers/compile_surface.py "
-                        f"— declare which config knobs it covers"))
-                fn = key_extra_def(sf)
-                for stamp in sorted(set(STAMP_KNOBS) - set(stamps)):
-                    findings.append(Finding(
-                        self.name, sf.path, fn.lineno, 0,
-                        f"stale STAMP_KNOBS entry '{stamp}' in "
-                        f"analysis/checkers/compile_surface.py: "
-                        f"key_extra stamps no such key"))
-
-        seeds = [rec for rec in index.records.values()
-                 if rec.name in AOT_SURFACES]
-        seen: Set[Tuple[str, str]] = set()
-        for rec in index.reachable(seeds):
-            if isinstance(rec.node, ast.Lambda):
-                continue
-            fidx = index.file_index[rec.sf.path]
-            if fidx.parent_func.get(id(rec.node)) is not None:
-                continue   # nested defs: analyzed with their parent's
-                #            scope so closure-variable taint is visible
-            for line, col, knob, why in tainted_knob_reads(rec, index):
-                if knob in covered:
-                    continue
-                key = (rec.sf.path, knob)
-                if key in seen:
-                    continue
-                seen.add(key)
-                findings.append(Finding(
-                    self.name, rec.sf.path, line, col,
-                    f"config knob '{knob}' shapes the traced program "
-                    f"({why} in `{rec.name}`) but never reaches a "
-                    f"compile_cache.key_extra stamp — an AOT cache hit "
-                    f"could reuse a stale executable across '{knob}' "
-                    f"values; stamp it only-when-on or justify with "
-                    f"`# tpulint: disable=cache-key`"))
-        return findings
 
 
 # ---------------------------------------------------------------------------
@@ -383,7 +95,7 @@ class RetraceHazardChecker(Checker):
     description = ("jit boundaries that silently recompile per step: "
                    "fresh lambda/partial identity, jit in a loop, "
                    "non-static shape params, host values in shape "
-                   "arithmetic, .lower() on an installed Compiled")
+                   "arithmetic")
     needs_engine = True
 
     def check_program(self, index: ProgramIndex) -> Iterable[Finding]:
@@ -406,7 +118,7 @@ class RetraceHazardChecker(Checker):
             self._scan_file(index, sf, emit, boundaries)
         for rec, static_names, kind in boundaries:
             params = rec.params()
-            for i in sorted(index.shaping_params(rec, preds=False)):
+            for i in sorted(index.shaping_params(rec)):
                 p = params[i]
                 if p in static_names:
                     continue
@@ -418,9 +130,9 @@ class RetraceHazardChecker(Checker):
                      f"static (and expect a recompile per distinct "
                      f"value) or derive it from aval shapes")
         # host values feeding shape arithmetic, over the trace-reachable
-        # closure (AOT surfaces + jit boundaries)
+        # closure (trace surfaces + jit boundaries)
         seeds = [rec for rec in index.records.values()
-                 if rec.name in AOT_SURFACES]
+                 if rec.name in TRACE_SURFACES]
         seeds += [rec for rec, _s, _k in boundaries]
         for rec in index.reachable(seeds):
             if isinstance(rec.node, ast.Lambda) or \
@@ -430,8 +142,7 @@ class RetraceHazardChecker(Checker):
             if fidx.parent_func.get(id(rec.node)) is not None:
                 continue
             resolver = rec.sf.resolver
-            for expr, why in index.shaping_use_sites(rec, preds=False,
-                                                     deep=True):
+            for expr, why in index.shaping_use_sites(rec, deep=True):
                 for n in ast.walk(expr):
                     desc = _host_value_desc(n, resolver)
                     if desc is not None:
@@ -446,8 +157,6 @@ class RetraceHazardChecker(Checker):
     def _scan_file(self, index: ProgramIndex, sf: SourceFile, emit,
                    boundaries: List[Tuple]) -> None:
         resolver = sf.resolver
-        compiled_names: Set[str] = set()
-
         fidx = index.file_index[sf.path]
 
         def note_boundary(fn_expr, call: Optional[ast.Call],
@@ -481,25 +190,6 @@ class RetraceHazardChecker(Checker):
                             boundaries.append((rec, statics,
                                                "jit-decorated"))
                 continue
-            if isinstance(node, ast.Assign) and \
-                    isinstance(node.value, ast.Call) and \
-                    isinstance(node.value.func, ast.Attribute) and \
-                    node.value.func.attr == "get_or_compile":
-                # the PR 3 regression class: re-lowering an installed
-                # Compiled re-traces and re-compiles per call
-                targets = list(node.targets)
-                if len(targets) == 1 and \
-                        isinstance(targets[0], ast.Tuple) and \
-                        targets[0].elts:
-                    targets = [targets[0].elts[0]]
-                for t in targets:
-                    if isinstance(t, ast.Name):
-                        compiled_names.add(t.id)
-                    elif isinstance(t, ast.Attribute) and \
-                            isinstance(t.value, ast.Name) and \
-                            t.value.id == "self":
-                        compiled_names.add(t.attr)
-                continue
             if not isinstance(node, ast.Call):
                 continue
             resolved = resolver.resolve(node.func)
@@ -522,25 +212,6 @@ class RetraceHazardChecker(Checker):
                     note_boundary(a0, node, "jitted")
             elif resolved in _SHARD_MAPS and node.args:
                 note_boundary(node.args[0], None, "shard-mapped")
-            if isinstance(node.func, ast.Attribute) and \
-                    node.func.attr == "lower" and \
-                    compiled_names:
-                base = node.func.value
-                attr = None
-                if isinstance(base, ast.Name):
-                    attr = base.id
-                elif isinstance(base, ast.Attribute) and \
-                        isinstance(base.value, ast.Name) and \
-                        base.value.id == "self":
-                    attr = base.attr
-                if attr in compiled_names:
-                    emit(sf.path, node,
-                         f"`.lower()` on `{attr}`, which already holds "
-                         f"a CompileCache.get_or_compile result — "
-                         f"re-lowering an installed Compiled re-traces "
-                         f"and re-compiles per call (the PR 3 "
-                         f"regression); lower once at AOT build time "
-                         f"and reuse the executable")
         # jax.jit invoked inside a loop body: a new jitted callable (and
         # trace) per iteration
         def visit(node: ast.AST, in_loop: bool) -> None:

@@ -1,7 +1,7 @@
 """donation-safety: a donated buffer must not be read after the call —
 plus shard-rebuild-dominance, the update-sharding escape gate.
 
-The invariant (docs/design.md §12, guarding the PR-3 AOT-cache rules):
+The invariant (docs/design.md §12):
 ``jax.jit(..., donate_argnums=...)`` hands the argument's HBM to the
 callee — after the call the old array is invalid, and reading it is
 use-after-free that jax only sometimes catches.

@@ -44,7 +44,7 @@ class of bug, on top of the engine's thread-role inference
   internals (``self._stop`` — the PR-8 collision).
 
 Scope: findings are reported for runtime code only (``theanompi_tpu/``,
-``scripts/``, ``bench.py``).  ``tests/`` spawn threads to *provoke*
+``scripts/``).  ``tests/`` spawn threads to *provoke*
 races; their spawn sites neither seed roles nor produce findings.
 Resolution follows the engine's static-only contract — a duck-typed
 call the call graph cannot resolve contributes nothing, so the pass

@@ -12,11 +12,10 @@ Round 12 extends the probe to the device-attribution schema
 (docs/design.md §13): ``devprof.feed_telemetry`` must emit exactly the
 declared ``device.*`` gauge vocabulary (``devprof.DEVICE_GAUGES``), the
 training sentry must emit the ``anomaly`` event with a ``kind`` from
-``sentry.ANOMALY_KINDS``, the bench trace columns must be exactly
-``devprof.TRACE_ROW_COLUMNS`` (what ``profile_row_fields`` emits), and
-``scripts/telemetry_report.py``'s consumed-event vocabulary
-(``TRACKED_EVENTS``) must cover every emitter — so a new emitter can't
-stream events the report and Perfetto export silently drop.
+``sentry.ANOMALY_KINDS``, and ``scripts/telemetry_report.py``'s
+consumed-event vocabulary (``TRACKED_EVENTS``) must cover every emitter
+— so a new emitter can't stream events the report and Perfetto export
+silently drop.
 
 Round 19 adds the protocol cross-check (docs/design.md §21): the
 center op table the ``analysis/protocol.py`` extraction reads out of
@@ -52,9 +51,6 @@ from ..core import Checker, Finding, register
 # another if a module ever moved (review finding, round 19)
 from ..protocol import (CENTER_PATH, FLEETMON_PATH, MEMBERSHIP_PATH,
                         TRACING_PATH, WIRE_PATH)
-# the key_extra vocabulary has ONE home — the compile-surface pass; the
-# round-26 probe cross-checks it against a live stamping run
-from .compile_surface import COMPILE_CACHE_PATH
 
 TELEMETRY_PATH = "theanompi_tpu/utils/telemetry.py"
 RECORDER_PATH = "theanompi_tpu/utils/recorder.py"
@@ -235,47 +231,7 @@ def device_schema_errors(devprof, sentry, telemetry,
         errors.append((DEVPROF_PATH,
                        "DEVICE_GAUGES contains a non-'device.' name"))
 
-    # 2. bench trace columns: profile_row_fields emits exactly the
-    # declared column set (bench.py folds its return verbatim)
-    fields = devprof.profile_row_fields(prof, total_flops=1e9,
-                                        peak_flops=1e12)
-    if set(fields) != set(devprof.TRACE_ROW_COLUMNS):
-        errors.append((DEVPROF_PATH,
-                       f"profile_row_fields keys {sorted(fields)} != "
-                       f"TRACE_ROW_COLUMNS "
-                       f"{sorted(devprof.TRACE_ROW_COLUMNS)}"))
-
-    # 2b. the bucketed-wire row columns (BENCH_BUCKET_BYTES rows) must
-    # stay disjoint from the trace vocabulary — a collision would let one
-    # emitter silently overwrite the other's column in the row JSON —
-    # and bench.py must emit exactly the declared names (string-level
-    # probe: bench imports jax, so the live-row check stays lexical)
-    bucket_cols = getattr(devprof, "BUCKET_ROW_COLUMNS", None)
-    if not bucket_cols:
-        errors.append((DEVPROF_PATH,
-                       "BUCKET_ROW_COLUMNS missing from devprof — the "
-                       "bucketed bench rows have no pinned vocabulary"))
-    else:
-        clash = sorted(set(bucket_cols) & set(devprof.TRACE_ROW_COLUMNS))
-        if clash:
-            errors.append((DEVPROF_PATH,
-                           f"BUCKET_ROW_COLUMNS collide with "
-                           f"TRACE_ROW_COLUMNS: {clash}"))
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-        bench_path = os.path.join(root, "bench.py")
-        if os.path.exists(bench_path):
-            with open(bench_path) as f:
-                src = f.read()
-            missing = [c for c in bucket_cols if f'"{c}"' not in src]
-            if missing:
-                errors.append(("bench.py",
-                               f"bucketed row column(s) {missing} "
-                               f"declared in devprof.BUCKET_ROW_COLUMNS "
-                               "never appear in bench.py — the rows "
-                               "would ship without them"))
-
-    # 3. the sentry's anomaly event: a live instance pushed into a NaN
+    # 2. the sentry's anomaly event: a live instance pushed into a NaN
     # must emit ANOMALY_EVENT with a declared kind and an iter field
     tm2 = telemetry.Telemetry(rank=0, run_id="drift-check")
     s = sentry.TrainingSentry({"verbose": False, "sentry_min_records": 2},
@@ -300,7 +256,7 @@ def device_schema_errors(devprof, sentry, telemetry,
             errors.append((SENTRY_PATH,
                            "anomaly event carries no 'iter' field"))
 
-    # 4. the report/Perfetto converter consumes every emitter's vocabulary
+    # 3. the report/Perfetto converter consumes every emitter's vocabulary
     if telemetry_report is not None:
         tracked = set(getattr(telemetry_report, "TRACKED_EVENTS", ()))
         want = {"phase", "train_record", "gauges",
@@ -875,106 +831,6 @@ def numerics_schema_errors(numerics, sentry, fleetmon, telemetry,
     return errors
 
 
-def key_extra_schema_errors(compile_cache_mod=None,
-                            root: Optional[str] = None) -> List[tuple]:
-    """Round-26 probe: the cache-key checker's statically-extracted
-    ``key_extra`` stamp vocabulary must equal the keys a REAL
-    ``key_extra`` run stamps (the stamping call every compile surface —
-    ``compile_iter_fns``, bench, prewarm — goes through), and both must
-    equal the checker's ``STAMP_KNOBS`` coverage registry — so neither
-    the extraction rules nor the registry can go stale (the PR 15
-    center-protocol precedent).  jax-free by construction:
-    ``compile_cache`` keeps jax out of module scope, the probe config
-    pins ``ushard_min_bytes`` so the ushard branch never imports
-    ``update_sharding``, and ``THEANOMPI_TPU_NO_PALLAS`` is forced for
-    the maximal call.  Also pins the §26 byte-stability floor: a
-    knob-less ``key_extra("val")`` must stay exactly ``{"fn": "val"}``."""
-    from ..core import SourceFile
-    from .compile_surface import (COMPILE_CACHE_PATH, STAMP_KNOBS,
-                                  key_extra_vocabulary)
-    errors: List[tuple] = []
-    if compile_cache_mod is None:
-        try:
-            from theanompi_tpu.utils import compile_cache as \
-                compile_cache_mod
-        except ImportError:
-            return errors
-    if root is None:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-    if not os.path.exists(os.path.join(root, COMPILE_CACHE_PATH)):
-        return errors
-    try:
-        sf = SourceFile(root, COMPILE_CACHE_PATH)
-    except (OSError, SyntaxError, ValueError):
-        return errors            # the parse step reports it already
-    static_stamps, _knobs, _problems = key_extra_vocabulary(sf)
-
-    # a maximal probe call: every guarded stamp switched on at once
-    class _ProbeStrategy:
-        name = "probe"
-
-    class _ProbeExchanger:
-        strategy = _ProbeStrategy()
-        mode = "params"
-        exchange_freq = 2
-        bucket_bytes = 1 << 20
-
-    class _ProbeModel:
-        n_subb = 2
-        pp_interleave = 2
-        _fsdp = None
-        config = {"numerics": True, "update_sharding": True,
-                  "ushard_min_bytes": 4096}
-
-    # the whole probe pins THEANOMPI_TPU_NO_PALLAS — "1" for the
-    # maximal call, absent for the byte-stability floor — so the
-    # verdict (which the result cache stores keyed on file contents)
-    # never depends on whatever the host process happens to export
-    saved = os.environ.get("THEANOMPI_TPU_NO_PALLAS")
-    os.environ["THEANOMPI_TPU_NO_PALLAS"] = "1"
-    try:
-        try:
-            live = compile_cache_mod.key_extra(
-                "train", model=_ProbeModel(),
-                exchanger=_ProbeExchanger(), spc=3)
-        except Exception as e:
-            return [(COMPILE_CACHE_PATH,
-                     f"the maximal jax-free key_extra probe call raised "
-                     f"{e!r} — the stamping path must stay callable "
-                     f"without a backend")]
-        os.environ.pop("THEANOMPI_TPU_NO_PALLAS", None)
-        base = compile_cache_mod.key_extra("val")
-    finally:
-        if saved is None:
-            os.environ.pop("THEANOMPI_TPU_NO_PALLAS", None)
-        else:
-            os.environ["THEANOMPI_TPU_NO_PALLAS"] = saved
-
-    if set(static_stamps) != set(live):
-        errors.append((COMPILE_CACHE_PATH,
-                       f"statically-extracted key_extra stamps "
-                       f"{sorted(static_stamps)} != keys a maximal live "
-                       f"key_extra run stamped {sorted(live)} — the "
-                       "cache-key checker's extraction rules drifted"))
-    if set(live) != set(STAMP_KNOBS):
-        errors.append((COMPILE_CACHE_PATH,
-                       f"live key_extra stamps {sorted(live)} != the "
-                       f"cache-key checker's STAMP_KNOBS registry "
-                       f"{sorted(STAMP_KNOBS)} — declare (or drop) the "
-                       "coverage entry in "
-                       "analysis/checkers/compile_surface.py"))
-
-    # §26 byte-stability floor: knob-less extras are frozen
-    if base != {"fn": "val"}:
-        errors.append((COMPILE_CACHE_PATH,
-                       f"key_extra('val') returned {base!r} — a "
-                       "knob-less config's extras must stay exactly "
-                       "{'fn': 'val'} so every pre-existing cache key "
-                       "is byte-stable"))
-    return errors
-
-
 def thread_role_coverage_errors(root: Optional[str] = None) -> List[tuple]:
     """Round-15 probe: the host-concurrency pass is only as good as its
     thread-role map, so every ``threading.Thread(...)``/``Timer(...)``
@@ -1188,16 +1044,16 @@ class SchemaDriftChecker(Checker):
     name = "schema-drift"
     description = ("recorder.SECTIONS / print_train_info record keys / "
                    "telemetry phase events must derive from telemetry."
-                   "PHASES; device.* gauges, sentry anomaly schema, and "
-                   "bench trace columns must match their declared "
-                   "vocabularies (live-object probe)")
+                   "PHASES; device.* gauges and the sentry anomaly "
+                   "schema must match their declared vocabularies "
+                   "(live-object probe)")
     reads_files = False    # `--only schema-drift` skips the repo parse
     # every file the live probes load beyond the lint selection — the
     # runner folds these into partial runs' cache keys (core.Checker)
     disk_scoped = (RECORDER_PATH, TELEMETRY_PATH, DEVPROF_PATH,
                    SENTRY_PATH, REPORT_PATH, MEMBERSHIP_PATH,
                    CHAOS_PATH, WIRE_PATH, TRACING_PATH, FLEETMON_PATH,
-                   CENTER_PATH, NUMERICS_PATH, COMPILE_CACHE_PATH)
+                   CENTER_PATH, NUMERICS_PATH)
 
     def check_project(self, files):
         # normal import both under pytest (real package loaded) and under
@@ -1277,9 +1133,5 @@ class SchemaDriftChecker(Checker):
         # round 15: the thread-role map must see and resolve every
         # Thread/Timer spawn in the thread-heaviest runtime modules
         errors += thread_role_coverage_errors()
-        # round 26: the key_extra stamp vocabulary, static extraction vs
-        # a real (jax-free) stamping run vs the cache-key checker's
-        # coverage registry
-        errors += key_extra_schema_errors()
         return [Finding(self.name, path, 1, 0, msg)
                 for path, msg in errors]

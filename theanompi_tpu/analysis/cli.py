@@ -124,7 +124,7 @@ def _cached_run(root, paths, only, disable, cache_dir=None):
     rels = iter_py_paths(root, paths)
     lint_rels = {r.replace(os.sep, "/") for r in rels}
     # EVERY file a disk-scoped checker loads beyond the lint selection
-    # (live-probe targets, the key_extra vocabulary, ops/ kernels) must
+    # (live-probe targets, ops/ kernels) must
     # key the cache even on partial runs whose path set does not cover
     # it — but they are NOT part of the linted set then, so no per-file
     # entry may be stored for them (it would read as "no findings" to a

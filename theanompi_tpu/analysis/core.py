@@ -27,7 +27,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 # Files/dirs the repo-wide walk visits by default (repo-relative).
-DEFAULT_PATHS = ("theanompi_tpu", "scripts", "tests", "bench.py")
+DEFAULT_PATHS = ("theanompi_tpu", "scripts", "tests")
 
 BASELINE_NAME = "tpulint_baseline.json"
 

@@ -112,12 +112,6 @@ def collective_name(resolved: Optional[str]) -> Optional[str]:
 # compile-surface vocabulary (docs/design.md §26)
 # ---------------------------------------------------------------------------
 
-#: Receiver names whose subscripts / ``.get`` reads count as config-knob
-#: reads: the tail of the dotted receiver (``config``, ``self.config``,
-#: ``model.config``, ``cfg``) — plus any local assigned from ``parse_kv``
-#: (the caller passes those in as ``extra_receivers``).
-CONFIG_RECEIVERS = {"config", "cfg"}
-
 #: Trace-shaping consumer slots that must be STATIC at trace time — a
 #: host value landing here changes the traced program's shape (scan
 #: lengths, schedule tables, iota/zeros shapes, PartitionSpecs, jit
@@ -144,16 +138,6 @@ TRACE_SHAPE_SLOTS = {
     "theanompi_tpu.parallel.buckets.plan_buckets": (1, "bucket_bytes"),
 }
 
-#: Predicate/selector slots: traced values are LEGAL here (``lax.cond``
-#: runs both branches), but a config knob baked into one still selects
-#: program behavior per compile — so the cache-key pass treats them as
-#: trace-shaping while the retrace pass does not.
-TRACE_PRED_SLOTS = {
-    "jax.lax.cond": (0, "pred"),
-    "jax.lax.switch": (0, "index"),
-    "jax.lax.fori_loop": (0, 1, "lower", "upper"),
-}
-
 #: Method names whose arguments are shape slots on any receiver.
 TRACE_SHAPE_METHODS = {"reshape", "broadcast_to"}
 
@@ -173,42 +157,9 @@ LOW_PRECISION_DTYPES = {"bfloat16", "float16", "float8_e4m3fn",
 _DTYPE_MODULES = ("jax.numpy.", "numpy.", "jax.dtypes.")
 
 
-def config_knob(node: ast.AST,
-                extra_receivers: Optional[Set[str]] = None
-                ) -> Optional[str]:
-    """The knob string of a config read expression — ``config["x"]``,
-    ``cfg.get("x", d)``, ``self.config.get("x")`` — or None.  A dotted
-    receiver matches when its last segment is in :data:`CONFIG_RECEIVERS`
-    or the whole chain is in ``extra_receivers`` (parse_kv locals)."""
-    recv = key = None
-    if isinstance(node, ast.Subscript):
-        recv = node.value
-        if isinstance(node.slice, ast.Constant) and \
-                isinstance(node.slice.value, str):
-            key = node.slice.value
-    elif isinstance(node, ast.Call) and \
-            isinstance(node.func, ast.Attribute) and \
-            node.func.attr == "get" and node.args:
-        recv = node.func.value
-        a0 = node.args[0]
-        if isinstance(a0, ast.Constant) and isinstance(a0.value, str):
-            key = a0.value
-    if recv is None or key is None:
-        return None
-    dotted = ImportResolver.dotted(recv)
-    if dotted is None:
-        return None
-    if dotted.rsplit(".", 1)[-1] in CONFIG_RECEIVERS or \
-            (extra_receivers and dotted in extra_receivers):
-        return key
-    return None
-
-
-def shaping_slot_exprs(call: ast.Call, resolver: ImportResolver,
-                       preds: bool = True):
+def shaping_slot_exprs(call: ast.Call, resolver: ImportResolver):
     """``(expr, slot description)`` for every argument of ``call``
-    occupying a trace-shaping slot.  ``preds=False`` restricts to the
-    shape-static slots (the retrace pass)."""
+    occupying a trace-shaping slot."""
     resolved = resolver.resolve(call.func)
     out = []
 
@@ -231,8 +182,6 @@ def shaping_slot_exprs(call: ast.Call, resolver: ImportResolver,
 
     if resolved in TRACE_SHAPE_SLOTS:
         take(TRACE_SHAPE_SLOTS[resolved], f"`{resolved.rsplit('.', 1)[-1]}`")
-    elif preds and resolved in TRACE_PRED_SLOTS:
-        take(TRACE_PRED_SLOTS[resolved], f"`{resolved.rsplit('.', 1)[-1]}`")
     elif resolved == "jax.jit":
         for kw in call.keywords:
             if kw.arg in TRACE_JIT_KWARGS:
@@ -511,7 +460,7 @@ class ProgramIndex:
         self._callees_cache: Dict[int, List[FuncRecord]] = {}
         self._summary_cache: Dict[int, FuncSummary] = {}
         self._key_params_cache: Optional[Dict[int, Set[int]]] = None
-        self._shaping_params_cache: Dict[bool, Dict[int, Set[int]]] = {}
+        self._shaping_params_cache: Optional[Dict[int, Set[int]]] = None
         self._transitive_cache: Dict[int, TransitiveSummary] = {}
 
     # -- construction ------------------------------------------------------
@@ -914,19 +863,15 @@ class ProgramIndex:
                                     changed = True
         return out
 
-    def shaping_params(self, rec: FuncRecord, preds: bool = True
-                       ) -> Set[int]:
+    def shaping_params(self, rec: FuncRecord) -> Set[int]:
         """Parameter positions this function spends in trace-shaping
         slots — directly, or by passing them into a callee that does
-        (fixpoint, like :meth:`key_params`).  ``preds=False`` restricts
-        to the shape-static slots (the retrace-hazard pass); the default
-        also counts predicate/selector slots (the cache-key pass)."""
-        if preds not in self._shaping_params_cache:
-            self._shaping_params_cache[preds] = \
-                self._compute_shaping_params(preds)
-        return self._shaping_params_cache[preds].get(id(rec.node), set())
+        (fixpoint, like :meth:`key_params`)."""
+        if self._shaping_params_cache is None:
+            self._shaping_params_cache = self._compute_shaping_params()
+        return self._shaping_params_cache.get(id(rec.node), set())
 
-    def _compute_shaping_params(self, preds: bool) -> Dict[int, Set[int]]:
+    def _compute_shaping_params(self) -> Dict[int, Set[int]]:
         out: Dict[int, Set[int]] = {}
         for rec in self.records.values():
             params = rec.params()
@@ -936,8 +881,7 @@ class ProgramIndex:
             for sub in body_walk(rec.node):
                 if not isinstance(sub, ast.Call):
                     continue
-                for expr, _why in shaping_slot_exprs(sub, rec.sf.resolver,
-                                                     preds=preds):
+                for expr, _why in shaping_slot_exprs(sub, rec.sf.resolver):
                     for nm in bare_names(expr):
                         if nm.id in params:
                             direct.add(params.index(nm.id))
@@ -982,8 +926,7 @@ class ProgramIndex:
                                         changed = True
         return out
 
-    def shaping_use_sites(self, rec: FuncRecord, preds: bool = True,
-                          deep: bool = False):
+    def shaping_use_sites(self, rec: FuncRecord, deep: bool = False):
         """``(expr, why)`` for every expression in ``rec``'s body that
         occupies a trace-shaping slot — the direct consumer slots plus
         arguments feeding a callee parameter the callee spends in one.
@@ -998,13 +941,13 @@ class ProgramIndex:
         for sub in walk:
             if not isinstance(sub, ast.Call):
                 continue
-            out.extend(shaping_slot_exprs(sub, resolver, preds=preds))
+            out.extend(shaping_slot_exprs(sub, resolver))
             enc = idx.enclosing.get(id(sub.func), rec.node)
             if ctor_types is None:
                 ctor_types = self._local_ctor_types(rec)
             for tgt in self.resolve_call(rec.sf, sub.func, enc,
                                          ctor_types):
-                sp = self.shaping_params(tgt, preds=preds)
+                sp = self.shaping_params(tgt)
                 if not sp:
                     continue
                 tparams = tgt.params()

@@ -25,7 +25,7 @@ from .parallel.mesh import WORKER_AXIS, init_multihost, worker_mesh
 
 def canonical_prng_impl(impl):
     """Normalize user-facing PRNG names to jax's ('threefry' is accepted as
-    an alias for 'threefry2x32'). Shared by the worker path and bench.py."""
+    an alias for 'threefry2x32')."""
     return {"threefry": "threefry2x32"}.get(impl, impl)
 
 

@@ -110,14 +110,6 @@ def main(argv=None) -> int:
                         "center_down/center_restored event pair; workers "
                         "ride a center outage out on wire retries "
                         "(parallel/wire.py, design.md §15)")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent AOT executable cache dir "
-                        "(utils/compile_cache): compile_iter_fns "
-                        "deserializes pre-built executables instead of "
-                        "recompiling — pre-populate off-line with "
-                        "scripts/prewarm_cache.py; supervised restarts and "
-                        "checkpoint resumes then skip the XLA compile "
-                        "(defaults to $THEANOMPI_COMPILE_CACHE if set)")
     p.add_argument("--record-dir", default=None, metavar="DIR",
                    help="record/telemetry directory (same as the "
                         "record_dir=DIR config key): recorder dumps, the "
@@ -130,9 +122,6 @@ def main(argv=None) -> int:
     kv = list(args.config)
     if args.n_workers:
         kv.append(f"n_workers={args.n_workers}")
-    if args.compile_cache and \
-            not any(c.startswith("compile_cache=") for c in kv):
-        kv.append(f"compile_cache={args.compile_cache}")
     if args.record_dir and \
             not any(c.startswith("record_dir=") for c in kv):
         kv.append(f"record_dir={args.record_dir}")

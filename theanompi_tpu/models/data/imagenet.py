@@ -13,7 +13,7 @@ hickle when installed, with a ``.npy``/``.npz`` fallback per file extension.
 Batches leave the host as uint8 crops; the step program casts them and
 subtracts the mean (``ModelBase.stage_input``).
 Without a data dir it synthesizes deterministic random uint8 image batches —
-enough for throughput benchmarking (bench.py) and pipeline tests, where only
+enough for throughput benchmarking and pipeline tests, where only
 shapes and rates matter.
 """
 

@@ -399,7 +399,7 @@ class BatchNorm(Layer):
     but folds (mean, inv·scale, bias) into per-channel bf16 vectors and
     normalizes in bf16 — no fp32 activation tensor is materialized between
     bf16 convs.  A/B lever for the BN share of ResNet-50 step time
-    (BASELINE.md round-3 analysis, finding 2)."""
+    (never set against the default on a chip: ROADMAP S8)."""
 
     has_state = True
 

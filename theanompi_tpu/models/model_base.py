@@ -160,10 +160,9 @@ class ModelBase:
             # Leaf-wise update-plane sharding (parallel/update_sharding.py,
             # docs/design.md §23): optimizer moments chunk per leaf over
             # the workers axis, one fused allgather rebuilds full params
-            # inside the step.  Wrapped HERE (not at compile time) so the
-            # prewarm venue's `_state_avals` → `self.opt.init` sees the
-            # chunked shapes and every venue requests byte-identical
-            # programs.  Under a non-BSP `rule` only the exchanger's
+            # inside the step.  Wrapped HERE (not at compile time) so
+            # `self.opt.init` sees the chunked shapes wherever it is
+            # called.  Under a non-BSP `rule` only the exchanger's
             # shardable extra (EASGD/ASGD centers) shards — async rules'
             # moments diverge per worker and must stay local.
             assert not self.config.get("zero_opt", False), (
@@ -417,6 +416,8 @@ class ModelBase:
                    "bn_state": self.bn_state, "extra": extra}
         self._state_specs = None if self.param_specs() is None else \
             steps.state_partition_specs(self, self.exchanger)
+        # the read-path jits close over the specs above: rebuild on demand
+        self._zero_shadow_jit = self._fsdp_val_jit = None
         self.step_state = {
             k: steps.replicate_tree(
                 v, n, self.mesh,
@@ -490,105 +491,41 @@ class ModelBase:
         self.numerics_aux = None
         self.val_fn = steps.build_val_step(self.mesh, self)
         self._step_rng = jax.random.key(self.seed + 2)
-        # Persistent AOT executable cache (utils/compile_cache.py): when a
-        # cache dir is configured (config `compile_cache` or the
-        # THEANOMPI_COMPILE_CACHE env var), every compile surface switches
-        # from lazy first-call jit to explicit lower → get_or_compile —
-        # a warm cache turns minutes of XLA compile into seconds of
-        # deserialize (supervised restarts, checkpoint resume, an off-line
-        # prewarm).  Unconfigured — the default — behavior is the lazy
-        # jit, bit for bit.  With it on, a surface whose AOT build or
-        # deserialize fails falls back to its lazy jit and says so.
-        self._aot_from_cache()
 
-    # -- AOT executable cache ---------------------------------------------
+    # -- abstract input signature ------------------------------------------
 
     _peek_aval_cache = None
 
-    def _peek_batch_aval(self, val: bool = False):
-        """Shape/dtype of one batch WITHOUT disturbing the stream: peek the
-        underlying source (bypassing a PrefetchLoader's queue) and rewind
-        its cursor — the same round-trip checkpoint resume relies on.
+    def _peek_batch_aval(self):
+        """Shape/dtype of one train batch WITHOUT disturbing the stream:
+        peek the underlying source (bypassing a PrefetchLoader's queue) and
+        rewind its cursor — the same round-trip checkpoint resume relies on.
 
-        Memoized per (train/val): the peek-and-rewind touches the wrapped
-        source directly, which is only safe while no PrefetchLoader
-        producer thread is drawing from it — true on the FIRST
-        compile_iter_fns (it precedes the first shuffle_data in every
-        venue), not on a mid-run recompile, where an unsynchronized
-        set_cursor would yank the live producer's cursor/augmentation RNG
-        backward.  Batch shapes are fixed for the life of the data object,
-        so recompiles reuse the first compile's avals instead of peeking."""
+        Memoized: the peek-and-rewind touches the wrapped source directly,
+        which is only safe while no PrefetchLoader producer thread is
+        drawing from it.  Batch shapes are fixed for the life of the data
+        object, so later calls reuse the first one's avals."""
         if self._peek_aval_cache is None:
-            self._peek_aval_cache = {}
-        if val not in self._peek_aval_cache:
             inner = getattr(self.data, "_data", None) or self.data
             cursor = inner.get_cursor() if hasattr(inner, "get_cursor") \
                 else None
-            batch = inner.next_val_batch(0) if val \
-                else inner.next_train_batch(0)
+            batch = inner.next_train_batch(0)
             if cursor is not None and hasattr(inner, "set_cursor"):
                 inner.set_cursor(cursor)
-            self._peek_aval_cache[val] = jax.tree.map(
+            self._peek_aval_cache = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(np.shape(x),
                                                np.asarray(x).dtype), batch)
-        return self._peek_aval_cache[val]
-
-    def _sds_like(self, tree):
-        """Abstract avals mirroring a placed pytree, shardings included —
-        what `.lower()` needs so the cached executable's expected input
-        shardings match the live arrays exactly."""
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=x.sharding), tree)
-
-    def _state_avals(self, exchanger=None):
-        """Boxed-state avals: from the live ``step_state`` when placed, else
-        from host templates (the off-line topology-AOT venue of
-        ``scripts/prewarm_cache.py``, whose mesh is non-addressable)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        if self.step_state is not None:
-            return self._sds_like(self.step_state)
-        exchanger = exchanger or self.exchanger
-        n = self.mesh.shape[WORKER_AXIS]
-        if self._fsdp is not None:
-            chunk = jax.ShapeDtypeStruct((self._fsdp.chunk,), jnp.float32)
-            unboxed = {"params": chunk,
-                       "opt_state": jax.eval_shape(self.opt.init, chunk),
-                       "bn_state": self.bn_state,
-                       "extra": exchanger.extra_state_template()}
-        else:
-            unboxed = {"params": self.params,
-                       "opt_state": jax.eval_shape(self.opt.init,
-                                                   self.params),
-                       "bn_state": self.bn_state,
-                       "extra": exchanger.extra_state_template()}
-        specs = steps.state_partition_specs(self, exchanger) \
-            if self.param_specs() is not None \
-            else {k: P(WORKER_AXIS) for k in unboxed}
-
-        def mk(x, s):
-            shape = tuple(getattr(x, "shape", None) if hasattr(x, "shape")
-                          else np.shape(x))
-            dtype = getattr(x, "dtype", None) or np.asarray(x).dtype
-            return jax.ShapeDtypeStruct(
-                (n,) + shape, dtype, sharding=NamedSharding(self.mesh, s))
-
-        out = {}
-        for k, v in unboxed.items():
-            s = specs[k]
-            if steps._is_spec(s):
-                out[k] = jax.tree.map(lambda x: mk(x, s), v)
-            else:
-                out[k] = jax.tree.map(mk, v, s, is_leaf=lambda x: x is None)
-        return out
+        return self._peek_aval_cache
 
     def _train_input_avals(self, spc: int, exchanger=None):
         """The abstract input signature of one train dispatch at the given
-        ``steps_per_call`` — the lowering avals shared by compile_iter_fns,
-        bench.py's flop-count path, and scripts/prewarm_cache.py, so every
-        venue requests byte-identical programs from the executable cache."""
+        ``steps_per_call``, from the placed ``step_state`` (shardings
+        included) and one peeked batch: ``train_fn.lower(*avals)`` is the
+        program the live arguments run (``benchmarks/harness.py`` joins
+        trace names to scopes with it).  ``exchanger`` is accepted for
+        that caller and not read."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        peek = self._peek_batch_aval(val=False)
+        peek = self._peek_batch_aval()
         bs = self.batch_spec()
         base = tuple(bs) if bs is not None else (WORKER_AXIS,)
         spec = P(*base) if spc == 1 else P(None, *base)
@@ -597,159 +534,14 @@ class ModelBase:
             lambda a: jax.ShapeDtypeStruct(
                 a.shape if spc == 1 else (spc,) + a.shape, a.dtype,
                 sharding=sh), peek)
-        return (self._state_avals(exchanger), batch_avals,
+        state_avals = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            self.step_state)
+        return (state_avals, batch_avals,
                 jax.ShapeDtypeStruct((), jnp.float32),
                 jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
                 jax.ShapeDtypeStruct((), jnp.int32))
-
-    def aot_train_program(self, cache, spc: Optional[int] = None,
-                          exchanger=None, load: bool = True):
-        """The ONE lower → ``get_or_compile`` sequence for THE train
-        program at a given ``steps_per_call`` — shared by
-        ``compile_iter_fns`` (below), bench.py's spc=1 flop-count path,
-        and both venues of ``scripts/prewarm_cache.py``.  The cache key is
-        content-addressed, so a drifted label/avals/extras composition in
-        any one venue silently forfeits the prewarm hit this subsystem
-        exists to guarantee — the composition therefore lives here, once.
-
-        Returns ``(compiled, info)`` as ``get_or_compile`` does
-        (``compiled`` is ``None`` on a ``load=False`` hit)."""
-        from ..utils import compile_cache
-        exchanger = exchanger if exchanger is not None else self.exchanger
-        spc = int(self.steps_per_call if spc is None else spc)
-        train_fn = steps.build_train_step(self.mesh, self, exchanger,
-                                          n_steps=spc)
-        lowered = train_fn.lower(*self._train_input_avals(spc, exchanger))
-        return cache.get_or_compile(
-            lowered, label=f"train:{type(self).__name__}:spc{spc}",
-            mesh=self.mesh,
-            extra=compile_cache.key_extra("train", self, exchanger,
-                                          spc=spc), load=load)
-
-    def _aot_from_cache(self) -> None:
-        """Explicit lower → ``get_or_compile`` for every compile surface:
-        train, val, the standalone exchange collective (unfused runs), and
-        the zero-shadow / fsdp-val read paths.  Each surface falls back to
-        its plain lazy jit independently on ANY failure — the cache can
-        slow nothing down and break nothing."""
-        from ..utils import compile_cache
-        cache = compile_cache.resolve(self.config)
-        self.compile_cache = cache
-        self.compile_info: Dict[str, Any] = {
-            "cache_dir": cache.cache_dir if cache.enabled else None,
-            "train": {"cache": "off", "compile_secs": None}}
-        self._train_compiled = None
-        if not cache.enabled:
-            return
-        if jax.process_count() > 1:
-            # per-host lowering avals are local shapes; the cached global
-            # program would never match — lazy jit handles multi-host
-            self.compile_info["note"] = "off (multi-host)"
-            return
-        spc = int(self.steps_per_call)
-        name = type(self).__name__
-        def attempt(fn_name, build):
-            try:
-                compiled, info = build()
-                self.compile_info[fn_name] = info
-                return compiled
-            except Exception as e:
-                self.compile_info[fn_name] = {"cache": "error",
-                                              "error": repr(e)[:300]}
-                if self.verbose:
-                    print(f"compile cache: {fn_name} AOT failed "
-                          f"({repr(e)[:200]}) — lazy jit fallback",
-                          flush=True)
-                return None
-
-        compiled = attempt("train",
-                           lambda: self.aot_train_program(cache, spc=spc))
-        if compiled is not None:
-            self.train_fn = compiled
-            self._train_compiled = compiled
-
-        def build_val():
-            n = self.mesh.shape[WORKER_AXIS]
-            if self._fsdp is not None:
-                # begin_val assembles FULL boxed param trees from the chunks
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                sh = NamedSharding(self.mesh, P(WORKER_AXIS))
-                pav = jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(
-                        (n,) + tuple(np.shape(p)), np.asarray(p).dtype,
-                        sharding=sh), self.params)
-            else:
-                pav = self._sds_like(self.step_state["params"])
-            bn_av = self._sds_like(self.step_state["bn_state"])
-            batch_av = self._val_batch_avals()
-            lowered = self.val_fn.lower(pav, bn_av, batch_av)
-            return cache.get_or_compile(
-                lowered, label=f"val:{name}", mesh=self.mesh,
-                extra=compile_cache.key_extra("val", self, self.exchanger))
-
-        compiled = attempt("val", build_val)
-        if compiled is not None:
-            self.val_fn = compiled
-
-        exch = self.exchanger
-        if exch is not None and getattr(exch, "_exchange_fn", None) \
-                is not None and not getattr(exch, "fused", False):
-            # the standalone collective the worker loop dispatches between
-            # steps (spc=1); fused runs carry the cadence inside the train
-            # program and never call it on the hot path
-            def build_exchange():
-                lowered = exch._exchange_fn.lower(
-                    self._state_avals(),
-                    jax.ShapeDtypeStruct((), jax.random.key(0).dtype),
-                    jax.ShapeDtypeStruct((), jnp.int32))
-                return cache.get_or_compile(
-                    lowered, label=f"exchange:{name}", mesh=self.mesh,
-                    extra=compile_cache.key_extra("exchange", self, exch))
-
-            compiled = attempt("exchange", build_exchange)
-            if compiled is not None:
-                exch._exchange_fn = compiled
-
-        if self.config.get("zero_opt", False) and self.config.get(
-                "ema_decay"):
-            def build_shadow():
-                # a prior _aot_from_cache pass stored the AOT Compiled in
-                # the memo — reset so _zero_shadow_fn rebuilds the lazy
-                # jit wrapper (a Compiled has no .lower) on recompile
-                self._zero_shadow_jit = None
-                lowered = self._zero_shadow_fn().lower(self._state_avals())
-                return cache.get_or_compile(
-                    lowered, label=f"zero_shadow:{name}", mesh=self.mesh,
-                    extra=compile_cache.key_extra("zero_shadow", self))
-
-            compiled = attempt("zero_shadow", build_shadow)
-            if compiled is not None:
-                self._zero_shadow_jit = compiled
-
-        if self._fsdp is not None:
-            def build_fsdp_val():
-                self._fsdp_val_jit = None     # same memo reset as above
-                lowered = self._fsdp_val_fn().lower(self._state_avals())
-                return cache.get_or_compile(
-                    lowered, label=f"fsdp_val:{name}", mesh=self.mesh,
-                    extra=compile_cache.key_extra("fsdp_val", self))
-
-            compiled = attempt("fsdp_val", build_fsdp_val)
-            if compiled is not None:
-                self._fsdp_val_jit = compiled
-        secs = [v.get("compile_secs") for v in self.compile_info.values()
-                if isinstance(v, dict) and v.get("compile_secs")]
-        self.compile_info["total_compile_secs"] = round(sum(secs), 3)
-
-    def _val_batch_avals(self):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        peek = self._peek_batch_aval(val=True)
-        bs = self.batch_spec()
-        base = tuple(bs) if bs is not None else (WORKER_AXIS,)
-        sh = NamedSharding(self.mesh, P(*base))
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
-            peek)
 
     # -- contract: iteration -----------------------------------------------
 
@@ -1107,7 +899,7 @@ class ModelBase:
                                             if k not in ident),
                       # lets load() give a targeted error (not a raw shape
                       # mismatch) when per-worker state meets a different
-                      # worker count (round-4 ADVICE #3)
+                      # worker count (round-4 review)
                       "n_workers": self.mesh.shape[WORKER_AXIS]}
         if self._fsdp is not None:
             # the chunk layout facts, so a resume on a DIFFERENT worker
@@ -1223,7 +1015,7 @@ class ModelBase:
         # feedback buffers, async diverged replicas) cannot cross a
         # worker-count change — fail with the real reason instead of a
         # raw leaf-shape mismatch deep in load_checkpoint (round-4
-        # ADVICE #3).  Worker-count-portable layouts: dedup'd replicas
+        # review).  Worker-count-portable layouts: dedup'd replicas
         # (BSP), and the FSDP/ZeRO chunked parts handled by refit above.
         n_saved = peek.get("n_workers")
         if n_saved is not None and int(n_saved) != n:

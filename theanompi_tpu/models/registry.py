@@ -1,7 +1,8 @@
-"""Model registry shared by the bench/sweep harnesses.
+"""Model registry shared by the CPU sweep and prediction scripts.
 
-One source of truth for the short names used by ``bench.py`` (BENCH_MODEL)
-and ``scripts/scaling_sweep.py`` (--model): dotted modelfile, modelclass,
+One source of truth for the short names used by
+``scripts/scaling_sweep.py`` (--model) and ``scripts/predict_scaling.py``:
+dotted modelfile, modelclass,
 and the synthetic-data config that makes the model runnable with zero data
 setup — the same (modelfile, modelclass) import-by-string contract the
 reference's launcher used (SURVEY.md §2.1).
@@ -16,8 +17,8 @@ MODELS = {
               {"synthetic_batches": 4}),
     "resnet50": ("theanompi_tpu.models.resnet50", "ResNet50",
                  {"synthetic_batches": 4}),
-    # sample_kind rides the extra dict: bench.py labels throughput honestly
-    # (sequences/sec, no cross-unit vs_baseline) for sequence models
+    # sample_kind rides the extra dict: sequence models count sequences,
+    # not images
     "transformer_lm": ("theanompi_tpu.models.transformer_lm", "TransformerLM",
                        {"synthetic_train": 2048,
                         "sample_kind": "sequences"}),

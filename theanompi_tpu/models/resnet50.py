@@ -115,7 +115,7 @@ class ResNet50(ModelBase):
     def build_model(self) -> None:
         cd = self.config.get("compute_dtype", jnp.bfloat16)
         # bn_norm_dtype='bfloat16': normalize in bf16 with fp32 stats —
-        # perf A/B lever (BASELINE.md round-3 finding 2); default fp32-exact
+        # perf A/B lever (ROADMAP S8); default fp32-exact
         bn_nd = self.config.get("bn_norm_dtype")
         if isinstance(bn_nd, str):
             bn_nd = jnp.dtype(bn_nd).type if bn_nd != "none" else None

@@ -22,9 +22,7 @@ wires share:
   bucket becomes its own single-leaf bucket, never split across buckets
   mid-leaf and never merged with neighbors.  Purity makes the plan
   stable across compiles, independent of membership masks (masks scale
-  VALUES, not shapes), and hashable into the AOT cache key extras
-  (:func:`plan_signature`; ``compile_cache.key_extra`` folds the
-  ``bucket_bytes`` knob into the rule signature).
+  VALUES, not shapes), and hashable (:func:`plan_signature`).
 
 * :func:`pack` / :func:`unpack` — leaves ↔ one contiguous 1-D vector
   per bucket.  Reshape+concatenate+slice only: bit-exact round-trip by

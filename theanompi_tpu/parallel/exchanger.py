@@ -346,8 +346,7 @@ class Exchanger:
         """The extra-state shapes the step machinery carries: the full
         template, with the plan-sharded keys' leaves chunked to the
         per-worker ``[chunk]`` windows when update-plane sharding is
-        active — every venue (live compile, ``_state_avals`` prewarm)
-        derives byte-identical programs from the same shapes."""
+        active."""
         full = self._extra_full_template()
         plan = self.update_plan()
         if plan is None:

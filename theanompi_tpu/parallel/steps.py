@@ -287,8 +287,7 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
     pipelining on: a whole pipeline round (forward schedule + its scan
     transpose) per scanned step, zero host round-trips between ticks.
     The schedule table is static (a pure function of ``(pp, v, M)``
-    baked at trace time), so fusing changes no cache key beyond the
-    ``pp_interleave`` extra ``utils/compile_cache.key_extra`` stamps.
+    baked at trace time).
     """
     axis = WORKER_AXIS
     n = mesh.shape[axis]

@@ -14,8 +14,7 @@ THIN CONFIGURATION of that wrapper: :func:`zero1` is
 ``update_sharding.flat_shard_opt`` — the flat-chunk-everything layout,
 which additionally carries the tensor/pipeline composition
 (``model_shards``/``pspecs``).  Config ``zero_opt=true`` behaves exactly
-as before, cache keys included (``compile_cache.key_extra`` stamps
-nothing new unless ``update_sharding`` is on).  Bit-equivalence with the
+as before.  Bit-equivalence with the
 unsharded optimizer holds exactly (elementwise update math on disjoint
 chunks; no reduction-order change) and is pinned in ``tests/test_zero.py``,
 ragged param counts (P=10, N=4 — explicit ``padded_size`` padding)

@@ -84,9 +84,8 @@ its own ``train_fn`` call in ``telemetry.span("train.call")``), one
 
 Consumers: the worker's ``trace_dir`` capture feeds the result into the
 PR 4 telemetry registry as ``device.*`` gauges (:func:`feed_telemetry` —
-names pinned by the tpulint schema-drift checker), ``bench.py``'s
-``BENCH_TRACE=1`` folds :data:`TRACE_ROW_COLUMNS` into the row JSON, and
-``scripts/profile_model.py`` prints the same breakdown interactively.
+names pinned by the tpulint schema-drift checker) and prints
+:func:`format_profile`.
 
 No jax at module scope (the lint CLI and stdlib scripts import this for
 the schema constants); :func:`capture` imports it lazily.
@@ -120,50 +119,22 @@ DEVICE_GAUGES = (
 )
 PROFILE_EVENT = "device_profile"
 
-# The bench-row columns BENCH_TRACE=1 adds (profile_row_fields emits
-# exactly these keys; scripts/merge_matrix.py treats them — like any
-# column — as unknown when absent, never as a regression).
-TRACE_ROW_COLUMNS = (
-    "overlap_ratio",
-    "exposed_comm_secs",
-    "device_compute_secs",
-    "device_comm_secs",
-    "device_mfu",
-    # per-lane idle share between compute intervals inside the dispatch
-    # window (ROADMAP item 2's pipeline-schedule acceptance metric):
-    # span-weighted over compute lanes, 1 − busy/span per lane.  Exposed
-    # same-lane collectives count as bubble deliberately — from the
-    # compute pipeline's perspective a stall is a stall.
-    "bubble_fraction",
-)
-
-# The bench-row columns BENCH_BUCKET_BYTES adds (the bucketed-wire rows,
-# parallel/buckets.py): the configured bucket size and the collectives
-# -per-exchange count the planner produced.  Declared HERE — the one
-# jax-free schema home for bench-row vocabularies — so the tpulint
-# schema-drift checker can pin bench's emission against it and guarantee
-# it stays disjoint from TRACE_ROW_COLUMNS (a name collision would
-# silently overwrite a trace column in the row JSON).
-BUCKET_ROW_COLUMNS = (
-    "bucket_bytes",
-    "n_buckets",
-)
-
-# The bench-row columns pipelined rows (pp > 1 in BENCH_CFG) add — the
+# The report columns of a pipelined run (pp > 1) — the
 # :func:`pipeline_schedule_report` measurement: the tick-count bubble read
 # off the hop events (exact when the capture verifies), the wall-time
-# weighted bubble, and the verification bit itself.  Same jax-free schema
-# -home discipline as BUCKET_ROW_COLUMNS; disjointness from the other two
-# vocabularies is pinned in tests/test_pipeline_schedule.py.
+# weighted bubble, and the verification bit itself.  Declared HERE, the
+# one jax-free schema home for the report vocabularies
+# (``scripts/predict_scaling.py`` joins on them); disjointness from the
+# other two is pinned in tests/test_pipeline_schedule.py.
 PIPELINE_ROW_COLUMNS = (
     "pipeline_bubble_ticks",
     "pipeline_bubble_time",
     "pipeline_schedule_verified",
 )
 
-# The bench-row columns update-plane-sharding rows add (BENCH_USHARD=1 /
-# BENCH_USHARD_REPORT=1; parallel/update_sharding.py, docs/design.md §23)
-# — the :func:`update_state_report` measurement: per-chip update-plane
+# The report columns of an update-plane-sharded run
+# (parallel/update_sharding.py, docs/design.md §23) — the
+# :func:`update_state_report` measurement: per-chip update-plane
 # bytes (optimizer state + exchanger extra, actual live-array bytes over
 # worker count), the replicated-equivalent bytes the same session would
 # hold without sharding, and their ratio (the ~N× headline).  Same
@@ -175,7 +146,7 @@ USHARD_ROW_COLUMNS = (
     "update_state_shrink",
 )
 
-# The bench-row columns compression rows add (onebit/topk/powersgd
+# The report columns of a compressed wire (onebit/topk/powersgd
 # strategies; ops/compress.py, ops/factor_pack.py, docs/design.md §24) —
 # the :func:`compress_traffic_report` estimate: local HBM bytes one
 # exchange moves through the compression pipeline, modeled at XLA-op
@@ -1025,8 +996,7 @@ def pipeline_schedule_report(events: Iterable[dict], pp: int, v: int,
 
 
 def format_schedule(occ: Dict[str, Any]) -> str:
-    """Human-readable per-lane occupancy report (the ``--schedule`` view of
-    ``scripts/profile_model.py``)."""
+    """Human-readable per-lane occupancy report."""
     lines = ["per-lane schedule occupancy "
              "(C compute · H hop · c comm · · idle):"]
     for l in occ.get("lanes", []):
@@ -1099,7 +1069,7 @@ class capture:
         if self._own_dir:
             # anonymous capture: the caller only wants the attribution, so
             # the multi-MB capture files must not accumulate under
-            # /tmp across bench rows (pass trace_dir to keep the raw
+            # /tmp across captures (pass trace_dir to keep the raw
             # capture for Perfetto)
             import shutil
             shutil.rmtree(self._cap.trace_dir, ignore_errors=True)
@@ -1134,32 +1104,6 @@ def feed_telemetry(profile: Dict[str, Any], tm=None) -> None:
              top_ops=[o["op"] for o in profile.get("top_ops", [])[:3]])
 
 
-def profile_row_fields(profile: Dict[str, Any],
-                       total_flops: Optional[float] = None,
-                       peak_flops: Optional[float] = None) -> Dict[str, Any]:
-    """The bench-row columns (:data:`TRACE_ROW_COLUMNS`, all keys always
-    present).  ``device_mfu`` is the trace-derived cross-check of the
-    ``cost_analysis`` MFU column: ``total_flops`` (per-device flops over
-    the WHOLE traced window) against one lane's compute-busy time —
-    None when flops/peak are unknown or the trace saw no compute."""
-    lanes = profile.get("compute_lanes") or profile.get("lanes") or 0
-    compute = profile.get("compute_secs") or 0.0
-    mfu = None
-    if total_flops and peak_flops and lanes and compute > 0:
-        per_lane_secs = compute / lanes
-        mfu = round(float(total_flops) / per_lane_secs / float(peak_flops), 4)
-        if not math.isfinite(mfu):
-            mfu = None
-    return {
-        "overlap_ratio": profile.get("overlap_ratio"),
-        "exposed_comm_secs": profile.get("exposed_comm_secs"),
-        "device_compute_secs": profile.get("compute_secs"),
-        "device_comm_secs": profile.get("comm_secs"),
-        "device_mfu": mfu,
-        "bubble_fraction": profile.get("bubble_fraction"),
-    }
-
-
 def update_state_report(model) -> Dict[str, Any]:
     """Per-chip update-plane memory (:data:`USHARD_ROW_COLUMNS`): what a
     chip actually holds for the optimizer state + exchanger extra, against
@@ -1175,7 +1119,7 @@ def update_state_report(model) -> Dict[str, Any]:
     state (error feedback) appears identically on both sides, so the
     shrink ratio isolates exactly the redundancy sharding removes.
     ``scripts/predict_scaling.py`` joins its analytic model against these
-    columns; bench.py folds them into sharded/control rows."""
+    columns."""
     import jax
     import numpy as np
     from ..parallel.mesh import WORKER_AXIS
@@ -1324,11 +1268,10 @@ def compress_traffic_model(strategy: str, n_elems: int, n_workers: int, *,
 
 
 def compress_traffic_report(model) -> Optional[Dict[str, Any]]:
-    """The :data:`COMPRESS_ROW_COLUMNS` bench columns for a live model —
+    """The :data:`COMPRESS_ROW_COLUMNS` columns for a live model —
     :func:`compress_traffic_model` fed from the model's actual strategy
     config and parameter count.  ``None`` when the exchange strategy has
-    no compression pipeline; bench.py folds the columns into onebit/topk/
-    powersgd rows next to the measured step time."""
+    no compression pipeline."""
     import jax
     strat = model.exchanger.strategy
     leaf_shapes = [tuple(getattr(l, "shape", ()) or ())
@@ -1348,7 +1291,7 @@ def compress_traffic_report(model) -> Optional[Dict[str, Any]]:
 
 
 def format_profile(profile: Dict[str, Any], top: int = 15) -> str:
-    """Human-readable breakdown (profile_model.py / worker verbose)."""
+    """Human-readable breakdown (worker verbose)."""
     lines = [
         f"device time: compute {profile['compute_secs']:.4f}s  "
         f"comm {profile['comm_secs']:.4f}s  "

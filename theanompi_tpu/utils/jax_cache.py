@@ -1,14 +1,12 @@
 """Where JAX's persistent compilation cache lives.
 
-Every entry point that compiles for a chip (``chip_smoke.py``, ``bench.py``,
-the worker and launcher CLIs) calls :func:`configure` once before its first
-compile.  The directory is part of the cache key's world: a path that moves
-between runs never hits, so it is never derived from ``tempfile``, a pid or
-the clock.
+Every entry point that compiles for a chip (``chip_smoke.py``,
+``benchmarks/run.py``, the worker and launcher CLIs) calls
+:func:`configure` once before its first compile.  The directory is part
+of the cache key's world: a path that moves between runs never hits, so it
+is never derived from ``tempfile``, a pid or the clock.
 
-This is JAX's own cache.  The repo's AOT executable store
-(``utils/compile_cache.py``, ``THEANOMPI_COMPILE_CACHE``) is a separate,
-opt-in mechanism and is not touched here.  A process pinned to the CPU
+This is JAX's own cache, and the only one.  A process pinned to the CPU
 (the test suite and the workers it spawns, rehearsals) is left alone:
 serializing the 8-device CPU executables crashed pytest
 (``tests/conftest.py``).

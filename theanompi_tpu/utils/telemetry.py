@@ -612,8 +612,8 @@ def init(config: Optional[dict] = None):
     Enablement: ``telemetry=false`` force-disables; otherwise a
     ``record_dir`` enables the streaming registry (events land next to the
     recorder's inforec files), and ``telemetry=true`` without a dir
-    enables an in-memory registry (metrics + flight ring, no stream —
-    what bench.py uses).  A previous instance is closed first, so repeated
+    enables an in-memory registry (metrics + flight ring, no stream).
+    A previous instance is closed first, so repeated
     in-process sessions don't leak file handles or cross-write streams."""
     global _ACTIVE
     config = config or {}

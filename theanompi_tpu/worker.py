@@ -54,11 +54,10 @@ class Worker(MeshProcess):
         # the compile recorder bucket: building the jit wrappers and
         # placing the state, NOT the XLA compile — the jit is lazy, so that
         # lands in the first train_iter (ring spans compile.place /
-        # compile.xla; an AOT compile_cache hit or miss is logged below)
+        # compile.xla)
         self.recorder.start()
         model.compile_iter_fns(self.exchanger)
         self.recorder.end("compile")
-        self._log_compile_cache(model)
         if config.get("scale_lr", True) and self.size > 1:
             model.scale_lr(self.size)
 
@@ -363,26 +362,6 @@ class Worker(MeshProcess):
             print(f"training finished in {time.time() - t0:.1f}s "
                   f"({epochs - start_epoch} epochs)", flush=True)
         return self.recorder
-
-
-    def _log_compile_cache(self, model) -> None:
-        """Startup line for the AOT executable cache (utils/compile_cache):
-        per-program hit/miss + wall time, and the process counters — the
-        at-a-glance evidence that a supervised restart or checkpoint
-        resume deserialized instead of recompiling."""
-        if not self.verbose:
-            return
-        cache = getattr(model, "compile_cache", None)
-        info = getattr(model, "compile_info", None) or {}
-        if cache is None or not cache.enabled:
-            return
-        parts = [f"{k}: {v['cache']}"
-                 + (f" ({v['compile_secs']:.1f}s)"
-                    if v.get("compile_secs") is not None else "")
-                 for k, v in info.items()
-                 if isinstance(v, dict) and "cache" in v]
-        print(f"compile cache [{cache.describe()}] " + " | ".join(parts),
-              flush=True)
 
 
 class BSP_Worker(Worker):
