@@ -332,3 +332,41 @@ def test_sequential_reorders_only_relu_then_max_pool(front, expect,
     fed_by = pool_operand_makers(jax.make_jaxpr(
         lambda p, x: seq.apply(p, x)[0])(params, x).jaxpr)
     assert fed_by == ([pool_reads] if pool_reads else [])
+
+
+# -- FC's weight gradient from gathered operands (PERF.md §6, PR 32) ---------
+
+@pytest.mark.parametrize("cd,tol", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-2)],
+                         ids=["float32", "bfloat16"])
+def test_dot_gathered_sums_the_weight_gradient_over_workers(cd, tol):
+    """On a 4-way mesh the product's forward and ``dx`` are the local
+    ones, ``dw`` is the full-batch ``x^T . dy`` in float32, the same bits
+    on every worker."""
+    from jax.sharding import PartitionSpec as P
+    from theanompi_tpu.jax_compat import shard_map, vary
+    from theanompi_tpu.parallel.mesh import worker_mesh
+    n, rows, n_in, n_out = 4, 6, 16, 8
+    r = np.random.RandomState(0)
+    x = jnp.asarray(r.randn(n * rows, n_in), jnp.float32)
+    w = jnp.asarray(r.randn(n_in, n_out), jnp.float32)
+    dy = jnp.asarray(r.randn(n * rows, n_out), jnp.float32)
+
+    def local(x, w, dy):
+        w = vary(w, "workers")
+        y, vjp = jax.vjp(
+            lambda x, w: L._dot_gathered(x.astype(cd), w, "workers"), x, w)
+        dx, dw = vjp(dy.astype(cd))
+        return y, dx, dw[None]
+
+    y, dx, dw = jax.jit(shard_map(
+        local, mesh=worker_mesh(n), in_specs=(P("workers"), P(), P("workers")),
+        out_specs=(P("workers"), P("workers"), P("workers"))))(x, w, dy)
+    assert dw.dtype == jnp.float32 and y.dtype == cd
+    scale = lambda a: tol * float(np.max(np.abs(a)))
+    np.testing.assert_allclose(np.asarray(y, np.float32), x @ w,
+                               atol=scale(x @ w))
+    np.testing.assert_allclose(np.asarray(dx, np.float32), dy @ w.T,
+                               atol=scale(dy @ w.T))
+    np.testing.assert_allclose(dw[0], x.T @ dy, atol=scale(x.T @ dy))
+    for k in range(1, n):
+        np.testing.assert_array_equal(dw[k], dw[0])

@@ -284,3 +284,51 @@ def test_unpack_weighted_sum_oracle():
 def test_unknown_strategy_raises():
     with pytest.raises(ValueError, match="unknown exchange strategy"):
         get_strategy("definitely-not-a-strategy")
+
+
+# -- a wide FC's gradient as gathered operands: the rule (PERF.md §6, PR 32) --
+
+@pytest.mark.parametrize("who,n,rows,n_in,n_out,engages,reduced,gathered", [
+    # VGG-16 at b384 a chip in bf16, four chips: the benchmark's cell
+    ("vgg16-fc6", 4, 384, 25088, 4096, True, 616_562_688, 67_239_936),
+    ("vgg16-fc7", 4, 384, 4096, 4096, True, 100_663_296, 18_874_368),
+    ("vgg16-fc8", 4, 384, 4096, 1000, False, 24_576_000, 11_741_184),
+    # one chip moves nothing; thirty-two gather too many rows
+    ("vgg16-fc6-n1", 1, 384, 25088, 4096, False, 0, 0),
+    ("vgg16-fc6-n32", 32, 384, 25088, 4096, False, 796_393_472,
+     694_812_672),
+    # AlexNet at b1024 on four: 82 MB against 226; ResNet-50's head is
+    # under the floor whatever its rows
+    ("alexnet-fc6", 4, 1024, 9216, 4096, False, 226_492_416, 81_788_928),
+    ("resnet50-head", 4, 8, 2048, 1000, False, 12_288_000, 146_304),
+])
+def test_gather_rule_table(who, n, rows, n_in, n_out, engages, reduced,
+                           gathered):
+    assert strategies.fc_wire_bytes(n, rows, 1, n_in, n_out, 2) \
+        == (reduced, gathered)
+    assert strategies.gather_engages(n, rows, 1, n_in, n_out, 2) is engages
+
+
+def test_gather_rule_counts_microbatches_and_the_floor():
+    # fc7 engages at one microbatch (18.9 MB against 100.7) and not at two
+    assert strategies.gather_engages(4, 384, 1, 4096, 4096, 2)
+    assert not strategies.gather_engages(4, 384, 2, 4096, 4096, 2)
+    # a 2 MiB leaf is an all-reduce of latency, whatever its rows
+    assert not strategies.gather_engages(4, 1, 1, 1024, 512, 2)
+    assert strategies.gather_engages(4, 1, 1, 1024, 512, 2, min_bytes=0)
+
+
+def test_allreduce_scales_summed_leaves_without_a_collective(mesh8):
+    tree = _mk_tree(3)
+    out, _ = _run_strategy(mesh8, get_strategy("allreduce"), tree)
+    held = functools.partial(get_strategy("allreduce"),
+                             summed={"['w']": ()})
+    out_held, _ = _run_strategy(
+        mesh8, held, tree,
+        state_boxed=jax.device_put(np.zeros((N, 0), np.float32),
+                                   worker_local_sharding(mesh8)))
+    # the other leaf is the mean still; the held one is its own 1/N
+    np.testing.assert_array_equal(np.asarray(out_held["b"]),
+                                  np.asarray(out["b"]))
+    np.testing.assert_allclose(np.asarray(out_held["w"]), tree["w"] / N,
+                               rtol=1e-6)

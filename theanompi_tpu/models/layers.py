@@ -293,8 +293,83 @@ class FC(_Affine):
 
     def pre_activation(self, params, x):
         cd = self.compute_dtype
-        y = jnp.dot(x.astype(cd), params["w"].astype(cd))
+        g = _gathered
+        if g is not None and x.ndim == 2 and g.take(
+                params["w"], x.shape[0], jnp.dtype(cd).itemsize):
+            y = _dot_gathered(x.astype(cd), params["w"], g.axis)
+        else:
+            y = jnp.dot(x.astype(cd), params["w"].astype(cd))
         return y + params["b"].astype(cd)
+
+
+# -- a wide FC's weight gradient from gathered operands ----------------------
+# The rule and its arithmetic: parallel/strategies.py ``gather_engages``.
+
+class GatheredGrads:
+    """One BSP step's record of the ``FC`` weights whose gradient is formed
+    from all-gathered operands; ``BSP_Exchanger.gathered_grads`` makes it,
+    ``steps.one_step`` traces the loss through :meth:`loss`, and ``taken``
+    then names the leaves that reach ``step_update`` already summed over
+    the workers.  A weight is known by being the very leaf of the
+    parameter tree the loss was handed: a model that copies or casts its
+    tree first keeps the all-reduce.  (A taken leaf must have no reader
+    beside its ``FC``: a tied copy's local gradient would go unsummed.)"""
+
+    def __init__(self, axis: str, engages):
+        self.axis = axis
+        self.engages = engages          # (rows, n_in, n_out, itemsize) -> bool
+        self.taken: Dict[str, tuple] = {}
+        self._paths: Dict[int, str] = {}
+
+    def loss(self, loss_and_metrics):
+        def watched(params, *args):
+            global _gathered
+            self._paths = {
+                id(leaf): jax.tree_util.keystr(path) for path, leaf
+                in jax.tree_util.tree_flatten_with_path(params)[0]}
+            before, _gathered = _gathered, self
+            try:
+                return loss_and_metrics(params, *args)
+            finally:
+                _gathered, self._paths = before, {}
+        return watched
+
+    def take(self, w, rows: int, itemsize: int) -> bool:
+        path = self._paths.get(id(w))
+        if path is None or w.ndim != 2 or w.dtype != jnp.float32 \
+                or not self.engages(rows, *w.shape, itemsize):
+            return False
+        self.taken[path] = (rows,) + tuple(w.shape) + (itemsize,)
+        return True
+
+
+_gathered: Optional[GatheredGrads] = None   # open while its loss is traced
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dot_gathered(x, w, axis):
+    """``x . w`` in ``x``'s dtype whose backward returns ``w``'s gradient
+    SUMMED over ``axis``: both operands all-gathered (in the dtype they
+    have as matmul operands anyway), one full-batch product accumulated in
+    ``w``'s float32.  Every chip multiplies the same operands in the same
+    order, so replicas stay bit-identical; ``dx`` stays local."""
+    return jnp.dot(x, w.astype(x.dtype))
+
+
+def _dot_gathered_fwd(x, w, axis):
+    return _dot_gathered(x, w, axis), (x, w)
+
+
+def _dot_gathered_bwd(axis, res, dy):
+    x, w = res
+    dx = jnp.dot(dy, w.astype(dy.dtype).T)
+    rows = [jax.lax.all_gather(a, axis, tiled=True) for a in (x, dy)]
+    dw = jax.lax.dot_general(*rows, (((0,), (0,)), ((), ())),
+                             preferred_element_type=w.dtype)
+    return dx, dw
+
+
+_dot_gathered.defvjp(_dot_gathered_fwd, _dot_gathered_bwd)
 
 
 class Pool(Layer):

@@ -55,8 +55,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import buckets, steps, topology, update_sharding
+from . import buckets, steps, strategies, topology, update_sharding
 from ..jax_compat import shard_map
+from ..models import layers
 from ..utils import telemetry, tracing
 from .mesh import WORKER_AXIS
 from .strategies import Strategy, get_strategy
@@ -401,8 +402,14 @@ class Exchanger:
         scale = jnp.minimum(1.0, clip / jnp.maximum(norm, 1e-12))
         return jax.tree.map(lambda g: (g * scale).astype(g.dtype), grads)
 
+    def gathered_grads(self):
+        """The step's :class:`layers.GatheredGrads`, or None where every
+        gradient leaf takes the rule's ordinary wire (every rule but BSP
+        on the plain all-reduce)."""
+        return None
+
     def step_update(self, params, opt_state, grads, extra, lr, *, axis, size,
-                    count):
+                    count, summed=None):
         """Default: purely local optimizer step (async rules train locally
         between exchanges)."""
         opt = self.model.opt
@@ -568,6 +575,7 @@ class BSP_Exchanger(Exchanger):
 
     def prepare(self, mesh: Mesh, model) -> None:
         super().prepare(mesh, model)
+        self._wire_counted = False
         self._build_exchange_fn()
 
     def _extra_full_template(self) -> Dict[str, Any]:
@@ -598,7 +606,7 @@ class BSP_Exchanger(Exchanger):
             return {"strat": jnp.tile(st, n)}
         return {}
 
-    def _strat_call(self, tree, strat_state, *, axis, size):
+    def _strat_call(self, tree, strat_state, *, axis, size, summed=None):
         """Invoke the exchange strategy, normalizing the model-parallel
         leaf-wise state layout: under tp/pp a leaf-wise strategy's arrays
         carry a leading ``[prod(group)]`` axis (see extra_state_template)
@@ -614,18 +622,64 @@ class BSP_Exchanger(Exchanger):
               and self.model.param_specs() is not None)
         if lw:
             strat_state = jax.tree.map(lambda x: x[0], strat_state)
+        # only the exact all-reduce is ever handed summed leaves
+        kw = {"summed": summed} if summed else {}
         tree, strat_state = self.strategy(tree, strat_state,
-                                          axis=axis, size=size)
+                                          axis=axis, size=size, **kw)
         if lw:
             strat_state = jax.tree.map(lambda x: x[None], strat_state)
         return tree, strat_state
 
+    # the floor of strategies.gather_engages; tests lower it on an
+    # instance to reach the gathered path at toy shapes, no key does
+    gather_min_bytes = strategies.GATHER_MIN_BYTES
+
+    def gathered_grads(self):
+        """A wide ``FC`` weight's gradient travels as its all-gathered
+        operands (``strategies.gather_engages``, docs/design.md §3) where
+        the mean gradient of a replicated float32 leaf is all this rule
+        moves: gradient mode on the exact ``allreduce`` wire, more than
+        one worker, plain data parallelism with replicated state."""
+        cfg = self.config
+        if not (self.size > 1 and self.mode == "grads"
+                and self.strategy.name == "allreduce"
+                and self.model.param_specs() is None
+                and all(self.mesh.shape[a] == 1 for a in self._group_axes())
+                and not any(cfg.get(k, False) for k in
+                            ("fsdp", "zero_opt", "update_sharding"))):
+            return None
+        n, floor = self.size, self.gather_min_bytes
+        n_subb = getattr(self.model, "n_subb", 1)
+        return layers.GatheredGrads(
+            WORKER_AXIS, lambda rows, n_in, n_out, itemsize:
+            strategies.gather_engages(n, rows, n_subb, n_in, n_out,
+                                      itemsize, floor))
+
+    def _count_wire(self, grads, summed) -> None:
+        """Exchange bytes a step, once per prepared step however often it
+        is traced: the payload the all-reduce sums and the gathered
+        operands' full-batch size (a chip receives (n-1)/n of each)."""
+        if self._wire_counted or self.strategy.name not in (
+                "allreduce", "allreduce16"):
+            return
+        self._wire_counted = True
+        n_subb = getattr(self.model, "n_subb", 1)
+        telemetry.count("comm.gathered_leaves", len(summed))
+        telemetry.count("comm.gathered_bytes", sum(
+            n_subb * self.size * rows * (n_in + n_out) * itemsize
+            for rows, n_in, n_out, itemsize in summed.values()))
+        telemetry.count("comm.allreduce_bytes", sum(
+            g.size * g.dtype.itemsize for p, g
+            in jax.tree_util.tree_flatten_with_path(grads)[0]
+            if jax.tree_util.keystr(p) not in summed))
+
     def step_update(self, params, opt_state, grads, extra, lr, *, axis, size,
-                    count):
+                    count, summed=None):
         if self.mode == "grads":
             strat_state = extra.get("strat", ())
-            grads, strat_state = self._strat_call(grads, strat_state,
-                                                  axis=axis, size=size)
+            self._count_wire(grads, summed or {})
+            grads, strat_state = self._strat_call(
+                grads, strat_state, axis=axis, size=size, summed=summed)
             if "strat" in extra:
                 extra = dict(extra, strat=strat_state)
             grads = self._restore_replication(grads)

@@ -361,8 +361,14 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
         ridx = lax.axis_index(axis)
         local_rng = jax.random.fold_in(jax.random.fold_in(rng, ridx), count)
 
+        # a wide FC's weight gradient may come back already summed over
+        # the workers, formed from gathered operands (layers.GatheredGrads)
+        gathered = exchanger.gathered_grads()
+        loss = model.loss_and_metrics if gathered is None \
+            else gathered.loss(model.loss_and_metrics)
         cost, err, grads, new_bn = _accumulate_grads(
-            model.loss_and_metrics, params, bn_state, batch, local_rng, n_subb)
+            loss, params, bn_state, batch, local_rng, n_subb)
+        summed = gathered.taken if gathered is not None else None
 
         # Model hooks (traced, optional — models outside ModelBase need not
         # define them): grad transform before the exchange, update gating /
@@ -371,7 +377,8 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
         if pg is not None:
             grads = pg(grads, count)
         new_params, new_opt, extra = exchanger.step_update(
-            params, opt_state, grads, extra, lr, axis=axis, size=n, count=count)
+            params, opt_state, grads, extra, lr, axis=axis, size=n,
+            count=count, summed=summed)
         pu = getattr(model, "postprocess_update", None)
         if pu is not None:
             new_params, new_opt = pu(params, opt_state, new_params, new_opt,
@@ -379,6 +386,12 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
         # numerics ingredients (§25): the already-live old/new params,
         # grads and extra — handed back for the cadence-gated sample at
         # the per_worker level.  Pure reads; None keeps this path inert.
+        if nx is not None and summed:
+            # the plane reads one worker's share: a leaf that came back
+            # summed over the workers is handed over as its mean
+            grads = jax.tree_util.tree_map_with_path(
+                lambda p, g: g * (1.0 / n)
+                if jax.tree_util.keystr(p) in summed else g, grads)
         ing = None if nx is None else (params, new_params, grads, extra)
         params, opt_state = new_params, new_opt
         new_bn = _revary_bn(exchanger.sync_bn(new_bn, axis=axis, size=n),

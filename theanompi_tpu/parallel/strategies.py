@@ -120,6 +120,39 @@ class NoComm(Strategy):
         return jax.tree.map(lambda g: g * inv, tree), state
 
 
+# A wide FC layer has many parameters and little arithmetic (Krizhevsky,
+# arXiv:1404.5997): its weight gradient is ``x^T . dy`` with a batch's rows,
+# so the wire can carry the two operands instead of the product.  The
+# all-reduce of a float32 ``in x out`` leaf moves ``2(n-1)/n . in.out.4``
+# bytes a chip; all-gathering the ``b`` rows a chip holds of both operands,
+# ``m`` microbatches a step, moves ``m(n-1) . b(in+out) . s``, and every
+# chip then forms the full-batch product itself: ``n-1`` redundant
+# weight-gradient matmuls.  ``BSP_Exchanger.gathered_grads`` applies the
+# rule; ``models/layers.py`` ``FC`` is the other half of the mechanism.
+# Below 8 MiB an all-reduce costs latency, not bytes.
+GATHER_MIN_BYTES = 8 << 20
+# The gathers may move at most this share of the all-reduce's bytes: the
+# margin pays for the redundant product.  At n=4, b=384, bf16 VGG-16's fc6
+# reads 0.109 and fc7 0.188 (engage), its 4096x1000 head 0.478 (stays).
+GATHER_MAX_SHARE = 0.25
+
+
+def fc_wire_bytes(n: int, rows: int, n_subb: int, n_in: int, n_out: int,
+                  itemsize: int) -> Tuple[int, int]:
+    """Bytes one chip moves a step for a float32 ``n_in x n_out`` weight:
+    ``(all-reduced, gathered)``."""
+    return (2 * (n - 1) * n_in * n_out * 4 // n,
+            n_subb * (n - 1) * rows * (n_in + n_out) * itemsize)
+
+
+def gather_engages(n: int, rows: int, n_subb: int, n_in: int, n_out: int,
+                   itemsize: int, min_bytes: int = GATHER_MIN_BYTES) -> bool:
+    """The rule: does an FC weight's gradient travel as gathered operands?"""
+    reduced, gathered = fc_wire_bytes(n, rows, n_subb, n_in, n_out, itemsize)
+    return (n > 1 and n_in * n_out * 4 >= min_bytes
+            and gathered <= GATHER_MAX_SHARE * reduced)
+
+
 class AllReduce(Strategy):
     """``lax.psum``-based mean — XLA emits the tuned ICI allreduce.
 
@@ -127,14 +160,27 @@ class AllReduce(Strategy):
     (and ``nccl16`` with ``wire_dtype=bfloat16``): on TPU there is no
     host-staged vs device-aware distinction to preserve, the compiled
     collective IS the device-aware path.
+
+    ``summed``: key paths (``jax.tree_util.keystr``) of leaves that arrive
+    already summed over the workers (an ``FC`` weight on the gathered
+    path): scaled to the mean, no collective.
     """
 
     def __init__(self, wire_dtype=None):
         self.wire_dtype = wire_dtype
         self.name = "allreduce" if wire_dtype is None else "allreduce16"
 
-    def __call__(self, tree, state, *, axis: str, size: int):
+    def __call__(self, tree, state, *, axis: str, size: int, summed=()):
         inv = 1.0 / size
+        if summed:
+            flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+            held = [jax.tree_util.keystr(p) in summed for p, _ in flat]
+            rest, state = self([g for (_, g), h in zip(flat, held) if not h],
+                               state, axis=axis, size=size)
+            rest = iter(rest)
+            return treedef.unflatten(
+                [g * inv if h else next(rest)
+                 for (_, g), h in zip(flat, held)]), state
         wd = self.wire_dtype
         if self.bucket_bytes > 0:
             # per-bucket async psum pairs: all starts issued before the
@@ -146,9 +192,9 @@ class AllReduce(Strategy):
             vecs = buckets.pack(tree, plan)
             tickets = [psum_start(v if wd is None else v.astype(wd), axis)
                        for v in vecs]
-            summed = [psum_done(t) for t in tickets]
+            sums = [psum_done(t) for t in tickets]
             reduced = [(s if wd is None else s.astype(v.dtype)) * inv
-                       for s, v in zip(summed, vecs)]
+                       for s, v in zip(sums, vecs)]
             return buckets.unpack(reduced, tree, plan), state
         if wd is None:
             out = jax.tree.map(lambda g: lax.psum(g, axis) * inv, tree)

@@ -91,7 +91,10 @@ SPANS = (
     "compile.xla", "compile.cache_load",           # jax.monitoring
 )
 COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
-          "pool_before_relu")
+          "pool_before_relu",
+          # BSP's wire a step, written when the step is first traced
+          "comm.allreduce_bytes", "comm.gathered_bytes",
+          "comm.gathered_leaves")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
