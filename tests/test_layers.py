@@ -370,3 +370,99 @@ def test_dot_gathered_sums_the_weight_gradient_over_workers(cd, tol):
     np.testing.assert_allclose(dw[0], x.T @ dy, atol=scale(x.T @ dy))
     for k in range(1, n):
         np.testing.assert_array_equal(dw[k], dw[0])
+
+
+# -- the looped model's layer kinds, each against a three-line form ------------
+
+def test_rmsnorm_is_x_over_root_mean_square_times_scale():
+    x = jax.random.normal(KEY, (2, 5, 16)) * 3.0
+    norm = L.RMSNorm(16, eps=1e-6, name="n")
+    p = {"scale": jnp.linspace(0.5, 2.0, 16)}
+    want = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) \
+        * p["scale"]
+    np.testing.assert_allclose(norm.apply(p, x), want, rtol=1e-6)
+    assert jax.tree.map(jnp.shape, norm.init(KEY)) == {"scale": (16,)}
+    # statistics in float32 whatever comes in; the result in the input's type
+    half = norm.apply(p, x.astype(jnp.bfloat16))
+    assert half.dtype == jnp.bfloat16
+    np.testing.assert_allclose(half.astype(F32), want, rtol=2e-2)
+
+
+def test_rotary_turns_half_split_pairs_by_position():
+    x = jax.random.normal(KEY, (2, 3, 6, 8))            # [B, H, T, hd]
+    ang = jnp.arange(6.0)[:, None] * 100.0 ** (-jnp.arange(0, 8, 2) / 8)
+    a, b = x[..., :4], x[..., 4:]
+    want = jnp.concatenate([a * jnp.cos(ang) - b * jnp.sin(ang),
+                            b * jnp.cos(ang) + a * jnp.sin(ang)], -1)
+    got = L.rotary(x, theta=100.0)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # a turn: lengths stay, position 0 stays, and q.k reads the distance
+    np.testing.assert_allclose(jnp.linalg.norm(got, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    np.testing.assert_array_equal(got[:, :, 0], x[:, :, 0])
+    same = jnp.broadcast_to(x[:, :, :1], x.shape)
+    r = L.rotary(same, theta=100.0)
+    np.testing.assert_allclose(jnp.sum(r[:, :, 1] * r[:, :, 3], -1),
+                               jnp.sum(r[:, :, 2] * r[:, :, 4], -1),
+                               rtol=1e-4)
+
+
+def test_gated_mlp_is_down_of_silu_gate_times_up():
+    mlp = L.GatedMLP(8, 24, compute_dtype=F32, name="m")
+    p = mlp.init(KEY)
+    assert jax.tree.map(jnp.shape, p) == {"wg": (8, 24), "wu": (8, 24),
+                                          "wd": (24, 8)}
+    x = jax.random.normal(KEY, (2, 5, 8))
+    g = x @ p["wg"]
+    want = (g * jax.nn.sigmoid(g) * (x @ p["wu"])) @ p["wd"]
+    np.testing.assert_allclose(mlp.apply(p, x), want, rtol=1e-5, atol=1e-8)
+
+
+def test_rotary_attention_is_attention_of_the_turned_q_and_k():
+    attn = L.RotaryAttention(16, 2, theta=50.0, compute_dtype=F32, name="a")
+    p = attn.init(KEY)
+    x = jax.random.normal(KEY, (2, 6, 16))
+    heads = lambda w: (x @ w).reshape(2, 6, 2, 8).transpose(0, 2, 1, 3)  # noqa: E731,E501
+    q, k, v = L.rotary(heads(p["wq"]), 50.0), L.rotary(heads(p["wk"]), 50.0), \
+        heads(p["wv"])
+    s = jnp.where(jnp.tril(jnp.ones((6, 6), bool)),
+                  q @ k.transpose(0, 1, 3, 2) / np.sqrt(8), -jnp.inf)
+    want = (jax.nn.softmax(s, -1) @ v).transpose(0, 2, 1, 3).reshape(
+        2, 6, 16) @ p["wo"]
+    np.testing.assert_allclose(attn.apply(p, x), want, rtol=1e-4, atol=1e-6)
+    # the scopes the benchmark's readers join on
+    text = jax.jit(attn.apply).lower(p, x).as_text(debug_info=True)
+    assert "a/attn_core" in text
+
+
+# -- the shim that lets jax's flash kernel into a step (jax_compat) -------------
+
+def test_the_flash_shim_marks_the_steps_axis_alone_and_fails_by_name(
+        monkeypatch):
+    """The library module's out_shapes vary over the axis the caller names,
+    where it is manual, and over no other; a library that no longer builds
+    them through its global ``jax`` is refused when the shim is put in."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    from jax.sharding import Mesh, PartitionSpec as P
+    from theanompi_tpu import jax_compat
+    view = jax_compat._VaryingOutShapes()
+    view.axes.add("workers")
+    assert view.numpy is jax.numpy                  # jax, for the rest
+    assert not view.ShapeDtypeStruct((2,), F32).vma  # no manual axis here
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("workers", "model"))
+    seen = []
+
+    def local(x):
+        seen.append(view.ShapeDtypeStruct((2,), F32).vma)
+        return x
+
+    jax.jit(jax_compat.shard_map(local, mesh=mesh, in_specs=P("workers",
+                                                             "model"),
+                                 out_specs=P("workers", "model"))
+            ).lower(jnp.zeros((2, 2)))
+    assert seen == [frozenset({"workers"})]
+    monkeypatch.setattr(fa, "jax", object())        # a jax of another build
+    q = jnp.zeros((1, 1, 128, 128), jnp.bfloat16)
+    with pytest.raises(AssertionError, match="out_shapes"):
+        jax_compat.flash_attention(q, q, q, axis_name="workers")

@@ -1,0 +1,65 @@
+"""Compile-only guards: the kernels of the looped cell's main path at the
+published widths, compiled for a described v5e chip (nothing runs; no chip
+is needed).  The topology is described inside a fixture, never at import:
+only one process may hold the TPU's library, and every xdist worker imports
+this file.  Keep such tests in this one file."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from theanompi_tpu.jax_compat import shard_map
+from theanompi_tpu.models import layers as L
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Skipped only where the TPU's compiler is not installed at all: where
+    it is, on the chip's machine as in the sandbox, a topology that cannot
+    be described fails these guards, it does not silence them."""
+    import importlib.util
+
+    from jax.experimental import topologies
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no libtpu installed: nothing can compile for a v5e")
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    return Mesh(np.array(topo.devices[:1]), ("workers",))
+
+
+def test_flash_attention_compiles_inside_the_steps_shard_map(one_chip_mesh):
+    """``attn_impl='flash'`` forward and backward at 16 heads of 128 over
+    two sequences of 4,096, inside a ``shard_map`` that checks vma as every
+    step of this package does: jax's kernel builds its out_shapes without
+    ``vma`` and was refused there until ``jax_compat.flash_attention``."""
+    mesh = one_chip_mesh
+    attn = L.RotaryAttention(2048, 16, theta=1e6, attn_impl="flash",
+                             name="attn")
+    params = jax.eval_shape(attn.init, jax.random.key(0))
+
+    def per_worker(p, x):
+        def loss(p, x):
+            return jnp.sum(attn.apply(p, x[0]).astype(jnp.float32))
+        return jax.tree.map(lambda g: g[None], jax.grad(loss)(
+            jax.tree.map(lambda a: a[0], p), x))
+
+    spec = P("workers")
+    sh = NamedSharding(mesh, spec)
+    boxed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=sh), params)
+    x = jax.ShapeDtypeStruct((1, 2, 4096, 2048), jnp.float32, sharding=sh)
+    step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec))
+    text = step.lower(boxed, x).compile().as_text()
+    # the forward kernel and both backward ones, at the tile asked for
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    for kernel in ("flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
+        assert kernel in text, kernel
+    assert "block_q_512" in text
+    assert "attn_core" in text                  # the scope reaches the HLO

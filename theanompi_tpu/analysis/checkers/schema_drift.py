@@ -137,8 +137,9 @@ def span_vocabulary_errors(telemetry, root: Optional[str] = None,
     package must be in ``telemetry.SPANS`` / ``telemetry.COUNTS``, every
     listed name must have a site (``compile.*`` spans are written by the
     ``jax.monitoring`` listener, named in ``COMPILE_EVENTS``), no span is
-    called like a recorder phase, and a dotted name's head is a phase or
-    ``input`` (the loader's threads).  ``sources`` ({path: text}) stands
+    called like a recorder phase, and a dotted name's head is a phase,
+    ``input`` (the loader's threads) or ``model`` (what a model file counts
+    of its own step).  ``sources`` ({path: text}) stands
     in for the package's files in tests."""
     import ast as _ast
     errors: List[tuple] = []
@@ -148,12 +149,13 @@ def span_vocabulary_errors(telemetry, root: Optional[str] = None,
         errors.append((TELEMETRY_PATH,
                        f"telemetry.SPANS repeats recorder phases "
                        f"{sorted(clash)}: Recorder.end writes those rows"))
-    heads = set(telemetry.PHASES) | {"input"}
+    heads = set(telemetry.PHASES) | {"input", "model"}
     for name in sorted(declared["span"] | declared["count"]):
         if "." in name and name.split(".", 1)[0] not in heads:
             errors.append((TELEMETRY_PATH,
                            f"span/counter {name!r}: the part before the "
-                           f"dot is neither a phase nor 'input'"))
+                           f"dot is neither a phase nor 'input' nor "
+                           f"'model'"))
     given = sources is not None
     if not given:
         if root is None:

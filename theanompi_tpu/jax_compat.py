@@ -31,6 +31,7 @@ collective the day a real async surface binds.
 
 from __future__ import annotations
 
+import inspect
 from typing import Any, NamedTuple
 
 import jax
@@ -53,6 +54,46 @@ def vary(x, axis_name: str):
     if axis_name in jax.typeof(x).vma:
         return x
     return lax.pcast(x, (axis_name,), to="varying")
+
+
+def flash_attention(q, k, v, *, axis_name: str, **kw):
+    """jax's Pallas TPU flash-attention kernel, callable inside a
+    ``shard_map`` over ``axis_name`` that checks vma (every step of this
+    package).  The library builds its kernels' ``out_shape``s without
+    ``vma``, which ``pallas_call`` refuses while the check is on, in the
+    forward call and in the two backward ones that are traced long after
+    this function has returned; so the library module's view of ``jax`` is
+    replaced, once and for the process, by one whose ``ShapeDtypeStruct``
+    marks an output varying over ``axis_name`` wherever that axis is manual.
+    A jax that no longer builds them that way fails here, by name."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    view = fa.jax
+    if not isinstance(view, _VaryingOutShapes):
+        assert view is jax and \
+            "jax.ShapeDtypeStruct(" in inspect.getsource(fa), (
+                "jax's flash_attention module no longer builds its "
+                "out_shapes through its global jax.ShapeDtypeStruct: "
+                "jax_compat.flash_attention has nothing to give vma to")
+        view = fa.jax = _VaryingOutShapes()
+    view.axes.add(axis_name)
+    return fa.flash_attention(q, k, v, **kw)
+
+
+class _VaryingOutShapes:
+    """``jax``, but for ``ShapeDtypeStruct``."""
+
+    def __init__(self):
+        self.axes = set()       # the axes a caller's step is mapped over
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def ShapeDtypeStruct(self, shape, dtype, **kw):
+        manual = jax.sharding.get_abstract_mesh().manual_axes
+        varying = frozenset(a for a in manual if a in self.axes)
+        if varying:
+            kw.setdefault("vma", varying)
+        return jax.ShapeDtypeStruct(shape, dtype, **kw)
 
 
 # -- async collective start/done ---------------------------------------------

@@ -22,6 +22,7 @@ Design departures from the reference, all deliberate and TPU-first:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -551,6 +552,10 @@ class Embedding(Layer):
         return params["w"].astype(self.compute_dtype)[x]
 
 
+FLASH_BLOCK = 512       # the flash kernel's tiles, all of them (the chip
+                        # has run no other size: PERF.md section 5)
+
+
 class MultiHeadAttention(Layer):
     """Causal multi-head self-attention (transformer zoo).
 
@@ -580,10 +585,20 @@ class MultiHeadAttention(Layer):
         """[B, H, T, hd] → [B, H, T, hd] softmax attention."""
         if self.attn_impl == "flash":
             from jax.experimental.pallas.ops.tpu.flash_attention import \
-                flash_attention
+                BlockSizes
+            from ..jax_compat import flash_attention
+            from ..parallel.mesh import WORKER_AXIS
             hd = q.shape[-1]
-            return flash_attention(q, k, v, causal=self.causal,
-                                   sm_scale=1.0 / (hd ** 0.5))
+            blk = min(FLASH_BLOCK, q.shape[2])
+            sizes = BlockSizes(**{f.name: 1 if f.name == "block_b" else blk
+                                  for f in dataclasses.fields(BlockSizes)})
+            # the kernel's tiles hold 16-bit operands at the narrowest
+            dt = q.dtype if q.dtype.itemsize >= 2 else jnp.bfloat16
+            return flash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
+                                   axis_name=WORKER_AXIS,
+                                   causal=self.causal, block_sizes=sizes,
+                                   sm_scale=1.0 / (hd ** 0.5)
+                                   ).astype(q.dtype)
         from ..ops.ring_attention import attention_reference
         return attention_reference(q, k, v, causal=self.causal)
 
@@ -653,6 +668,90 @@ class MultiHeadAttention(Layer):
         return y, (k_cache, v_cache)
 
 
+class RMSNorm(Layer):
+    """Root-mean-square normalisation over the trailing dim (Zhang and
+    Sennrich 2019): ``x / sqrt(mean(x^2) + eps) * scale``, no mean taken
+    off and no bias.  Statistics in float32."""
+
+    def __init__(self, dim: int, eps: float = 1e-6, name: str = "rms"):
+        self.dim, self.eps = dim, eps
+        self.name = name
+
+    def init(self, key):
+        return {"scale": jnp.ones((self.dim,))}
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        with jax.named_scope(self.name):
+            x32 = x.astype(jnp.float32)
+            ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+            y = x32 * jax.lax.rsqrt(ms + self.eps) * params["scale"]
+            return y.astype(x.dtype)
+
+
+def rotary(x, theta: float = 10000.0):
+    """Rotary position embedding (Su et al. 2021) of ``[..., T, hd]`` in
+    the half-split form: at position ``t`` the pair ``(x[i], x[i + hd/2])``
+    turns by ``t * theta ** (-2i / hd)``.  The turn is made in float32."""
+    t, hd = x.shape[-2], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None]  # [T, hd/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class RotaryAttention(MultiHeadAttention):
+    """:class:`MultiHeadAttention` with rotary positions on q and k and no
+    position table.  Scopes: ``<name>`` around the layer, ``attn_core``
+    around scores to weighted sum, whatever ``attn_impl`` computes them."""
+
+    def __init__(self, dim: int, n_head: int, theta: float = 10000.0, **kw):
+        super().__init__(dim, n_head, **kw)
+        self.theta = theta
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        cd = self.compute_dtype
+        b, t, d = x.shape
+        with jax.named_scope(self.name):
+            q = rotary(self._proj(params, x, "wq"), self.theta)
+            k = rotary(self._proj(params, x, "wk"), self.theta)
+            v = self._proj(params, x, "wv")
+            with jax.named_scope("attn_core"):
+                o = self._attend(q, k, v)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
+            return jnp.dot(o.astype(cd), params["wo"].astype(cd))
+
+
+class GatedMLP(Layer):
+    """Gated feed-forward (Shazeer 2020): ``W_d(act(W_g x) * W_u x)``, no
+    bias; ``silu`` makes it SwiGLU.  The gate's product in float32."""
+
+    def __init__(self, dim: int, hidden: int, activation: str = "silu",
+                 w_init=("normal", 0.02), compute_dtype=jnp.bfloat16,
+                 name: str = "mlp"):
+        self.dim, self.hidden = dim, hidden
+        self.activation = activation
+        self.w_init = w_init
+        self.compute_dtype = compute_dtype
+        self.name = name
+
+    def init(self, key):
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"wg": init_weight(kg, (self.dim, self.hidden), self.w_init),
+                "wu": init_weight(ku, (self.dim, self.hidden), self.w_init),
+                "wd": init_weight(kd, (self.hidden, self.dim), self.w_init)}
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        cd = self.compute_dtype
+        with jax.named_scope(self.name):
+            x = x.astype(cd)
+            g = jnp.dot(x, params["wg"].astype(cd)).astype(jnp.float32)
+            u = jnp.dot(x, params["wu"].astype(cd)).astype(jnp.float32)
+            h = (_activate(g, self.activation) * u).astype(cd)
+            return jnp.dot(h, params["wd"].astype(cd))
+
+
 class Flatten(Layer):
     def __init__(self, name: str = "flatten"):
         self.name = name
@@ -692,6 +791,8 @@ def _activate(x, kind: Optional[str]):
         return jax.nn.sigmoid(x)
     if kind == "leaky_relu":
         return jax.nn.leaky_relu(x, 0.2)
+    if kind == "silu":
+        return jax.nn.silu(x)
     raise ValueError(f"unknown activation {kind!r}")
 
 

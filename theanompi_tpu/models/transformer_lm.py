@@ -39,23 +39,23 @@ class LMData(DataBase):
         n_train = int(self.config.get("synthetic_train", n_train))
         n_val = int(self.config.get("synthetic_val", n_val))
         noise = float(self.config.get("noise", noise))
-
-        def make(n, seed):
-            r = np.random.RandomState(seed)
-            start = r.randint(0, vocab, (n, 1))
-            seq = (start + np.arange(seq_len + 1)) % vocab
-            flip = r.rand(n, seq_len + 1) < noise
-            seq = np.where(flip, r.randint(0, vocab, seq.shape), seq)
-            return seq.astype(np.int32)
-
-        self._train_seq = make(n_train, 101)
-        self._val_seq = make(n_val, 202)
+        self._train_seq = self._draw(n_train, 101, seq_len, vocab, noise)
+        self._val_seq = self._draw(n_val, 202, seq_len, vocab, noise)
         # DataBase bookkeeping keys off x/y arrays
         self.x_train = self._train_seq[:, :-1]
         self.y_train = self._train_seq[:, 1:]
         self.x_val = self._val_seq[:, :-1]
         self.y_val = self._val_seq[:, 1:]
         self._finalize()
+
+    def _draw(self, n, seed, seq_len, vocab, noise):
+        """``[n, seq_len + 1]`` int32 ids: inputs and next-token targets."""
+        r = np.random.RandomState(seed)
+        start = r.randint(0, vocab, (n, 1))
+        seq = (start + np.arange(seq_len + 1)) % vocab
+        flip = r.rand(n, seq_len + 1) < noise
+        seq = np.where(flip, r.randint(0, vocab, seq.shape), seq)
+        return seq.astype(np.int32)
 
     def _make_batch(self, x, y, train):
         # token ids stay int32 (the base class casts images to float32)

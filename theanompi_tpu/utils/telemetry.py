@@ -94,7 +94,10 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "pool_before_relu",
           # BSP's wire a step, written when the step is first traced
           "comm.allreduce_bytes", "comm.gathered_bytes",
-          "comm.gathered_leaves")
+          "comm.gathered_leaves",
+          # a looped model's work a step, written at its first trace
+          "model.loop_steps", "model.layer_applications",
+          "model.head_tokens")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
