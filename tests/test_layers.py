@@ -435,14 +435,15 @@ def test_rotary_attention_is_attention_of_the_turned_q_and_k():
     assert "a/attn_core" in text
 
 
-# -- the shim that lets jax's flash kernel into a step (jax_compat) -------------
+# -- the Pallas kernel behind attn_impl='flash', and the shim to it ------------
 
-def test_the_flash_shim_marks_the_steps_axis_alone_and_fails_by_name(
+def test_the_splash_shim_marks_the_steps_axis_alone_and_fails_by_name(
         monkeypatch):
     """The library module's out_shapes vary over the axis the caller names,
     where it is manual, and over no other; a library that no longer builds
     them through its global ``jax`` is refused when the shim is put in."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    from jax.experimental.pallas.ops.tpu.splash_attention import \
+        splash_attention_kernel as sk
     from jax.sharding import Mesh, PartitionSpec as P
     from theanompi_tpu import jax_compat
     view = jax_compat._VaryingOutShapes()
@@ -462,7 +463,89 @@ def test_the_flash_shim_marks_the_steps_axis_alone_and_fails_by_name(
                                  out_specs=P("workers", "model"))
             ).lower(jnp.zeros((2, 2)))
     assert seen == [frozenset({"workers"})]
-    monkeypatch.setattr(fa, "jax", object())        # a jax of another build
+    monkeypatch.setattr(sk, "jax", object())        # a jax of another build
     q = jnp.zeros((1, 1, 128, 128), jnp.bfloat16)
     with pytest.raises(AssertionError, match="out_shapes"):
-        jax_compat.flash_attention(q, q, q, axis_name="workers")
+        jax_compat.splash_attention(q, q, q, axis_name="workers",
+                                    causal=True, **L.flash_tiles(128))
+
+
+@pytest.mark.parametrize("t, causal", [(256, True), (256, False),
+                                       (512, True), (512, False)])
+def test_the_flash_branch_is_the_references_attention_and_gradients(
+        t, causal):
+    """``attn_impl='flash'`` in Pallas interpret mode, heads of 128 in
+    bfloat16, against ``attention_reference`` in float32 on the same
+    operands: the output and the gradients of q, k and v to bfloat16's
+    rounding (0.2-0.7% of the largest entry, as on the chip: PERF.md
+    section 6, PR 35)."""
+    import jax.experimental.pallas.tpu as pltpu
+    from theanompi_tpu.ops.ring_attention import attention_reference
+    attn = L.MultiHeadAttention(256, 2, causal=causal, attn_impl="flash")
+    ks = jax.random.split(jax.random.key(t + causal), 4)
+    q, k, v = (jax.random.normal(kk, (2, 2, t, 128), F32).astype(
+        jnp.bfloat16) for kk in ks[:3])
+    w = jax.random.normal(ks[3], (2, 2, t, 128), F32)
+
+    def flash(q, k, v):
+        o = attn._attend(q, k, v)
+        return jnp.sum(o.astype(F32) * w), o
+
+    def plain(q, k, v):
+        o = attention_reference(q.astype(F32), k.astype(F32), v.astype(F32),
+                                causal=causal)
+        return jnp.sum(o * w), o
+
+    with pltpu.force_tpu_interpret_mode():
+        (_, o), got = jax.value_and_grad(flash, (0, 1, 2), has_aux=True)(
+            q, k, v)
+    (_, o_ref), want = jax.value_and_grad(plain, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    assert o.dtype == jnp.bfloat16 and o.shape == q.shape
+    for name, a, b in zip("o q k v".split(), (o,) + got, (o_ref,) + want):
+        b = b.astype(F32)
+        gap = float(jnp.max(jnp.abs(a.astype(F32) - b)) / jnp.max(jnp.abs(b)))
+        assert gap < 0.01, (name, gap)
+
+
+@pytest.mark.parametrize("t, want", [
+    (128, (128, 128, 128)), (4096, (512, 1024, 512)), (1536, (512, 768, 384)),
+    (96, None), (4097, None)])
+def test_flash_tiles_follow_the_sequence_length(t, want):
+    """The sizes the zoo uses get the tiles chosen on the chip, cut to the
+    sequence; one off 128 is refused by name."""
+    if want is None:
+        with pytest.raises(ValueError, match="multiple of 128"):
+            L.flash_tiles(t)
+        return
+    tiles = L.flash_tiles(t)
+    assert (tiles["block_q"], tiles["block_kv"],
+            tiles["block_kv_compute"]) == want
+    assert tiles["block_q_dkv"] == tiles["block_kv_dkv"] \
+        == tiles["block_kv_dkv_compute"] == want[1]
+    assert tiles["use_fused_bwd_kernel"] is True
+    for name, b in tiles.items():
+        assert name == "use_fused_bwd_kernel" or (t % b == 0 and b % 128 == 0)
+
+
+@pytest.mark.parametrize("attn_impl, want", [("flash", 16), ("reference", 0)])
+def test_the_looped_step_counts_the_attention_cores_on_the_kernel_path(
+        attn_impl, want):
+    """Four layers four times over: sixteen cores a step, all of them on
+    the kernel path under ``flash`` and none under ``reference``.  Traced,
+    not lowered: the kernel itself is the chip's."""
+    from theanompi_tpu.models.looped_lm import LoopedLM
+    from theanompi_tpu.utils import telemetry
+    m = LoopedLM(dict(vocab=128, d_model=256, n_head=2, n_layer=4, d_ff=64,
+                      seq_len=128, loop_steps=4, attn_impl=attn_impl,
+                      n_workers=1, seed=3, batch_size=2, synthetic_train=8,
+                      synthetic_val=4, verbose=False))
+    names = ("model.attn_kernel_applications", "model.layer_applications")
+    before = [telemetry.totals().get(k, (0, 0))[0] for k in names]
+    batch = {"x": jnp.zeros((2, 128), jnp.int32),
+             "y": jnp.zeros((2, 128), jnp.int32)}
+    for _ in range(2):          # counted once however often it is traced
+        jax.eval_shape(lambda p: m.loss_and_metrics(p, {}, batch, None, True),
+                       m.params)
+    after = [telemetry.totals()[k][0] for k in names]
+    assert [a - b for a, b in zip(after, before)] == [want, 16]

@@ -4,6 +4,8 @@ is needed).  The topology is described inside a fixture, never at import:
 only one process may hold the TPU's library, and every xdist worker imports
 this file.  Keep such tests in this one file."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,9 +37,10 @@ def one_chip_mesh(topo):
 
 def test_flash_attention_compiles_inside_the_steps_shard_map(one_chip_mesh):
     """``attn_impl='flash'`` forward and backward at 16 heads of 128 over
-    two sequences of 4,096, inside a ``shard_map`` that checks vma as every
-    step of this package does: jax's kernel builds its out_shapes without
-    ``vma`` and was refused there until ``jax_compat.flash_attention``."""
+    two sequences of 4,096, under ``jax.checkpoint`` as the looped stack
+    applies a layer, inside a ``shard_map`` that checks vma as every step
+    of this package does: jax's kernel builds its out_shapes without
+    ``vma`` and is refused there but for ``jax_compat.splash_attention``."""
     mesh = one_chip_mesh
     attn = L.RotaryAttention(2048, 16, theta=1e6, attn_impl="flash",
                              name="attn")
@@ -45,8 +48,9 @@ def test_flash_attention_compiles_inside_the_steps_shard_map(one_chip_mesh):
 
     def per_worker(p, x):
         def loss(p, x):
-            return jnp.sum(attn.apply(p, x[0]).astype(jnp.float32))
-        return jax.tree.map(lambda g: g[None], jax.grad(loss)(
+            return jnp.sum(jax.checkpoint(attn.apply)(p, x[0]).astype(
+                jnp.float32))
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(loss)(
             jax.tree.map(lambda a: a[0], p), x))
 
     spec = P("workers")
@@ -57,9 +61,15 @@ def test_flash_attention_compiles_inside_the_steps_shard_map(one_chip_mesh):
     step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
                              out_specs=spec))
     text = step.lower(boxed, x).compile().as_text()
-    # the forward kernel and both backward ones, at the tile asked for
-    assert text.count('custom_call_target="tpu_custom_call"') >= 3
-    for kernel in ("flash_mha_bwd_dkv", "flash_mha_bwd_dq"):
-        assert kernel in text, kernel
-    assert "block_q_512" in text
+    # the forward kernel, the same again for the backward pass, and one
+    # fused backward kernel for dq, dk and dv: no kernel of dq's own
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "splash_mha_fwd_residuals" in text
+    assert "splash_mha_dkv_no_residuals" in text
+    assert "splash_mha_dq" not in text and "flash_mha" not in text
+    # at the tiles layers.flash_tiles gives this length, 32 heads in one
+    # call (the batch goes in as heads)
+    for name, size in L.flash_tiles(4096).items():
+        assert f'{name}\\": {json.dumps(size)}' in text, name
+    assert "bf16[32,4096,128]" in text
     assert "attn_core" in text                  # the scope reaches the HLO

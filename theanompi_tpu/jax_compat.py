@@ -56,27 +56,41 @@ def vary(x, axis_name: str):
     return lax.pcast(x, (axis_name,), to="varying")
 
 
-def flash_attention(q, k, v, *, axis_name: str, **kw):
-    """jax's Pallas TPU flash-attention kernel, callable inside a
-    ``shard_map`` over ``axis_name`` that checks vma (every step of this
-    package).  The library builds its kernels' ``out_shape``s without
-    ``vma``, which ``pallas_call`` refuses while the check is on, in the
-    forward call and in the two backward ones that are traced long after
-    this function has returned; so the library module's view of ``jax`` is
-    replaced, once and for the process, by one whose ``ShapeDtypeStruct``
-    marks an output varying over ``axis_name`` wherever that axis is manual.
-    A jax that no longer builds them that way fails here, by name."""
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-    view = fa.jax
+def splash_attention(q, k, v, *, axis_name: str, causal: bool, **tiles):
+    """jax's Pallas TPU splash-attention kernel over ``[B, H, T, hd]``,
+    callable inside a ``shard_map`` over ``axis_name`` that checks vma
+    (every step of this package).  The kernel takes no scale: ``q`` comes
+    scaled.  ``tiles`` are its ``BlockSizes``.  The batch goes in as more
+    heads (one mask serves them all), so nothing is vmapped.
+
+    The library builds its kernels' ``out_shape``s without ``vma``, which
+    ``pallas_call`` refuses while the check is on, in the forward call and
+    in the backward one that is traced long after this function has
+    returned; so the library module's view of ``jax`` is replaced, once and
+    for the process, by one whose ``ShapeDtypeStruct`` marks an output
+    varying over ``axis_name`` wherever that axis is manual.  A jax that no
+    longer builds them that way fails here, by name.  The mask's block
+    tables are constants of the trace and are marked varying the same way."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    view = sk.jax
     if not isinstance(view, _VaryingOutShapes):
         assert view is jax and \
-            "jax.ShapeDtypeStruct(" in inspect.getsource(fa), (
-                "jax's flash_attention module no longer builds its "
+            "jax.ShapeDtypeStruct(" in inspect.getsource(sk), (
+                "jax's splash_attention_kernel module no longer builds its "
                 "out_shapes through its global jax.ShapeDtypeStruct: "
-                "jax_compat.flash_attention has nothing to give vma to")
-        view = fa.jax = _VaryingOutShapes()
+                "jax_compat.splash_attention has nothing to give vma to")
+        view = sk.jax = _VaryingOutShapes()
     view.axes.add(axis_name)
-    return fa.flash_attention(q, k, v, **kw)
+    b, h, t, _ = q.shape
+    mask = (sm.CausalMask if causal else sm.FullMask)((t, t))
+    kernel = sk.make_splash_mha(
+        sm.MultiHeadMask([mask] * (b * h)), head_shards=1, q_seq_shards=1,
+        block_sizes=sk.BlockSizes(**tiles))
+    if axis_name in jax.sharding.get_abstract_mesh().manual_axes:
+        kernel = jax.tree.map(lambda a: vary(a, axis_name), kernel)
+    return kernel(*(a.reshape(b * h, t, a.shape[-1]) for a in (q, k, v))
+                  ).reshape(b, h, t, v.shape[-1])
 
 
 class _VaryingOutShapes:

@@ -22,7 +22,6 @@ Design departures from the reference, all deliberate and TPU-first:
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -552,8 +551,24 @@ class Embedding(Layer):
         return params["w"].astype(self.compute_dtype)[x]
 
 
-FLASH_BLOCK = 512       # the flash kernel's tiles, all of them (the chip
-                        # has run no other size: PERF.md section 5)
+def flash_tiles(t: int) -> dict:
+    """The splash kernel's ``BlockSizes`` for sequences of ``t``: the
+    winner of PR 35's sweep on a v5e at T 4096, heads of 128, bfloat16,
+    causal (PERF.md section 6 holds the whole table): the forward takes q
+    512 rows and k/v 1,024 at a time, 512 to a product; the fused backward
+    (scores made once for dq, dk and dv) 1,024 each way.  A shorter ``t``
+    gets the largest multiple of 128 under those that divides it."""
+    if t % 128:
+        raise ValueError(f"attn_impl='flash' needs a sequence length that "
+                         f"is a multiple of 128, the kernel's lanes; got {t}")
+
+    def tile(n, cap):
+        return max(b for b in range(128, min(n, cap) + 1, 128) if n % b == 0)
+
+    q, kv = tile(t, 512), tile(t, 1024)
+    return dict(block_q=q, block_kv=kv, block_kv_compute=tile(kv, 512),
+                block_q_dkv=kv, block_kv_dkv=kv, block_kv_dkv_compute=kv,
+                use_fused_bwd_kernel=True)
 
 
 class MultiHeadAttention(Layer):
@@ -564,11 +579,15 @@ class MultiHeadAttention(Layer):
     (fp32 accumulation) — the sequence-SHARDED variant of the same math is
     :func:`ops.ring_attention.ring_attention` on a 2-D data×seq mesh.
 
-    ``attn_impl='flash'`` (TPU only): the fused Pallas flash-attention
-    kernel (``jax.experimental.pallas.ops.tpu.flash_attention`` — tiled
-    online-softmax in VMEM, custom VJP, never materializes the [T, T]
-    scores) instead of the XLA einsum chain.  Needs seq_len a multiple of
-    the kernel's 128-wide blocks."""
+    ``attn_impl='flash'`` (TPU only): jax's Pallas splash-attention kernel
+    (``jax.experimental.pallas.ops.tpu.splash_attention``: tiled online
+    softmax in VMEM with 16-bit operands and float32 accumulation and
+    statistics, a custom VJP whose backward is one fused kernel, the mask
+    known at trace time so that tiles above the diagonal are never fetched
+    and only the diagonal's are masked; never materializes the [T, T]
+    scores) instead of the XLA einsum chain, through
+    :func:`jax_compat.splash_attention` with :func:`flash_tiles`.  Needs
+    seq_len a multiple of 128."""
 
     def __init__(self, dim: int, n_head: int, causal: bool = True,
                  w_init=("normal", 0.02), compute_dtype=jnp.bfloat16,
@@ -581,26 +600,22 @@ class MultiHeadAttention(Layer):
         self.attn_impl = attn_impl
         self.name = name
 
-    def _attend(self, q, k, v):
-        """[B, H, T, hd] → [B, H, T, hd] softmax attention."""
+    def _attend(self, q, k, v, scaled: bool = False):
+        """[B, H, T, hd] → [B, H, T, hd] softmax attention; ``scaled`` says
+        that q already carries the ``1 / sqrt(hd)``."""
         if self.attn_impl == "flash":
-            from jax.experimental.pallas.ops.tpu.flash_attention import \
-                BlockSizes
-            from ..jax_compat import flash_attention
+            from ..jax_compat import splash_attention
             from ..parallel.mesh import WORKER_AXIS
-            hd = q.shape[-1]
-            blk = min(FLASH_BLOCK, q.shape[2])
-            sizes = BlockSizes(**{f.name: 1 if f.name == "block_b" else blk
-                                  for f in dataclasses.fields(BlockSizes)})
             # the kernel's tiles hold 16-bit operands at the narrowest
             dt = q.dtype if q.dtype.itemsize >= 2 else jnp.bfloat16
-            return flash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                                   axis_name=WORKER_AXIS,
-                                   causal=self.causal, block_sizes=sizes,
-                                   sm_scale=1.0 / (hd ** 0.5)
-                                   ).astype(q.dtype)
+            if not scaled:      # the kernel takes no scale
+                q = q.astype(jnp.float32) / (q.shape[-1] ** 0.5)
+            return splash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
+                                    axis_name=WORKER_AXIS, causal=self.causal,
+                                    **flash_tiles(q.shape[2])).astype(v.dtype)
         from ..ops.ring_attention import attention_reference
-        return attention_reference(q, k, v, causal=self.causal)
+        return attention_reference(q, k, v, causal=self.causal,
+                                   scale=1.0 if scaled else None)
 
     def init(self, key):
         ks = jax.random.split(key, 4)
@@ -688,14 +703,17 @@ class RMSNorm(Layer):
             return y.astype(x.dtype)
 
 
-def rotary(x, theta: float = 10000.0):
+def rotary(x, theta: float = 10000.0, scale: float = 1.0):
     """Rotary position embedding (Su et al. 2021) of ``[..., T, hd]`` in
     the half-split form: at position ``t`` the pair ``(x[i], x[i + hd/2])``
-    turns by ``t * theta ** (-2i / hd)``.  The turn is made in float32."""
+    turns by ``t * theta ** (-2i / hd)``.  The turn is made in float32, and
+    so is the multiplication by ``scale``."""
     t, hd = x.shape[-2], x.shape[-1]
     freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None]  # [T, hd/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:        # no multiplication by one in a lowering without
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
@@ -714,11 +732,15 @@ class RotaryAttention(MultiHeadAttention):
         cd = self.compute_dtype
         b, t, d = x.shape
         with jax.named_scope(self.name):
-            q = rotary(self._proj(params, x, "wq"), self.theta)
+            # the flash kernel takes no scale: q gets it where the turn
+            # has it in float32, not as a second rounding of compute_dtype
+            fold = self.attn_impl == "flash"
+            q = rotary(self._proj(params, x, "wq"), self.theta,
+                       (self.dim // self.n_head) ** -0.5 if fold else 1.0)
             k = rotary(self._proj(params, x, "wk"), self.theta)
             v = self._proj(params, x, "wv")
             with jax.named_scope("attn_core"):
-                o = self._attend(q, k, v)
+                o = self._attend(q, k, v, scaled=fold)
             o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
             return jnp.dot(o.astype(cd), params["wo"].astype(cd))
 

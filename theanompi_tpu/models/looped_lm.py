@@ -25,7 +25,8 @@ taken ``head_block`` tokens at a time, so that no ``[tokens, vocab]``
 tensor of a whole step exists.  Scopes: ``ut_loop`` (the stack, all loop
 steps; inside it ``attn``, ``attn_core``, ``mlp``) and ``exit_head``
 (heads, gate, objective).  Counters, at the step's first trace:
-``model.loop_steps``, ``model.layer_applications``, ``model.head_tokens``.
+``model.loop_steps``, ``model.layer_applications``, ``model.head_tokens``,
+``model.attn_kernel_applications``.
 """
 
 from __future__ import annotations
@@ -232,6 +233,8 @@ class LoopedLM(ModelBase):
             telemetry.count("model.loop_steps", r)
             telemetry.count("model.layer_applications", r * self.n_layer)
             telemetry.count("model.head_tokens", r * rows * self.seq_len)
+            telemetry.count("model.attn_kernel_applications", r * sum(
+                b.attn.attn_impl == "flash" for b in self.blocks))
 
     def loss_and_metrics(self, params, bn_state, batch, rng, train):
         x, y = batch["x"], batch["y"]
