@@ -467,7 +467,7 @@ def test_the_splash_shim_marks_the_steps_axis_alone_and_fails_by_name(
     q = jnp.zeros((1, 1, 128, 128), jnp.bfloat16)
     with pytest.raises(AssertionError, match="out_shapes"):
         jax_compat.splash_attention(q, q, q, axis_name="workers",
-                                    causal=True, **L.flash_tiles(128))
+                                    mask="causal", **L.flash_tiles(128))
 
 
 @pytest.mark.parametrize("t, causal", [(256, True), (256, False),
@@ -549,3 +549,20 @@ def test_the_looped_step_counts_the_attention_cores_on_the_kernel_path(
                        m.params)
     after = [telemetry.totals()[k][0] for k in names]
     assert [a - b for a, b in zip(after, before)] == [want, 16]
+
+
+@pytest.mark.parametrize("t, window, fwd, bwd", [
+    (8192, 512, (512, 512, 512), (512, 1024, 1024)),
+    (8192, 256, (512, 256, 256), (512, 1024, 1024)),
+    (8192, 4096, (512, 1024, 512), (512, 1024, 1024)),
+    (256, 64, (256, 128, 128), (256, 256, 256))])
+def test_flash_tiles_under_a_window(t, window, fwd, bwd):
+    """The forward's k/v tile is no longer than the window; without one
+    the tiles are what they were."""
+    tiles = L.flash_tiles(t, window)
+    assert (tiles["block_q"], tiles["block_kv"],
+            tiles["block_kv_compute"]) == fwd
+    assert (tiles["block_q_dkv"], tiles["block_kv_dkv"],
+            tiles["block_kv_dkv_compute"]) == bwd
+    assert L.flash_tiles(t, None) == L.flash_tiles(t)
+    assert L.flash_tiles(8192)["block_q_dkv"] == 1024

@@ -243,3 +243,42 @@ def test_it_trains_through_the_rules_own_path():
     attn = rule.model.blocks[0].attn
     assert isinstance(attn, L.RotaryAttention)
     assert (attn.attn_impl, attn.theta) == ("reference", 1e6)
+
+
+# -- the lowering, pinned ---------------------------------------------------------------
+
+@pytest.mark.parametrize("impl, t, want", [
+    ("reference", 16,
+     "09c99e59dabc7b026d3cefd60779cb6e8e27d94d1daa75e3a2594e9f43be5683"),
+    ("flash", 128,
+     "d3971e93d4aa46a67e59cb573f9cb0e5690965cac6ca8cc407d8f703cc1c7f33")])
+def test_the_looped_steps_lowering_is_what_it_was(impl, t, want):
+    """The looped model's loss and gradient lowered for a TPU at toy size:
+    the StableHLO's hash as commit 6180482 (PR 36) gave it, before
+    ``jax_compat.splash_attention`` took its mask as an argument and
+    ``layers.attend`` left ``MultiHeadAttention._attend`` (PR 37).  A
+    change to what the looped cell shares with another model (``_attend``,
+    ``attend``, ``flash_tiles``, ``splash_attention``, ``rotary``,
+    ``RMSNorm``, ``GatedMLP``) that moves this has changed the looped
+    cell's program: measure that cell, then pin the new hash.  The Pallas
+    kernels' serialized bodies are cut out first: they hold the call
+    stack's file paths and line numbers, which differ from one checkout
+    to the next."""
+    import hashlib
+    import re
+
+    model = LoopedLM(dict(
+        vocab=128, d_model=256, n_head=2, n_layer=2, d_ff=96, seq_len=t,
+        loop_steps=3, n_workers=1, seed=3, batch_size=2, synthetic_train=8,
+        synthetic_val=4, verbose=False, attn_impl=impl))
+    model.head_block = t
+    x = jnp.zeros((2, t), jnp.int32)
+    step = jax.jit(jax.value_and_grad(
+        lambda p, x: model.loss_and_metrics(p, {}, {"x": x, "y": x}, None,
+                                            True)[0]))
+    text = step.trace(jax.device_get(model.params), x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    text, kernels = re.subn(
+        r'(\\22body\\22: \\22)[A-Za-z0-9+/=]*(\\22)', r"\1\2", text)
+    assert kernels == (3 if impl == "flash" else 0)
+    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -73,3 +73,82 @@ def test_flash_attention_compiles_inside_the_steps_shard_map(one_chip_mesh):
         assert f'{name}\\": {json.dumps(size)}' in text, name
     assert "bf16[32,4096,128]" in text
     assert "attn_core" in text                  # the scope reaches the HLO
+
+
+@pytest.mark.parametrize("kind, heads, window", [
+    ("sliding_attention", 18, 512), ("full_attention", 12, None)])
+def test_mixed_attention_compiles_at_the_routed_cells_shapes(
+        one_chip_mesh, kind, heads, window):
+    """The routed cell's two attention layers at a quarter of the
+    published heads, 18 or 12 query heads over 2 key/value heads of 128,
+    one sequence of 8,192, hidden 3,072: a causal window of 512 as a
+    trace-time mask (full layers: the causal mask, YaRN over half the
+    head), the per-head gate, under ``jax.checkpoint`` inside the step's
+    ``shard_map``."""
+    from theanompi_tpu.models.routed_lm import rotary_frequencies
+    mesh = one_chip_mesh
+    with open("benchmarks/configs/laguna-s-2.1.json") as f:
+        rope = json.load(f)["rope_parameters"][kind]
+    freq, factor = rotary_frequencies(rope, 128)
+    assert len(freq) == (64 if window else 32)
+    attn = L.GroupedQueryAttention(3072, heads, 2, 128, freq, factor,
+                                   window=window, attn_impl="flash",
+                                   name="attn")
+    params = jax.eval_shape(attn.init, jax.random.key(0))
+
+    def per_worker(p, x):
+        def loss(p, x):
+            return jnp.sum(jax.checkpoint(attn.apply)(p, x[0]).astype(
+                jnp.float32))
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(loss)(
+            jax.tree.map(lambda a: a[0], p), x))
+
+    spec = P("workers")
+    sh = NamedSharding(mesh, spec)
+    boxed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=sh), params)
+    x = jax.ShapeDtypeStruct((1, 1, 8192, 3072), jnp.float32, sharding=sh)
+    step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec))
+    text = step.lower(boxed, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert "splash_mha_fwd_residuals" in text
+    assert "splash_mha_dkv_no_residuals" in text
+    # the key/value heads go in repeated to the query heads
+    assert f"bf16[{heads},8192,128]" in text
+    for name, size in L.flash_tiles(8192, window).items():
+        assert f'{name}\\": {json.dumps(size)}' in text, name
+    assert "attn_core" in text
+
+
+def test_the_held_experts_compile_at_the_routed_cells_shapes(one_chip_mesh):
+    """8 of 256 experts of width 1,024 over 8,192 tokens of 3,072, 10 a
+    token, with the shared expert: router, sort, the walk over the routed
+    pairs with its grouped products, forward and backward under
+    ``jax.checkpoint`` inside the step's ``shard_map``."""
+    from theanompi_tpu.parallel.moe import HeldExperts
+    mesh = one_chip_mesh
+    layer = HeldExperts(3072, 256, (0, 8), 10, 1024, 1024, 2.5, name="moe")
+    assert layer.rows_at_once(8192) == 4096
+    params = jax.eval_shape(layer.init, jax.random.key(0))
+
+    def per_worker(p, x):
+        def loss(p, x):
+            return jnp.sum(jax.checkpoint(layer.apply)(p, x[0]))
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(loss)(
+            jax.tree.map(lambda a: a[0], p), x))
+
+    spec = P("workers")
+    sh = NamedSharding(mesh, spec)
+    boxed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=sh), params)
+    x = jax.ShapeDtypeStruct((1, 1, 8192, 3072), jnp.float32, sharding=sh)
+    step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec))
+    compiled = step.lower(boxed, x).compile()
+    text = compiled.as_text()
+    assert "moe/router" in text and "experts" in text
+    assert "shared_expert" in text
+    # one stretch of 4,096 rows at a time, never the worst case's 65,536
+    assert "[4096,3072]" in text and "[65536," not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

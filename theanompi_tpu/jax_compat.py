@@ -56,11 +56,14 @@ def vary(x, axis_name: str):
     return lax.pcast(x, (axis_name,), to="varying")
 
 
-def splash_attention(q, k, v, *, axis_name: str, causal: bool, **tiles):
+def splash_attention(q, k, v, *, axis_name: str, mask, **tiles):
     """jax's Pallas TPU splash-attention kernel over ``[B, H, T, hd]``,
     callable inside a ``shard_map`` over ``axis_name`` that checks vma
     (every step of this package).  The kernel takes no scale: ``q`` comes
-    scaled.  ``tiles`` are its ``BlockSizes``.  The batch goes in as more
+    scaled.  ``mask`` is known at trace time: ``"causal"``, ``"full"``, or
+    ``("window", w)``, causal and no key further back than ``w - 1``
+    (``0 <= t - s < w``); tiles the mask leaves empty are never fetched.
+    ``tiles`` are the kernel's ``BlockSizes``.  The batch goes in as more
     heads (one mask serves them all), so nothing is vmapped.
 
     The library builds its kernels' ``out_shape``s without ``vma``, which
@@ -83,7 +86,14 @@ def splash_attention(q, k, v, *, axis_name: str, causal: bool, **tiles):
         view = sk.jax = _VaryingOutShapes()
     view.axes.add(axis_name)
     b, h, t, _ = q.shape
-    mask = (sm.CausalMask if causal else sm.FullMask)((t, t))
+    if mask == "causal":
+        mask = sm.CausalMask((t, t))
+    elif mask == "full":
+        mask = sm.FullMask((t, t))
+    else:
+        kind, w = mask
+        assert kind == "window" and 1 <= w, mask
+        mask = sm.LocalMask((t, t), window_size=(w - 1, 0), offset=0)
     kernel = sk.make_splash_mha(
         sm.MultiHeadMask([mask] * (b * h)), head_shards=1, q_seq_shards=1,
         block_sizes=sk.BlockSizes(**tiles))

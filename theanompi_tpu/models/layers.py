@@ -551,13 +551,18 @@ class Embedding(Layer):
         return params["w"].astype(self.compute_dtype)[x]
 
 
-def flash_tiles(t: int) -> dict:
+def flash_tiles(t: int, window: Optional[int] = None) -> dict:
     """The splash kernel's ``BlockSizes`` for sequences of ``t``: the
     winner of PR 35's sweep on a v5e at T 4096, heads of 128, bfloat16,
     causal (PERF.md section 6 holds the whole table): the forward takes q
     512 rows and k/v 1,024 at a time, 512 to a product; the fused backward
     (scores made once for dq, dk and dv) 1,024 each way.  A shorter ``t``
-    gets the largest multiple of 128 under those that divides it."""
+    gets the largest multiple of 128 under those that divides it.  Under
+    a causal ``window`` (PR 37's sweep at T 8192, window 512, 18 heads:
+    3.74 ms forward, forward again and backward against 4.33) the
+    forward's k/v tile is no longer than the window, so that fewer keys
+    outside it are fetched and masked, and the backward takes q 512 rows
+    at a time."""
     if t % 128:
         raise ValueError(f"attn_impl='flash' needs a sequence length that "
                          f"is a multiple of 128, the kernel's lanes; got {t}")
@@ -566,9 +571,36 @@ def flash_tiles(t: int) -> dict:
         return max(b for b in range(128, min(n, cap) + 1, 128) if n % b == 0)
 
     q, kv = tile(t, 512), tile(t, 1024)
-    return dict(block_q=q, block_kv=kv, block_kv_compute=tile(kv, 512),
-                block_q_dkv=kv, block_kv_dkv=kv, block_kv_dkv_compute=kv,
-                use_fused_bwd_kernel=True)
+    fwd_kv = kv if window is None else tile(t, max(128, min(window, 1024)))
+    return dict(block_q=q, block_kv=fwd_kv,
+                block_kv_compute=tile(fwd_kv, 512),
+                block_q_dkv=kv if window is None else q, block_kv_dkv=kv,
+                block_kv_dkv_compute=kv, use_fused_bwd_kernel=True)
+
+
+def attend(q, k, v, *, attn_impl: str, causal: bool = True,
+           window: Optional[int] = None, scaled: bool = False):
+    """[B, H, T, hd] → [B, H, T, hd] softmax attention, by the splash
+    kernel (``attn_impl='flash'``) or the XLA einsum chain
+    (``'reference'``).  ``window`` (causal only) keeps the keys
+    ``0 <= t - s < window``; ``scaled`` says that q already carries the
+    ``1 / sqrt(hd)``."""
+    assert window is None or causal, "a window is a causal window"
+    if attn_impl == "flash":
+        from ..jax_compat import splash_attention
+        from ..parallel.mesh import WORKER_AXIS
+        # the kernel's tiles hold 16-bit operands at the narrowest
+        dt = q.dtype if q.dtype.itemsize >= 2 else jnp.bfloat16
+        if not scaled:      # the kernel takes no scale
+            q = q.astype(jnp.float32) / (q.shape[-1] ** 0.5)
+        mask = ("window", window) if window is not None else \
+            "causal" if causal else "full"
+        return splash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
+                                axis_name=WORKER_AXIS, mask=mask,
+                                **flash_tiles(q.shape[2], window)).astype(v.dtype)
+    from ..ops.ring_attention import attention_reference
+    return attention_reference(q, k, v, causal=causal,
+                               scale=1.0 if scaled else None, window=window)
 
 
 class MultiHeadAttention(Layer):
@@ -603,19 +635,8 @@ class MultiHeadAttention(Layer):
     def _attend(self, q, k, v, scaled: bool = False):
         """[B, H, T, hd] → [B, H, T, hd] softmax attention; ``scaled`` says
         that q already carries the ``1 / sqrt(hd)``."""
-        if self.attn_impl == "flash":
-            from ..jax_compat import splash_attention
-            from ..parallel.mesh import WORKER_AXIS
-            # the kernel's tiles hold 16-bit operands at the narrowest
-            dt = q.dtype if q.dtype.itemsize >= 2 else jnp.bfloat16
-            if not scaled:      # the kernel takes no scale
-                q = q.astype(jnp.float32) / (q.shape[-1] ** 0.5)
-            return splash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                                    axis_name=WORKER_AXIS, causal=self.causal,
-                                    **flash_tiles(q.shape[2])).astype(v.dtype)
-        from ..ops.ring_attention import attention_reference
-        return attention_reference(q, k, v, causal=self.causal,
-                                   scale=1.0 if scaled else None)
+        return attend(q, k, v, attn_impl=self.attn_impl, causal=self.causal,
+                      scaled=scaled)
 
     def init(self, key):
         ks = jax.random.split(key, 4)
@@ -743,6 +764,110 @@ class RotaryAttention(MultiHeadAttention):
                 o = self._attend(q, k, v, scaled=fold)
             o = o.transpose(0, 2, 1, 3).reshape(b, t, d)
             return jnp.dot(o.astype(cd), params["wo"].astype(cd))
+
+
+def yarn_frequencies(rot: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's ``rot / 2`` rotary frequencies (Peng et al. 2023, as the
+    transformers ``yarn`` initialisation computes them): pair ``i`` of a
+    rotary size ``rot`` turns by ``f_i = theta ** (-2i / rot)`` where it
+    makes more than ``beta_fast`` turns over ``original_max`` positions,
+    by ``f_i / factor`` where it makes fewer than ``beta_slow``, and by a
+    linear blend of the two between."""
+    f = theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+
+    def pair_of(turns):     # the pair that makes `turns` over original_max
+        return rot * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), rot - 1)
+    keep = 1.0 - np.clip((np.arange(rot // 2) - lo) / max(hi - lo, 1e-3),
+                         0.0, 1.0)
+    return (f / factor * (1.0 - keep) + f * keep).astype(np.float32)
+
+
+def rotary_turn(x, freq, factor: float = 1.0, scale: float = 1.0):
+    """``[..., T, hd]`` → float32: the first ``2 * len(freq)`` entries of
+    each head turned in half-split pairs, ``(x[i], x[i + len(freq)])`` by
+    ``t * freq[i]`` at position ``t``, with cos and sin times ``factor``
+    (YaRN's attention factor); the rest of the head passed on; all of it
+    times ``scale`` (the softmax scale folded into q)."""
+    t, r = x.shape[-2], len(freq)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(freq, jnp.float32)[None]                  # [T, r]
+    cos, sin = jnp.cos(ang) * (factor * scale), \
+        jnp.sin(ang) * (factor * scale)
+    x = x.astype(jnp.float32)
+    x1, x2, rest = x[..., :r], x[..., r:2 * r], x[..., 2 * r:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+                           + [rest * scale] * (rest.shape[-1] > 0),
+                           axis=-1)
+
+
+class GroupedQueryAttention(Layer):
+    """Causal attention with fewer key/value heads than query heads
+    (Ainslie et al. 2023), an optional causal window, rotary positions
+    over a part of the head and a per-head output gate (head-wise gated
+    attention, arXiv:2505.06708): query head ``i`` reads key/value head
+    ``i // (n_q / n_kv)``; ``o_i <- sigmoid(x W_g)_i * o_i`` before
+    ``W_o``.  No bias.  ``freq`` are the rotary frequencies (their number
+    sets how much of the head turns), ``rope_factor`` multiplies cos and
+    sin.  The core is :func:`attend`, as :class:`MultiHeadAttention`'s;
+    scopes ``<name>`` and ``attn_core`` as :class:`RotaryAttention`'s."""
+
+    def __init__(self, dim: int, n_q: int, n_kv: int, head_dim: int, freq,
+                 rope_factor: float = 1.0, window: Optional[int] = None,
+                 w_init=("normal", 0.02), compute_dtype=jnp.bfloat16,
+                 attn_impl: str = "reference", name: str = "attn"):
+        assert n_q % n_kv == 0, (n_q, n_kv)
+        assert attn_impl in ("reference", "flash"), attn_impl
+        assert 2 * len(freq) <= head_dim, (len(freq), head_dim)
+        self.dim, self.n_q, self.n_kv, self.hd = dim, n_q, n_kv, head_dim
+        self.freq, self.rope_factor, self.window = freq, rope_factor, window
+        self.w_init = w_init
+        self.compute_dtype = compute_dtype
+        self.attn_impl = attn_impl
+        self.name = name
+
+    def init(self, key):
+        ks = jax.random.split(key, 5)
+        d, q, kv = self.dim, self.n_q * self.hd, self.n_kv * self.hd
+        mk = lambda k, shape: init_weight(k, shape, self.w_init)  # noqa: E731
+        return {"wq": mk(ks[0], (d, q)), "wk": mk(ks[1], (d, kv)),
+                "wv": mk(ks[2], (d, kv)), "wg": mk(ks[3], (d, self.n_q)),
+                "wo": mk(ks[4], (q, d))}
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        cd = self.compute_dtype
+        b, t, _ = x.shape
+        x = x.astype(cd)
+
+        def heads(name, n):                                 # [B, n, T, hd]
+            y = jnp.dot(x, params[name].astype(cd))
+            return y.reshape(b, t, n, self.hd).transpose(0, 2, 1, 3)
+
+        with jax.named_scope(self.name):
+            fold = self.attn_impl == "flash"    # as RotaryAttention's
+            q = rotary_turn(heads("wq", self.n_q), self.freq,
+                            self.rope_factor,
+                            self.hd ** -0.5 if fold else 1.0).astype(cd)
+            k = rotary_turn(heads("wk", self.n_kv), self.freq,
+                            self.rope_factor).astype(cd)
+            group = self.n_q // self.n_kv
+            k = jnp.repeat(k, group, axis=1)
+            v = jnp.repeat(heads("wv", self.n_kv), group, axis=1)
+            with jax.named_scope("attn_core"):
+                o = attend(q, k, v, attn_impl=self.attn_impl,
+                           window=self.window, scaled=fold)
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, params["wg"].astype(cd),
+                preferred_element_type=jnp.float32))        # [B, T, n_q]
+            o = o.transpose(0, 2, 1, 3).astype(jnp.float32) \
+                * gate[..., None]
+            return jnp.dot(o.reshape(b, t, -1).astype(cd),
+                           params["wo"].astype(cd))
 
 
 class GatedMLP(Layer):

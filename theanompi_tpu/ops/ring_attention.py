@@ -120,8 +120,10 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, *, axis: str,
 
 
 def attention_reference(q, k, v, *, causal: bool = False,
-                        scale: Optional[float] = None):
-    """Single-device softmax attention oracle (tests)."""
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """Single-device softmax attention oracle (tests).  ``window`` (with
+    ``causal``): no key further back than ``window - 1``."""
     d = q.shape[-1]
     scale = (1.0 / (d ** 0.5)) if scale is None else scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -129,6 +131,9 @@ def attention_reference(q, k, v, *, causal: bool = False,
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         valid = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            valid &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] \
+                < window
         s = jnp.where(valid[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,

@@ -44,6 +44,8 @@ Round-4, sequence-sharded tokens (``seq_shards > 1``):
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -244,3 +246,193 @@ class MoE(L.Layer):
         comb = (disp * comb_gate[:, :, None]).astype(cd)
         y = jnp.einsum("ecd,nec->nd", ye, comb)
         return y, aux       # aux already global+invariant (pmean'd f/P)
+
+
+class HeldExperts(L.Layer):
+    """One chip's part of a routed feed-forward layer of gated (SwiGLU)
+    experts, told which experts it holds (``held = (first, past)``, a
+    contiguous range of the model's ``n_experts``):
+
+        p = softmax(x W_r) over all n_experts;  the top_k largest chosen,
+        w_e = scale * p_e / (sum of the chosen p)
+        y = Shared(x) + sum over e chosen and held of w_e * Expert_e(x)
+
+    The router keeps its whole width and its ``top_k``; an expert that is
+    chosen and not held adds nothing here (its chip adds it) and its weight
+    stays in the normalisation.  **Nothing is dropped, whatever the
+    routing**: every (token, expert) pair whose expert is held is
+    computed.  The pairs are sorted by expert; the static shape is the
+    worst case, ``N * min(top_k, held)`` rows, walked a sixteenth at a
+    time (gather, grouped products, weighted scatter-add) as far as the
+    last routed pair and no further, so the work follows the rows
+    actually routed and the memory is one stretch's (``_routed_part``).
+    The experts' products are grouped matrix products over the stacked
+    ``[held, d, width]`` weights (``lax.ragged_dot``).  Router in
+    float32 at the highest precision, so that a float32 reference chooses
+    alike; matrix operands in ``compute_dtype``.
+
+    With more than one chip in the expert group this layer would be
+    followed by its exchange (a sum over the group of the routed parts,
+    the shared expert counted once); on one chip it runs without.
+
+    Beside :class:`MoE` and not in its place: that one is the Switch/GShard
+    layer of ``MoETransformerLM`` (2-layer MLP experts, a capacity factor
+    that drops, an auxiliary loss, whole-layer sharding over mesh axes),
+    which its tests and the toy token cell keep.
+
+    Scopes: ``<name>`` around all of it, ``router``, ``experts`` around
+    the grouped products alone, ``shared_expert``."""
+
+    def __init__(self, dim: int, n_experts: int, held, top_k: int,
+                 width: int, shared_width: int = 0, scale: float = 1.0,
+                 w_init=("normal", 0.02), compute_dtype=jnp.bfloat16,
+                 name: str = "moe"):
+        first, past = (int(i) for i in held)
+        assert 0 <= first < past <= n_experts, (held, n_experts)
+        assert 1 <= top_k <= n_experts, (top_k, n_experts)
+        self.dim, self.n_experts, self.width = dim, n_experts, width
+        self.first, self.n_held = first, past - first
+        self.top_k, self.scale = int(top_k), float(scale)
+        self.w_init = w_init
+        self.compute_dtype = compute_dtype
+        self.name = name
+        self.shared = L.GatedMLP(dim, shared_width, w_init=w_init,
+                                 compute_dtype=compute_dtype,
+                                 name="shared_expert") \
+            if shared_width else None
+
+    def init(self, key):
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
+        d, f, h = self.dim, self.width, self.n_held
+        p = {"router": L.init_weight(kr, (d, self.n_experts), self.w_init),
+             "experts": {"wg": L.init_weight(kg, (h, d, f), self.w_init),
+                         "wu": L.init_weight(ku, (h, d, f), self.w_init),
+                         "wd": L.init_weight(kd, (h, f, d), self.w_init)}}
+        if self.shared is not None:
+            p["shared_expert"] = self.shared.init(ks)
+        return p
+
+    def route(self, params, xf):
+        """``[N, d]`` -> the chosen experts ``[N, top_k]`` and their
+        weights, normalised over the chosen and scaled."""
+        with jax.named_scope("router"):
+            logits = jnp.dot(xf.astype(jnp.float32),
+                             params["router"].astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)
+            top, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                    self.top_k)
+            return chosen, self.scale * top / jnp.sum(top, axis=-1,
+                                                      keepdims=True)
+
+    def rows_at_once(self, n_tokens: int) -> int:
+        """Rows of the sorted pairs that go through the experts at once: a
+        sixteenth of the worst case, ``N * min(top_k, held)``."""
+        return max(1, n_tokens * min(self.top_k, self.n_held) // 16)
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        with jax.named_scope(self.name):
+            xf = x.reshape(-1, self.dim)
+            chosen, weights = self.route(params, xf)
+            # pairs (token, choice) by expert held; one not held sorts last
+            local = chosen.reshape(-1) - self.first
+            local = jnp.where((local >= 0) & (local < self.n_held), local,
+                              self.n_held)
+            order = jnp.argsort(local)
+            ends = jnp.cumsum(jnp.sum(
+                local[:, None] == jnp.arange(self.n_held)[None], axis=0,
+                dtype=jnp.int32))
+            y = _routed_part(self.rows_at_once(xf.shape[0]),
+                             params["experts"], xf.astype(self.compute_dtype),
+                             weights, order, ends)
+            if self.shared is not None:
+                y = y + self.shared.apply(params["shared_expert"],
+                                          xf).astype(jnp.float32)
+            return y.reshape(x.shape[:-1] + (self.dim,))
+
+
+# The held pairs' part of a routed layer, ``[N, d]`` float32:
+# ``sum_j weights[n, j] * Expert_{chosen[n, j]}(xf[n])`` over the pairs whose
+# expert is held.  ``order`` sorts the pairs ``n * top_k + j`` by held expert,
+# one not held last; expert ``e``'s rows of that order end at ``ends[e]``.
+# The sorted pairs are walked ``rows`` at a time, as far as the last routed
+# pair and no further (a loop whose trip count is the step's own), forward
+# and backward alike: reverse-mode differentiation cannot transpose such a
+# loop, so the backward pass is written out beside the forward one.  It
+# makes each stretch's products again; nothing but the arguments is kept.
+
+def _stretch(rows, order, ends, top_k, i):
+    """Stretch ``i``: its pairs, their tokens and choices, each expert's
+    number of rows in it, and which rows are pairs at all."""
+    start = i * rows
+    pair = lax.dynamic_slice_in_dim(order, start, rows)
+    upto = jnp.clip(ends - start, 0, rows)
+    return pair // top_k, pair % top_k, jnp.diff(upto, prepend=0), \
+        jnp.arange(rows) < upto[-1]
+
+
+def _expert_rows(sizes, live, experts, xs, w):
+    """``[rows, d]`` float32: row ``r``'s expert (by ``sizes``) applied to
+    ``xs[r]``, times ``w[r]``; nought where ``r`` is no pair.  A grouped
+    product leaves in a row of no group whatever it finds, and so does
+    its transpose: such a row is cut off on the way in and on the way
+    out, which cuts its cotangents off too (on the chip they are not
+    nought: PERF.md section 6, PR 37)."""
+    cd = xs.dtype
+    xs = jnp.where(live[:, None], xs, 0)
+    with jax.named_scope("experts"):
+        g = lax.ragged_dot(xs, experts["wg"].astype(cd), sizes,
+                           preferred_element_type=jnp.float32)
+        u = lax.ragged_dot(xs, experts["wu"].astype(cd), sizes,
+                           preferred_element_type=jnp.float32)
+        ye = lax.ragged_dot((jax.nn.silu(g) * u).astype(cd),
+                            experts["wd"].astype(cd), sizes,
+                            preferred_element_type=jnp.float32)
+    return jnp.where(live[:, None], ye * w[:, None], 0.0)
+
+
+def _stretches(rows, ends):
+    return (ends[-1] + rows - 1) // rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed_part(rows, experts, xf, weights, order, ends):
+    top_k = weights.shape[1]
+
+    def some_rows(i, y):
+        token, choice, sizes, live = _stretch(rows, order, ends, top_k, i)
+        return y.at[token].add(_expert_rows(
+            sizes, live, experts, xf[token], weights[token, choice]))
+
+    return lax.fori_loop(0, _stretches(rows, ends), some_rows,
+                         lax.full_like(xf, 0, jnp.float32))
+
+
+def _routed_part_fwd(rows, experts, xf, weights, order, ends):
+    return _routed_part(rows, experts, xf, weights, order, ends), \
+        (experts, xf, weights, order, ends)
+
+
+def _routed_part_bwd(rows, kept, dy):
+    experts, xf, weights, order, ends = kept
+    top_k = weights.shape[1]
+
+    def some_rows(i, grads):
+        token, choice, sizes, live = _stretch(rows, order, ends, top_k, i)
+        _, back = jax.vjp(functools.partial(_expert_rows, sizes, live),
+                          experts, xf[token], weights[token, choice])
+        d_experts, d_xs, d_w = back(dy[token])
+        g_experts, g_xf, g_weights = grads
+        return (jax.tree.map(jnp.add, g_experts, d_experts),
+                g_xf.at[token].add(d_xs.astype(jnp.float32)),
+                g_weights.at[token, choice].add(d_w))
+
+    zeros = lambda a: lax.full_like(a, 0, jnp.float32)      # noqa: E731
+    g_experts, g_xf, g_weights = lax.fori_loop(
+        0, _stretches(rows, ends), some_rows,
+        (jax.tree.map(zeros, experts), zeros(xf), zeros(weights)))
+    return (jax.tree.map(lambda g, a: g.astype(a.dtype), g_experts, experts),
+            g_xf.astype(xf.dtype), g_weights.astype(weights.dtype),
+            None, None)
+
+
+_routed_part.defvjp(_routed_part_fwd, _routed_part_bwd)

@@ -97,7 +97,10 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "comm.gathered_leaves",
           # a looped model's work a step, written at its first trace
           "model.loop_steps", "model.layer_applications",
-          "model.head_tokens", "model.attn_kernel_applications")
+          "model.head_tokens", "model.attn_kernel_applications",
+          # a routed model's share a step, written at its first trace
+          "model.experts_held", "model.experts_routed",
+          "model.routed_pairs", "model.window_layers", "model.full_layers")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
