@@ -249,18 +249,18 @@ def test_it_trains_through_the_rules_own_path():
 
 @pytest.mark.parametrize("impl, t, want", [
     ("reference", 16,
-     "09c99e59dabc7b026d3cefd60779cb6e8e27d94d1daa75e3a2594e9f43be5683"),
+     "04e3b3a7d4f5b376cdb0762f697bbed77653ab391265be66d90eedb05da3337a"),
     ("flash", 128,
-     "d3971e93d4aa46a67e59cb573f9cb0e5690965cac6ca8cc407d8f703cc1c7f33")])
+     "4499a5849d3fb5c3a001dad4b6c768f7eee3982328dbd057afdc73312a549b17")])
 def test_the_looped_steps_lowering_is_what_it_was(impl, t, want):
     """The looped model's loss and gradient lowered for a TPU at toy size:
-    the StableHLO's hash as commit 6180482 (PR 36) gave it, before
-    ``jax_compat.splash_attention`` took its mask as an argument and
-    ``layers.attend`` left ``MultiHeadAttention._attend`` (PR 37).  A
-    change to what the looped cell shares with another model (``_attend``,
-    ``attend``, ``flash_tiles``, ``splash_attention``, ``rotary``,
-    ``RMSNorm``, ``GatedMLP``) that moves this has changed the looped
-    cell's program: measure that cell, then pin the new hash.  The Pallas
+    the StableHLO's hash since PR 38, whose heads take their loss and
+    gradients from one pass (``layers.weighted_cross_entropy``; PR 37 had
+    left the hashes of commit 6180482, PR 36, as they were).  A change to
+    what the looped cell shares with another model (``attend``,
+    ``flash_tiles``, ``splash_attention``, ``rotary``, ``RMSNorm``,
+    ``GatedMLP``, ``weighted_cross_entropy``) that moves this has changed
+    the looped cell's program: measure that cell, then pin the new hash.  The Pallas
     kernels' serialized bodies are cut out first: they hold the call
     stack's file paths and line numbers, which differ from one checkout
     to the next."""

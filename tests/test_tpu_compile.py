@@ -152,3 +152,50 @@ def test_the_held_experts_compile_at_the_routed_cells_shapes(one_chip_mesh):
     # one stretch of 4,096 rows at a time, never the worst case's 65,536
     assert "[4096,3072]" in text and "[65536," not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+
+
+def test_the_heads_make_three_products_a_block_and_no_backward_loop(
+        one_chip_mesh):
+    """``layers.weighted_cross_entropy`` at the looped cell's head shapes
+    (4 x 8,192 tokens of 2,048, 49,152 classes, blocks of 2,048) with its
+    gradients, inside the step's ``shard_map``: one loop whose body holds
+    the logits, the gradient to the states and the share of the gradient
+    to the weight, and nothing of the head under ``transpose(`` but the
+    three scalings.  The program's temporaries read 1,007.3 MB (reverse
+    mode over a rematerialised block, as the heads were until PR 38,
+    747.3 MB: the states' gradient is held in bfloat16 from the loop to
+    the backward pass, 134 MB, and a block's ``c (softmax - onehot)`` is
+    written out in bfloat16 for the two products that read it, 201 MB)."""
+    mesh = one_chip_mesh
+    m, d, v, blk = 4 * 8192, 2048, 49152, 2048
+
+    def per_worker(w, h, y, c):
+        def loss(w, h, c):
+            with jax.named_scope("exit_head"):
+                return 3.7 * L.weighted_cross_entropy(
+                    w, h, y[0], c, block=blk,
+                    compute_dtype=jnp.bfloat16)[0]
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(
+            loss, argnums=(0, 1, 2))(w[0], h[0], c[0]))
+
+    spec = P("workers")
+    sh = NamedSharding(mesh, spec)
+    args = [jax.ShapeDtypeStruct((1,) + shape, dtype, sharding=sh)
+            for shape, dtype in (((d, v), jnp.float32), ((m, d), jnp.float32),
+                                 ((m,), jnp.int32), ((m,), jnp.float32))]
+    step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=spec))
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    in_the_loop = [line for line in text.splitlines()
+                   if " convolution(" in line
+                   and "jvp(exit_head)/while/body" in line]
+    assert len(in_the_loop) == L.head_logit_products(blk, blk) == 3
+    # the logits and the weight's share come out [2048, 49152] in float32,
+    # the states' gradient is the product that contracts the classes
+    assert sum("= f32[2048,49152]" in line for line in in_the_loop) == 2
+    assert sum("= f32[2048,2048]" in line for line in in_the_loop) == 1
+    assert text.count(" while(") == 1
+    assert "transpose(jvp(exit_head))/while" not in text
+    assert "transpose(jvp(exit_head))/mul" in text      # the scalings
+    assert compiled.memory_analysis().temp_size_in_bytes < 1050 * 10 ** 6

@@ -20,13 +20,16 @@ the exit distribution and the loss are float32; matmul operands are
 
 What a step holds in memory is set here: each layer application is
 rematerialised in the backward pass (``jax.checkpoint``; ``R x n_layer``
-saved residuals and nothing else of the stack), and the heads' losses are
-taken ``head_block`` tokens at a time, so that no ``[tokens, vocab]``
-tensor of a whole step exists.  Scopes: ``ut_loop`` (the stack, all loop
-steps; inside it ``attn``, ``attn_core``, ``mlp``) and ``exit_head``
-(heads, gate, objective).  Counters, at the step's first trace:
+saved residuals and nothing else of the stack), and the heads' loss and
+its gradients come from one pass over the logits, ``head_block`` tokens at
+a time (:func:`layers.weighted_cross_entropy`: the exit distribution is
+made first and handed to it as the tokens' weights), so that no
+``[tokens, vocab]`` tensor of a whole step exists and none is made twice.
+Scopes: ``ut_loop`` (the stack, all loop steps; inside it ``attn``,
+``attn_core``, ``mlp``) and ``exit_head`` (heads, gate, objective).
+Counters, at the step's first trace:
 ``model.loop_steps``, ``model.layer_applications``, ``model.head_tokens``,
-``model.attn_kernel_applications``.
+``model.head_logit_products``, ``model.attn_kernel_applications``.
 """
 
 from __future__ import annotations
@@ -190,41 +193,24 @@ class LoopedLM(ModelBase):
 
     # -- heads, gate, objective ---------------------------------------------------
 
-    def _head_losses(self, params, hs, y):
-        """``hs [R, N, d]``, ``y [N]`` -> each token's cross-entropy and
-        top-1 miss at every loop step, ``[R, N]`` each, ``head_block``
-        tokens at a time with the logits made again in the backward pass."""
-        r, n, d = hs.shape
-        blk = min(self.head_block, n)
-        assert n % blk == 0, (
-            f"{n} tokens a step do not divide into head_block={blk}")
-
-        @jax.checkpoint
-        def block(_, hy):
-            h, yb = hy
-            logits = self._logits(params, h)
-            ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-                logits, yb[:, None], axis=-1)[:, 0]
-            miss = (jnp.argmax(logits, axis=-1) != yb).astype(jnp.float32)
-            return None, (ce, miss)
-
-        _, (ce, miss) = lax.scan(
-            block, None, (hs.reshape(-1, blk, d),
-                          jnp.tile(y, r).reshape(-1, blk)))
-        return ce.reshape(r, n), miss.reshape(r, n)
-
     def exit_losses(self, params, hs, y):
         """``(objective, cross-entropy of each loop step [R], top-1 error of
-        the last, exit distribution [R, N])`` over ``N`` tokens."""
+        the last, exit distribution [R, N])`` over ``N`` tokens.  The exit
+        distribution comes from the gate alone, so it is made first and
+        handed to the head as each token's weight at each loop step."""
+        r, n, d = hs.shape
         with jax.named_scope("exit_head"):
-            ce, miss = self._head_losses(params, hs, y)
             z = jnp.einsum("rnd,d->rn", hs, params["gate"]["w"]) \
                 + params["gate"]["b"]
             p, logp = exit_distribution(z)
+            expected, ce, miss = L.weighted_cross_entropy(
+                params["head"]["w"], hs.reshape(r * n, d), jnp.tile(y, r),
+                (p / n).reshape(r * n), block=self.head_block,
+                compute_dtype=self.cd)
             entropy = -jnp.sum(p * logp, axis=0)
-            cost = jnp.mean(jnp.sum(p * ce, axis=0)
-                            - self.exit_beta * entropy)
-            return cost, jnp.mean(ce, axis=1), jnp.mean(miss[-1]), p
+            cost = expected - self.exit_beta * jnp.mean(entropy)
+            return cost, jnp.mean(ce.reshape(r, n), axis=1), \
+                jnp.mean(miss[-n:]), p
 
     def _count_once(self, rows: int) -> None:
         if not self._counted:
@@ -232,7 +218,10 @@ class LoopedLM(ModelBase):
             r = self.loop_steps
             telemetry.count("model.loop_steps", r)
             telemetry.count("model.layer_applications", r * self.n_layer)
-            telemetry.count("model.head_tokens", r * rows * self.seq_len)
+            tokens = r * rows * self.seq_len
+            telemetry.count("model.head_tokens", tokens)
+            telemetry.count("model.head_logit_products",
+                            L.head_logit_products(tokens, self.head_block))
             telemetry.count("model.attn_kernel_applications", r * sum(
                 b.attn.attn_impl == "flash" for b in self.blocks))
 

@@ -35,13 +35,15 @@ held adds nothing.
 
 The residual stream, the norms, the router and the loss are float32;
 matmul operands are ``compute_dtype``.  Each layer is rematerialised in
-the backward pass (``jax.checkpoint``) and the head's losses are taken
-``head_block`` tokens at a time, as :class:`looped_lm.LoopedLM`'s.
+the backward pass (``jax.checkpoint``); the head's loss and its gradients
+come from one pass over the logits, ``head_block`` tokens at a time
+(:func:`layers.weighted_cross_entropy` with every token's weight ``1/N``).
 Scopes: ``block<i>`` (inside ``attn``, ``attn_core``, ``mlp`` or ``moe``
 with ``router``, ``experts``, ``shared_expert``) and ``head``.  Counters,
 at the step's first trace: ``model.experts_held``,
 ``model.experts_routed``, ``model.routed_pairs``, ``model.window_layers``,
-``model.full_layers``, ``model.attn_kernel_applications``.
+``model.full_layers``, ``model.attn_kernel_applications``,
+``model.head_logit_products``.
 """
 
 from __future__ import annotations
@@ -49,7 +51,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ..parallel.moe import HeldExperts
 from ..utils import telemetry
@@ -223,27 +224,6 @@ class RoutedLM(ModelBase):
         with jax.named_scope("head"):
             return self._logits(params, h), state
 
-    def _head_losses(self, params, h, y):
-        """``h [N, d]``, ``y [N]`` -> each token's cross-entropy and top-1
-        miss, ``head_block`` tokens at a time with the logits made again in
-        the backward pass."""
-        n, d = h.shape
-        blk = min(self.head_block, n)
-        assert n % blk == 0, (
-            f"{n} tokens a step do not divide into head_block={blk}")
-
-        @jax.checkpoint
-        def block(_, hy):
-            logits = self._logits(params, hy[0])
-            ce = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-                logits, hy[1][:, None], axis=-1)[:, 0]
-            miss = (jnp.argmax(logits, axis=-1) != hy[1]).astype(jnp.float32)
-            return None, (ce, miss)
-
-        _, (ce, miss) = lax.scan(block, None, (h.reshape(-1, blk, d),
-                                               y.reshape(-1, blk)))
-        return ce.reshape(n), miss.reshape(n)
-
     def _count_once(self, rows: int) -> None:
         if not self._counted:
             self._counted = True
@@ -260,16 +240,21 @@ class RoutedLM(ModelBase):
             telemetry.count("model.full_layers", len(self.blocks) - windows)
             telemetry.count("model.attn_kernel_applications", sum(
                 b.attn.attn_impl == "flash" for b in self.blocks))
+            telemetry.count("model.head_logit_products", L.head_logit_products(
+                rows * self.seq_len, self.head_block))
 
     def loss_and_metrics(self, params, bn_state, batch, rng, train):
         x, y = batch["x"], batch["y"]
         if train:
             self._count_once(x.shape[0])
         h = self.hidden_state(params, x, train)
+        n = y.size
         with jax.named_scope("head"):
-            ce, miss = self._head_losses(
-                params, h.reshape(-1, self.d_model), y.reshape(-1))
-            return jnp.mean(ce), (jnp.mean(miss), bn_state)
+            cost, _, miss = L.weighted_cross_entropy(
+                params["head"]["w"], h.reshape(n, self.d_model),
+                y.reshape(n), jnp.full((n,), 1.0 / n), block=self.head_block,
+                compute_dtype=self.cd)
+            return cost, (jnp.mean(miss), bn_state)
 
     def val_metrics(self, params, bn_state, batch):
         logits, _ = self.apply_model(params, batch["x"], train=False,

@@ -98,6 +98,8 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           # a looped model's work a step, written at its first trace
           "model.loop_steps", "model.layer_applications",
           "model.head_tokens", "model.attn_kernel_applications",
+          # [block, V] products the traced heads of either make a step
+          "model.head_logit_products",
           # a routed model's share a step, written at its first trace
           "model.experts_held", "model.experts_routed",
           "model.routed_pairs", "model.window_layers", "model.full_layers")
