@@ -27,19 +27,30 @@ def _platforms(monkeypatch, value):
                         property(lambda self: value), raising=False)
 
 
-def test_sets_nothing_when_the_env_var_places_it(monkeypatch, updates):
+NAMES_IN_KEY = (jax_cache._NAMES_IN_KEY, True)
+
+
+def test_sets_no_directory_when_the_env_var_places_it(monkeypatch, updates):
     monkeypatch.setenv(jax_cache.ENV_VAR, "/some/where")
     _platforms(monkeypatch, None)
     assert jax_cache.configure() is None
-    assert updates == []
+    assert updates == [NAMES_IN_KEY]
 
 
-def test_default_is_the_checkout_and_nothing_else(monkeypatch, updates):
+def test_the_names_in_key_option_is_one_this_jax_has():
+    """The option's name is a string here: a jax that renamed it would make
+    ``configure`` raise on the chip, where no test runs."""
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is False
+    assert jax_cache._NAMES_IN_KEY == \
+        "jax_compilation_cache_include_metadata_in_key"
+
+
+def test_default_is_the_checkout_and_no_other_directory(monkeypatch, updates):
     monkeypatch.delenv(jax_cache.ENV_VAR, raising=False)
     _platforms(monkeypatch, None)
     want = os.path.join(REPO, ".jax_cache")
     assert jax_cache.configure() == want
-    assert updates == [(jax_cache._OPTION, want)]
+    assert updates == [NAMES_IN_KEY, (jax_cache._OPTION, want)]
     # fixed: no tempfile, pid or clock in it
     assert jax_cache.configure() == want
 

@@ -65,13 +65,13 @@ def test_nesting_reentrancy_parent_and_self_time():
 
 def test_an_exception_closes_the_span_and_unwinds_the_stack():
     with pytest.raises(ValueError):
-        with telemetry.span("load.dequeue"):
-            with telemetry.span("load.result"):
+        with telemetry.span("train.call"):
+            with telemetry.span("train.args"):
                 raise ValueError("x")
     with telemetry.span("print"):
         pass
     assert [(r[0], r[4]) for r in telemetry.spans()] == [
-        ("load.result", "load.dequeue"), ("load.dequeue", None),
+        ("train.args", "train.call"), ("train.call", None),
         ("print", None)]
 
 
@@ -163,7 +163,7 @@ def test_a_span_costs_microseconds_and_needs_no_registry():
 def test_vocabulary_is_one_tuple_beside_the_phases():
     assert not set(telemetry.SPANS) & set(telemetry.PHASES)
     assert set(telemetry.COMPILE_EVENTS.values()) <= set(telemetry.SPANS)
-    assert len(set(telemetry.SPANS)) == len(telemetry.SPANS) == 14
+    assert len(set(telemetry.SPANS)) == len(telemetry.SPANS) == 13
     assert len(set(telemetry.COUNTS)) == len(telemetry.COUNTS) == 17
 
 
@@ -260,13 +260,14 @@ def test_every_producer_leaves_the_same_input_spans(producer):
         loader.set_window(2, put)
     loader.shuffle_data(0)
     try:
-        seen = []
+        seen, returned = [], {}
         for i in range(1, 7):
             if producer == "window":
                 loader.next_train_window(2 * i)
             else:
                 loader.next_train_batch(i)
             seen.append(loader.last_batch_id)
+            returned[loader.last_batch_id] = time.time_ns()
     finally:
         loader._shutdown()
     assert seen == sorted(set(seen)) and len(seen) == 6
@@ -274,7 +275,6 @@ def test_every_producer_leaves_the_same_input_spans(producer):
     names = {r[0] for r in got}
     assert {"input.plan", "input.materialize", "input.device_put",
             "input.enqueue", "load.dequeue"} <= names
-    assert ("load.result" in names) == (producer == "pooled")
     classes = devprof.thread_classes(got)
     main = threading.get_ident()
     assert classes[main] == "main"
@@ -288,9 +288,10 @@ def test_every_producer_leaves_the_same_input_spans(producer):
                 "input.enqueue", "load.dequeue"} <= {r[0] for r in mine}
         put_end = max(r[3] for r in mine if r[0] == "input.device_put")
         deq = next(r for r in mine if r[0] == "load.dequeue")
-        # in hand after the dequeue, or after the future's result
-        in_hand = max(r[3] for r in mine if r[0].startswith("load."))
-        assert deq[1] == main and put_end <= in_hand
+        # in hand once the loader's call returns: after the dequeue and,
+        # where the pool hands over a future, after its result
+        assert deq[1] == main and deq[3] <= returned[bid]
+        assert put_end <= returned[bid]
     tot = telemetry.totals()
     assert tot["input.dequeues"][0] == 6
     assert 0 <= tot.get("input.unready_dequeues", (0, 0))[0] <= 6
@@ -336,13 +337,13 @@ def test_one_batch_id_runs_from_the_plan_to_the_step():
         assert {"input.plan", "input.materialize", "input.device_put",
                 "input.enqueue", "load.dequeue", "train.args",
                 "train.call", "train.reduce"} <= set(mine)
-        # in hand once dequeued and, a future, once its result is there
-        in_hand = max(mine[n][3] for n in ("load.dequeue", "load.result")
-                      if n in mine)
+        # in hand (dequeued and, a future, its result there) before the
+        # step's arguments are made
         assert mine["input.plan"][2] <= mine["input.materialize"][2] \
-            <= mine["input.device_put"][3] <= in_hand \
+            <= mine["input.device_put"][3] \
             <= mine["train.args"][2] <= mine["train.call"][2] \
             <= mine["train.reduce"][3]
+        assert mine["load.dequeue"][2] <= mine["train.args"][2]
         assert mine["train.call"][4] == "train"
         assert mine["load.dequeue"][4] == "load"
         assert len({mine["input.plan"][1], mine["input.materialize"][1],

@@ -5,6 +5,7 @@ only one process may hold the TPU's library, and every xdist worker imports
 this file.  Keep such tests in this one file."""
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -199,3 +200,74 @@ def test_the_heads_make_three_products_a_block_and_no_backward_loop(
     assert "transpose(jvp(exit_head))/while" not in text
     assert "transpose(jvp(exit_head))/mul" in text      # the scalings
     assert compiled.memory_analysis().temp_size_in_bytes < 1050 * 10 ** 6
+
+
+def _without_source_names(text):
+    """The compiled module's text less what names its source: every
+    ``metadata={...}`` and the four tables their ``stack_frame_id``s point
+    into."""
+    text = re.sub(r",?\s*metadata=\{[^{}]*\}", "", text)
+    for table in ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames"):
+        text = re.sub(r"\n%s\n.*?\n\n" % table, "\n\n", text, count=1,
+                      flags=re.S)
+    return text
+
+
+def test_the_scopes_change_no_instruction_of_the_train_program(
+        topo, monkeypatch):
+    """The BSP train step of a small ``Sequential`` model (the CIFAR-10
+    stack: convolutions, pools before their ReLUs, dropout, two FC layers)
+    compiled for the described 2x2 mesh, as the program writes it and with
+    ``jax.named_scope`` silenced: less its metadata the text is the same,
+    so ``exchange``, ``update`` and the layers' keys cost the chip nothing.
+    The scopes have no switch in the program; the test takes them out."""
+    import contextlib
+
+    from theanompi_tpu.models.cifar10 import Cifar10_model
+    from theanompi_tpu.parallel import steps
+    from theanompi_tpu.parallel.exchanger import BSP_Exchanger
+
+    mesh = Mesh(np.array(topo.devices), ("workers",))
+    n = mesh.shape["workers"]
+    # the CPU backend's compiler option means nothing to the chip's
+    monkeypatch.setattr(steps, "_keep_collectives_apart", lambda: None)
+    model = Cifar10_model({
+        "mesh": mesh, "size": n, "rank": 0, "verbose": False,
+        "batch_size": 8, "n_class": 10, "synthetic_batches": 1,
+        "synthetic_train": 64, "synthetic_val": 32})
+    exchanger = BSP_Exchanger(model.config)
+    exchanger.prepare(mesh, model)
+    exchanger.gather_min_bytes = 0      # fc1's gradient as gathered operands
+    rows = NamedSharding(mesh, P("workers"))
+    whole = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype,
+                                       sharding=rows),
+        jax.eval_shape(lambda p: {
+            "params": p, "opt_state": model.opt.init(p),
+            "bn_state": model.bn_state,
+            "extra": exchanger.extra_state_template()}, model.params))
+    batch = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows),
+        model._peek_batch_aval())
+    scalars = [jax.ShapeDtypeStruct((), dtype, sharding=whole) for dtype in
+               (jnp.float32, jax.random.key(0).dtype, jnp.int32)]
+
+    def compiled_text():
+        step = steps.build_train_step(mesh, model, exchanger)
+        return step.lower(state, batch, *scalars).compile().as_text()
+
+    scoped = compiled_text()
+    for scope in ("/exchange/", "/update/", "jvp(conv1)/", "(jvp(fc1))/"):
+        assert scope in scoped, scope
+    assert "all-reduce" in scoped and "all-gather" in scoped
+    # the reshape that boxes the new parameters and moments is the update's:
+    # this compiler names an update's fusion after that root
+    assert "/update/broadcast_in_dim" in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled_text()
+    assert "/exchange/" not in bare and "/update/" not in bare
+    assert "jvp(conv1)" not in bare
+    assert _without_source_names(scoped) == _without_source_names(bare)

@@ -64,7 +64,7 @@ class PrefetchLoader:
     ring — ``input.plan`` and ``input.enqueue`` (the blocking ``q.put``) on
     the producer thread, ``input.materialize`` and ``input.device_put`` on
     whichever thread does the work (the pool's, when there is one) — and
-    the consumer writes ``load.dequeue`` / ``load.result``.  All carry the
+    the consumer writes ``load.dequeue``.  All carry the
     queue item's id (``last_batch_id`` after a dequeue), so one batch can
     be followed from its plan to the step that trains on it.
     ``input.device_put`` times the host call only: ``jax.device_put``
@@ -215,8 +215,7 @@ class PrefetchLoader:
             return self._maybe_put(self._data.next_train_batch(count))
         batch, cursor = self._dequeue()
         if hasattr(batch, "result"):     # pooled producer: an ordered future
-            with telemetry.span("load.result", self.last_batch_id):
-                batch = batch.result()   # (re-raises materialize errors)
+            batch = batch.result()       # (re-raises materialize errors)
         # commit the cursor only AFTER the batch is in hand — a failed
         # materialize must not mark its batch consumed
         self._consumed_cursor = cursor

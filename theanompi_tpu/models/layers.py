@@ -159,19 +159,22 @@ class Sequential:
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
-            if i in self._pool_first:
-                x = layer.pre_activation(params[k], x)
-                continue
-            y = layer.apply(params.get(k), x, train=train, rng=sub,
-                            state=state.get(k))
-            if layer.has_state:
-                x, st = y
-                if st is not None:
-                    new_state[k] = st
-            else:
-                x = y if not isinstance(y, tuple) else y[0]
-            if i - 1 in self._pool_first:
-                x = jax.nn.relu(x)
+            # the layer's key names its instructions in the compiled
+            # program (`.../conv3_1/conv_general_dilated`)
+            with jax.named_scope(k):
+                if i in self._pool_first:
+                    x = layer.pre_activation(params[k], x)
+                    continue
+                y = layer.apply(params.get(k), x, train=train, rng=sub,
+                                state=state.get(k))
+                if layer.has_state:
+                    x, st = y
+                    if st is not None:
+                        new_state[k] = st
+                else:
+                    x = y if not isinstance(y, tuple) else y[0]
+                if i - 1 in self._pool_first:
+                    x = jax.nn.relu(x)
         return x, new_state
 
 
@@ -364,7 +367,8 @@ def _dot_gathered_fwd(x, w, axis):
 def _dot_gathered_bwd(axis, res, dy):
     x, w = res
     dx = jnp.dot(dy, w.astype(dy.dtype).T)
-    rows = [jax.lax.all_gather(a, axis, tiled=True) for a in (x, dy)]
+    with jax.named_scope("exchange"):
+        rows = [jax.lax.all_gather(a, axis, tiled=True) for a in (x, dy)]
     dw = jax.lax.dot_general(*rows, (((0,), (0,)), ((), ())),
                              preferred_element_type=w.dtype)
     return dx, dw

@@ -412,10 +412,16 @@ class Exchanger:
                     count, summed=None):
         """Default: purely local optimizer step (async rules train locally
         between exchanges)."""
-        opt = self.model.opt
-        params, opt_state = opt.update(self._clip_grads(grads), opt_state,
-                                       params, lr)
+        params, opt_state = self._update(params, opt_state, grads, lr)
         return params, opt_state, extra
+
+    def _update(self, params, opt_state, grads, lr):
+        """The optimizer's work on the gradient it consumes, under the
+        scope ``update``: the device's time in it is ``update_ms``
+        (docs/design.md's scope table)."""
+        with jax.named_scope("update"):
+            return self.model.opt.update(self._clip_grads(grads), opt_state,
+                                         params, lr)
 
     def sync_bn(self, bn_state, *, axis, size):
         """How BatchNorm running stats relate across workers.  Async rules
@@ -678,14 +684,15 @@ class BSP_Exchanger(Exchanger):
         if self.mode == "grads":
             strat_state = extra.get("strat", ())
             self._count_wire(grads, summed or {})
-            grads, strat_state = self._strat_call(
-                grads, strat_state, axis=axis, size=size, summed=summed)
+            # the wire, whatever opcodes the compiler gives it: the
+            # device's time under this scope is `exchange_scope_ms`
+            with jax.named_scope("exchange"):
+                grads, strat_state = self._strat_call(
+                    grads, strat_state, axis=axis, size=size, summed=summed)
+                grads = self._restore_replication(grads)
             if "strat" in extra:
                 extra = dict(extra, strat=strat_state)
-            grads = self._restore_replication(grads)
-        opt = self.model.opt
-        params, opt_state = opt.update(self._clip_grads(grads), opt_state,
-                                       params, lr)
+        params, opt_state = self._update(params, opt_state, grads, lr)
         return params, opt_state, extra
 
     def _restore_replication(self, grads):
@@ -711,7 +718,8 @@ class BSP_Exchanger(Exchanger):
     def sync_bn(self, bn_state, *, axis, size):
         # Keep BSP replicas bit-identical: running stats are averaged every
         # step (cheap — BN state is tiny next to params).
-        return jax.tree.map(lambda x: lax.pmean(x, axis), bn_state)
+        with jax.named_scope("exchange"):
+            return jax.tree.map(lambda x: lax.pmean(x, axis), bn_state)
 
     def numerics_extra(self, params, extra, axis):
         out = {}

@@ -332,20 +332,28 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
         local_rng = jax.random.fold_in(jax.random.fold_in(rng, ridx), count)
 
         def loss_fn(ch, bn, b, r, train):
-            return model.loss_and_metrics(fsdp.gather_params(ch, axis),
-                                          bn, b, r, train)
+            # the gather and its transpose, the psum_scatter, are the wire
+            with jax.named_scope("exchange"):
+                full = fsdp.gather_params(ch, axis)
+            return model.loss_and_metrics(full, bn, b, r, train)
 
         cost, err, g_chunk, new_bn = _accumulate_grads(
             loss_fn, chunk, bn_state, batch, local_rng, n_subb)
-        g_chunk = g_chunk * (1.0 / n)          # transpose summed; BSP means
-        g_chunk = fsdp.clip_chunk(
-            g_chunk, float(model.config.get("grad_clip", 0.0) or 0.0), axis)
-        new_chunk, new_opt = model.opt.update(g_chunk, opt_state, chunk, lr)
+        with jax.named_scope("update"):
+            g_chunk = g_chunk * (1.0 / n)      # transpose summed; BSP means
+            g_chunk = fsdp.clip_chunk(
+                g_chunk, float(model.config.get("grad_clip", 0.0) or 0.0),
+                axis)
+            new_chunk, new_opt = model.opt.update(g_chunk, opt_state, chunk,
+                                                  lr)
+            # the compiler names an update's fusion after its root, the
+            # reshape that boxes the new state: inside the scope with it
+            new_chunk, new_opt = box(new_chunk), box(new_opt)
         new_bn = _revary_bn(exchanger.sync_bn(new_bn, axis=axis, size=n),
                             axis)
         new_state = {
-            "params": box(new_chunk),
-            "opt_state": box(new_opt),
+            "params": new_chunk,
+            "opt_state": new_opt,
             "bn_state": box(new_bn),
             "extra": state["extra"],
         }
@@ -381,8 +389,9 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
             count=count, summed=summed)
         pu = getattr(model, "postprocess_update", None)
         if pu is not None:
-            new_params, new_opt = pu(params, opt_state, new_params, new_opt,
-                                     count)
+            with jax.named_scope("update"):
+                new_params, new_opt = pu(params, opt_state, new_params,
+                                         new_opt, count)
         # numerics ingredients (§25): the already-live old/new params,
         # grads and extra — handed back for the cadence-gated sample at
         # the per_worker level.  Pure reads; None keeps this path inert.
@@ -393,13 +402,16 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
                 lambda p, g: g * (1.0 / n)
                 if jax.tree_util.keystr(p) in summed else g, grads)
         ing = None if nx is None else (params, new_params, grads, extra)
-        params, opt_state = new_params, new_opt
         new_bn = _revary_bn(exchanger.sync_bn(new_bn, axis=axis, size=n),
                             axis)
 
+        # the compiler names an update's fusion after its root, the reshape
+        # that boxes the new state: that reshape is the update's too
+        with jax.named_scope("update"):
+            new_params, new_opt = box(new_params), box(new_opt)
         new_state = {
-            "params": box(params),
-            "opt_state": box(opt_state),
+            "params": new_params,
+            "opt_state": new_opt,
             "bn_state": box(new_bn),
             "extra": box(extra),
         }

@@ -19,6 +19,13 @@ from typing import Optional
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 _OPTION = "jax_compilation_cache_dir"
+# JAX's key leaves an instruction's metadata out by default, so a program
+# that differs from a cached one in its names alone (a ``jax.named_scope``
+# added, a layer renamed) loads that executable, and with it that compile's
+# ``op_name``s: the text the benchmark's scope join reads then names nothing
+# the program wrote (PERF.md section 6, PR 39).  The names are part of what
+# a cached executable holds here, so they are part of its key.
+_NAMES_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
 
 # <checkout>/.jax_cache, from this file's own location
 # (<checkout>/theanompi_tpu/utils/jax_cache.py); listed in .gitignore
@@ -31,14 +38,16 @@ def configure() -> Optional[str]:
     """Point JAX's persistent compilation cache at a fixed directory.
 
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads it itself and this
-    sets nothing (returns ``None``); likewise in a process whose platform
-    is pinned to ``cpu``.  Otherwise the cache goes to :data:`DEFAULT_DIR`
-    and that path is returned.
+    sets no directory (returns ``None``); otherwise the cache goes to
+    :data:`DEFAULT_DIR` and that path is returned.  Either way an entry's
+    key holds the program's names (``_NAMES_IN_KEY``).  A process whose
+    platform is pinned to ``cpu`` is left alone.
     """
-    if os.environ.get(ENV_VAR):
-        return None
     import jax
     if jax.config.jax_platforms == "cpu":
+        return None
+    jax.config.update(_NAMES_IN_KEY, True)
+    if os.environ.get(ENV_VAR):
         return None
     jax.config.update(_OPTION, DEFAULT_DIR)
     return DEFAULT_DIR

@@ -82,7 +82,7 @@ FLIGHT_EVENTS = 256          # ring-buffer length (events, not bytes)
 # tpulint schema-drift checker guards both directions.  PERF.md §3 says
 # which metric or operator reading each one feeds.
 SPANS = (
-    "load.dequeue", "load.result",                 # consumer, inside `load`
+    "load.dequeue",                                # consumer, inside `load`
     "train.args", "train.call", "train.reduce",    # inside `train`
     "exchange", "print",                           # main thread
     "input.plan", "input.enqueue",                 # producer thread
