@@ -3,6 +3,7 @@ reference (logits, loss, every leaf's gradient); its attention against a
 dense masked softmax; its expert layer's shares against the uncut layer,
 and at both extremes of routing (nothing is dropped)."""
 
+import functools
 import math
 import os
 import sys
@@ -21,6 +22,7 @@ from benchmarks.reference.check import by_path  # noqa: E402
 from theanompi_tpu.models import layers as L  # noqa: E402
 from theanompi_tpu.models.routed_lm import (RoutedLM,  # noqa: E402
                                             rotary_frequencies)
+from theanompi_tpu.parallel import moe  # noqa: E402
 from theanompi_tpu.parallel.moe import HeldExperts  # noqa: E402
 from theanompi_tpu.utils import telemetry  # noqa: E402
 
@@ -141,8 +143,9 @@ def test_bfloat16_is_inside_the_training_checks_limits(params, tokens):
 def test_it_trains_through_the_rule_and_counts_once():
     from theanompi_tpu import BSP
     names = ("model.experts_held", "model.experts_routed",
-             "model.routed_pairs", "model.window_layers",
-             "model.full_layers", "model.attn_kernel_applications")
+             "model.routed_pairs", "model.routed_rows_at_once",
+             "model.window_layers", "model.full_layers",
+             "model.attn_kernel_applications")
     assert set(names) <= set(telemetry.COUNTS)
     before = {k: telemetry.totals().get(k, (0, 0))[0] for k in names}
     rule = BSP()
@@ -154,9 +157,12 @@ def test_it_trains_through_the_rule_and_counts_once():
     assert math.isfinite(rec.epoch_records[-1]["val_cost"])
     after = {k: telemetry.totals()[k][0] - before[k] for k in names}
     # three routed layers of 4 of 16 experts; 2 rows x 32 tokens x 4 a
-    # token in each; two window layers, two full ones; no kernel here
+    # token in each, of which a quarter is expected here: the 64 pairs a
+    # layer overflow its first stretch of 16 rows, so the walks' loops run;
+    # two window layers, two full ones; no kernel here
     assert after == {"model.experts_held": 12, "model.experts_routed": 48,
                      "model.routed_pairs": 3 * 2 * 32 * 4,
+                     "model.routed_rows_at_once": 3 * (2 * 32 * 4 // 16),
                      "model.window_layers": 2, "model.full_layers": 2,
                      "model.attn_kernel_applications": 0}
 
@@ -167,8 +173,10 @@ def test_the_scopes_reach_the_lowering(model, params, tokens):
         lambda p: objective(model, p, x, y))(p)).lower(params).as_text(
             debug_info=True)
     for scope in ("jvp(block0)/mlp/", "jvp(block3)/moe/", "attn/attn_core/",
-                  "moe/router/", "moe/while/body/experts",
-                  "moe/while/body/transpose(jvp(experts))/",
+                  "moe/router/", "jvp(block3)/moe/experts/",
+                  "jvp(block3)/moe/while/body/experts/",
+                  "checkpoint/moe/experts/",
+                  "checkpoint/moe/while/body/experts/",
                   "moe/shared_expert/", "jvp(head)/"):
         assert scope in text, scope
 
@@ -311,7 +319,7 @@ def reference_layer(p, x, first=0):
 
 
 @pytest.mark.parametrize("cuts", [(0, 12), (0, 4, 8, 12), (0, 1, 6, 7, 12),
-                                  (0, 3, 6, 9, 12)])
+                                  (0, 3, 6, 9, 12), (0, 2, 12), (0, 11, 12)])
 def test_the_expert_shares_add_up_to_the_uncut_layer(cuts):
     """For every range of a partition of the experts: the routed parts of
     all shares and the shared expert counted once are the uncut
@@ -373,50 +381,183 @@ def test_the_head_shares_add_up_to_the_uncut_attention(n_q, n_kv, cuts):
     np.testing.assert_allclose(want, ref, rtol=1e-4, atol=1e-5)
 
 
-def steer(params, x, experts):
+def steer(params, x, experts, first=None, others=None):
     """Router weights under which every token of ``x`` chooses exactly
-    ``experts`` (its ``K`` largest logits by a wide margin)."""
-    bias = np.full((E,), -50.0, np.float32)
-    bias[list(experts)] = 50.0 + np.arange(len(experts))
-    # logits = x W: make W's columns read a constant-one feature
-    xs = jnp.concatenate([x[..., :-1], jnp.ones_like(x[..., :1])], axis=-1)
-    router = jnp.zeros((D, E)).at[-1].set(bias) \
-        + 0.01 * params["router"].at[-1].set(0.0)
+    ``experts`` (its ``K`` largest logits by a wide margin); with ``first``
+    only the first so many tokens do, and the rest choose ``others``."""
+    def bias(chosen):
+        b = np.full((E,), -50.0, np.float32)
+        b[list(chosen)] = 50.0 + np.arange(len(chosen))
+        return b
+
+    # logits = x W: W's last two rows read a feature that is one in the
+    # first tokens and one that is one in the rest
+    n = x.shape[0] * x.shape[1]
+    here = (np.arange(n) < (n if first is None else first)).astype(
+        np.float32).reshape(x.shape[:-1] + (1,))
+    xs = jnp.concatenate([x[..., :-2], here, 1 - here], axis=-1)
+    router = jnp.zeros((D, E)).at[-2].set(bias(experts)).at[-1].set(
+        bias(experts if others is None else others)) \
+        + 0.01 * params["router"].at[-2:].set(0.0)
     return dict(params, router=router), xs
 
 
-@pytest.mark.parametrize("held, chosen, rows", [
-    ((2, 7), (2, 3, 4, 5, 6), 5),       # every token chooses the 5 held
-    ((2, 7), (0, 1, 7, 8, 9), 0),       # no token chooses a held one
-    ((0, 3), (0, 1, 2, 10, 11), 3)])    # all held, and two absent ones
-def test_nothing_is_dropped_at_either_extreme_of_routing(held, chosen,
-                                                         rows):
+NOT_HELD = (0, 1, 7, 8, 9)      # what a token steered away from (2, 7) chooses
+# held, what the first tokens choose, how many they are (None: all 48), the
+# pairs that gives; a stretch of (2, 7) is 48 * 5 // 16 = 15 rows
+ROUTINGS = [
+    pytest.param((2, 7), (2, 3, 4, 5, 6), None, 240, id="worst-case"),
+    pytest.param((2, 7), NOT_HELD, None, 0, id="no-pair"),
+    pytest.param((0, 3), (0, 1, 2, 10, 11), None, 144, id="two-absent"),
+    pytest.param((2, 7), (2, 3, 4, 5, 6), 2, 10, id="part-of-a-stretch"),
+    pytest.param((2, 7), (2, 3, 4, 5, 6), 3, 15, id="one-stretch-full"),
+    pytest.param((2, 7), (2, 3, 4, 5, 6), 9, 45, id="three-stretches"),
+    pytest.param((2, 7), (1, 2, 3, 8, 9), 20, 40, id="a-third-in-part")]
+
+
+def steered_share(held, chosen, first, shared):
+    """A share of the whole layer and tokens steered as a row of
+    ``ROUTINGS`` says."""
+    _, params = whole_layer(1)
+    x = jax.random.normal(jax.random.key(9), (2, 24, D), F32)
+    params, x = steer(params, x, chosen, first, NOT_HELD)
+    layer, p = share_of(params, *held, shared=shared)
+    return layer, p, x
+
+
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared-expert", "no-shared-expert"])
+@pytest.mark.parametrize("held, chosen, first, pairs", ROUTINGS)
+def test_nothing_is_dropped_at_either_extreme_of_routing(held, chosen, first,
+                                                         pairs, shared):
     """With the router set so that every token chooses the held experts
     the buffer is full to its worst case, ``N * min(K, held)`` rows, and
     the layer is still the reference; a capacity-style drop would fail
-    it.  With none chosen the routed part is nought."""
-    _, params = whole_layer(1)
-    x = jax.random.normal(jax.random.key(9), (2, 24, D), F32)
-    params, x = steer(params, x, chosen)
-    layer, p = share_of(params, *held)
+    it.  With none chosen the routed part is nought (the walk's first
+    stretch runs with every row cut off).  Between them: pairs that fill
+    a part of the first stretch, all of it and no more (the walk's loop
+    does not run), three stretches, and a third one in part.  Value and
+    every gradient (the experts', the router's through the weights, the
+    shared expert's through what the routed part is added into, the
+    input's) are the reference's."""
+    layer, p, x = steered_share(held, chosen, first, shared)
+    stretch = layer.rows_at_once(48)
+    assert 16 * stretch == 48 * min(K, held[1] - held[0]) >= pairs
+    ref_p = p if shared else dict(p, shared_expert=jax.tree.map(
+        jnp.zeros_like, whole_layer(1)[1]["shared_expert"]))
     with jax.default_matmul_precision("highest"):
         top, _ = layer.route(p, x.reshape(-1, D))
-        assert set(np.unique(np.asarray(top))) == set(chosen)
-        got = layer.apply(p, x)
-        want = reference_layer(p, x, held[0])
-        shared = laguna.gated_mlp(p["shared_expert"],
-                                  x.reshape(-1, D)).reshape(x.shape)
-        grads = jax.grad(lambda p: jnp.sum(layer.apply(p, x) ** 2))(p)
-        ref = jax.grad(lambda p: jnp.sum(reference_layer(
-            p, x, held[0]) ** 2))(p)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
-    routed = float(jnp.max(jnp.abs(got - shared)))
-    assert (routed > 0.05) if rows else (routed == 0.0)
-    assert rows * 48 <= 16 * layer.rows_at_once(48) \
-        == 48 * min(K, held[1] - held[0])
-    for k, g in by_path(ref).items():
-        np.testing.assert_allclose(by_path(grads)[k], g, rtol=2e-3,
-                                   atol=1e-5, err_msg=k)
+        local = np.asarray(top) - held[0]
+        assert int(((local >= 0) & (local < layer.n_held)).sum()) == pairs
+        (got, y), grads = jax.value_and_grad(
+            lambda p, x: (lambda y: (jnp.sum(y ** 2), y))(layer.apply(p, x)),
+            (0, 1), has_aux=True)(p, x)
+        want, ref = jax.value_and_grad(
+            lambda p, x: jnp.sum(reference_layer(p, x, held[0]) ** 2),
+            (0, 1))(ref_p, x)
+        base = laguna.gated_mlp(ref_p["shared_expert"],
+                                x.reshape(-1, D)).reshape(x.shape)
+    np.testing.assert_allclose(y, reference_layer(ref_p, x, held[0]),
+                               rtol=1e-4, atol=1e-5)
+    routed = float(jnp.max(jnp.abs(y - base)))
+    assert (routed > 0.05) if pairs else (routed == 0.0)
+    assert float(got) == pytest.approx(float(want), rel=1e-4)
+    got_leaves, ref_leaves = by_path(grads), by_path(ref)
+    for k in got_leaves:
+        np.testing.assert_allclose(got_leaves[k], ref_leaves[k], rtol=2e-3,
+                                   atol=1e-5, err_msg=str(k))
+    if not pairs:
+        assert not float(jnp.max(jnp.abs(grads[0]["experts"]["wd"])))
+
+
+# The walk as it was until PR 40, kept here to hold the new one to it: one
+# loop from stretch 0 into a zero array, the shared expert's result added
+# behind it; the backward a loop into zero carries of every gradient's
+# shape, each stretch's cotangents from ``jax.vjp`` of the forward's rows.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def parent_routed_part(rows, experts, xf, weights, order, ends):
+    top_k = weights.shape[1]
+
+    def some_rows(i, y):
+        token, choice, sizes, live = moe._stretch(rows, order, ends, top_k, i)
+        return y.at[token].add(moe._expert_rows(
+            sizes, live, experts, xf[token], weights[token, choice]))
+
+    return jax.lax.fori_loop(0, moe._stretches(rows, ends), some_rows,
+                             jnp.zeros(xf.shape, F32))
+
+
+def parent_fwd(rows, experts, xf, weights, order, ends):
+    return parent_routed_part(rows, experts, xf, weights, order, ends), \
+        (experts, xf, weights, order, ends)
+
+
+def parent_bwd(rows, kept, dy):
+    experts, xf, weights, order, ends = kept
+    top_k = weights.shape[1]
+
+    def some_rows(i, grads):
+        token, choice, sizes, live = moe._stretch(rows, order, ends, top_k, i)
+        _, back = jax.vjp(functools.partial(moe._expert_rows, sizes, live),
+                          experts, xf[token], weights[token, choice])
+        d_experts, d_xs, d_w = back(dy[token])
+        g_experts, g_xf, g_weights = grads
+        return (jax.tree.map(jnp.add, g_experts, d_experts),
+                g_xf.at[token].add(d_xs.astype(F32)),
+                g_weights.at[token, choice].add(d_w))
+
+    zeros = lambda a: jnp.zeros(a.shape, F32)               # noqa: E731
+    g_experts, g_xf, g_weights = jax.lax.fori_loop(
+        0, moe._stretches(rows, ends), some_rows,
+        (jax.tree.map(zeros, experts), zeros(xf), zeros(weights)))
+    return g_experts, g_xf, g_weights, None, None
+
+
+parent_routed_part.defvjp(parent_fwd, parent_bwd)
+
+
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared-expert", "no-shared-expert"])
+@pytest.mark.parametrize("held, chosen, first, pairs", ROUTINGS)
+def test_the_walk_is_the_parents_in_float32(held, chosen, first, pairs,
+                                            shared):
+    """Float32 operands, the routing given: the straight-line stretch, the
+    loop behind it and the backward rule written out give the parent's
+    value and its gradients to the experts, the tokens, the weights and
+    what the routed part is added into, each to 1e-6 of its norm (a
+    weight's gradient sums 16 products where the parent's summed 32,
+    nothing else differs but the order of the sums)."""
+    layer, p, x = steered_share(held, chosen, first, shared)
+    xf = x.reshape(-1, D)
+    chosen, weights = layer.route(p, xf)
+    local = chosen.reshape(-1) - layer.first
+    local = jnp.where((local >= 0) & (local < layer.n_held), local,
+                      layer.n_held)
+    order = jnp.argsort(local)
+    ends = jnp.cumsum(jnp.sum(local[:, None] == jnp.arange(layer.n_held),
+                              axis=0, dtype=jnp.int32))
+    assert int(ends[-1]) == pairs
+    base = layer.shared.apply(p["shared_expert"], xf) if shared \
+        else jnp.zeros_like(xf)
+    rows = layer.rows_at_once(48)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(moe._routed_part(
+                rows, *a[:3], order, ends, a[3]) ** 2), (0, 1, 2, 3)))(
+                    p["experts"], xf, weights, base)
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum((parent_routed_part(
+                rows, *a[:3], order, ends) + a[3]) ** 2), (0, 1, 2, 3)))(
+                    p["experts"], xf, weights, base)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-6)
+    got_leaves, want_leaves = by_path(got[1]), by_path(want[1])
+    assert set(got_leaves) == set(want_leaves) and len(want_leaves) == 6
+    for k, g in want_leaves.items():
+        norm = float(jnp.linalg.norm(g))
+        assert float(jnp.linalg.norm(got_leaves[k] - g)) <= 1e-6 * norm, k
+        # with no pair only the cotangent handed through is not nought
+        assert (norm > 0) == bool(pairs or (shared and k == "3")), k
 
 
 def test_a_weight_not_held_stays_in_the_normalisation():
@@ -435,45 +576,45 @@ def test_a_weight_not_held_stays_in_the_normalisation():
     assert (part < 2.5).any()
 
 
-def test_rows_of_no_group_are_cut_off_both_ways(monkeypatch):
+@pytest.mark.parametrize("first", [None, 2], ids=["the-loops-last-stretch",
+                                                   "the-straight-line-one"])
+def test_rows_of_no_group_are_cut_off_both_ways(monkeypatch, first):
     """On the chip a grouped product leaves in a row outside every group
     whatever it finds there, forward and transposed (the CPU's leaves
-    nought, which hides it).  With a product planted that leaves 1e30
-    there in both directions, the layer and its gradients are still the
-    reference's."""
+    nought, which hides it).  With products planted that leave NaN there
+    (the forward's three, the backward rule's two made again and its three
+    by a transposed matrix) and weight-gradient products planted that add
+    in whatever both operands hold there, the layer and its gradients are
+    still the reference's: in the last stretch of the loop, and in the
+    straight-line stretch where the pairs fill a part of it."""
     from jax import lax
 
-    real = lax.ragged_dot
+    rows_by_matrix, rows_by_rows = lax.ragged_dot, lax.ragged_dot_general
 
-    @jax.custom_vjp
-    def planted(lhs, rhs, sizes):
-        inside = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
-        return jnp.where(inside[:, None], real(
-            lhs, rhs, sizes, preferred_element_type=F32), 1e30)
+    def planted(lhs, rhs, group_sizes, **_):
+        inside = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(inside[:, None], rows_by_matrix(
+            lhs, rhs, group_sizes, preferred_element_type=F32), jnp.nan)
 
-    def fwd(lhs, rhs, sizes):
-        return planted(lhs, rhs, sizes), (lhs, rhs, sizes)
+    def planted_weight_gradient(lhs, rhs, group_sizes, **kw):
+        outside = (jnp.arange(lhs.shape[0]) >= jnp.sum(group_sizes))[:, None]
+        return rows_by_rows(lhs, rhs, group_sizes, **kw) + (
+            jnp.where(outside, lhs, 0).T @ jnp.where(outside, rhs, 0))[None]
 
-    def bwd(kept, ct):
-        lhs, rhs, sizes = kept
-        inside = jnp.arange(lhs.shape[0]) < jnp.sum(sizes)
-        _, back = jax.vjp(lambda a, b: real(
-            a, b, sizes, preferred_element_type=F32), lhs, rhs)
-        d_lhs, d_rhs = back(jnp.where(inside[:, None], ct, 0.0))
-        return jnp.where(inside[:, None], d_lhs, 1e30), d_rhs, None
-
-    planted.defvjp(fwd, bwd)
-    monkeypatch.setattr(lax, "ragged_dot",
-                        lambda a, b, sizes, **_: planted(a, b, sizes))
-    _, params = whole_layer(3)
-    layer, p = share_of(params, 0, 4)
-    x = jax.random.normal(jax.random.key(11), (2, 24, D), F32)
+    monkeypatch.setattr(lax, "ragged_dot", planted)
+    monkeypatch.setattr(lax, "ragged_dot_general", planted_weight_gradient)
+    if first is None:       # the router's own choice: some eighty pairs
+        layer, p = share_of(whole_layer(3)[1], 0, 4)
+        x = jax.random.normal(jax.random.key(11), (2, 24, D), F32)
+    else:                   # ten pairs in a stretch of fifteen rows
+        layer, p, x = steered_share((2, 7), (2, 3, 4, 5, 6), first, True)
     with jax.default_matmul_precision("highest"):
         got, grads = jax.value_and_grad(
             lambda p, x: jnp.sum(layer.apply(p, x) ** 2), (0, 1))(p, x)
         monkeypatch.undo()
         want, ref = jax.value_and_grad(
-            lambda p, x: jnp.sum(reference_layer(p, x) ** 2), (0, 1))(p, x)
+            lambda p, x: jnp.sum(reference_layer(p, x, layer.first) ** 2),
+            (0, 1))(p, x)
     assert float(want) > 1 and float(got) == pytest.approx(float(want),
                                                            rel=1e-4)
     for k, g in by_path(ref).items():

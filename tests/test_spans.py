@@ -164,7 +164,7 @@ def test_vocabulary_is_one_tuple_beside_the_phases():
     assert not set(telemetry.SPANS) & set(telemetry.PHASES)
     assert set(telemetry.COMPILE_EVENTS.values()) <= set(telemetry.SPANS)
     assert len(set(telemetry.SPANS)) == len(telemetry.SPANS) == 13
-    assert len(set(telemetry.COUNTS)) == len(telemetry.COUNTS) == 17
+    assert len(set(telemetry.COUNTS)) == len(telemetry.COUNTS) == 18
 
 
 # -- the recorder, the registry, the listener --------------------------------

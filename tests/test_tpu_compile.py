@@ -122,11 +122,12 @@ def test_mixed_attention_compiles_at_the_routed_cells_shapes(
     assert "attn_core" in text
 
 
-def test_the_held_experts_compile_at_the_routed_cells_shapes(one_chip_mesh):
-    """8 of 256 experts of width 1,024 over 8,192 tokens of 3,072, 10 a
-    token, with the shared expert: router, sort, the walk over the routed
-    pairs with its grouped products, forward and backward under
-    ``jax.checkpoint`` inside the step's ``shard_map``."""
+@pytest.fixture(scope="module")
+def held_experts_program(one_chip_mesh):
+    """One routed layer at the routed cell's shapes (8 of 256 experts of
+    width 1,024 over 8,192 tokens of 3,072, 10 a token, with the shared
+    expert), its value and its gradients to the parameters and the input
+    under ``jax.checkpoint`` inside the step's ``shard_map``, compiled."""
     from theanompi_tpu.parallel.moe import HeldExperts
     mesh = one_chip_mesh
     layer = HeldExperts(3072, 256, (0, 8), 10, 1024, 1024, 2.5, name="moe")
@@ -136,8 +137,8 @@ def test_the_held_experts_compile_at_the_routed_cells_shapes(one_chip_mesh):
     def per_worker(p, x):
         def loss(p, x):
             return jnp.sum(jax.checkpoint(layer.apply)(p, x[0]))
-        return jax.tree.map(lambda g: g[None], jax.value_and_grad(loss)(
-            jax.tree.map(lambda a: a[0], p), x))
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(
+            loss, argnums=(0, 1))(jax.tree.map(lambda a: a[0], p), x[0]))
 
     spec = P("workers")
     sh = NamedSharding(mesh, spec)
@@ -146,13 +147,150 @@ def test_the_held_experts_compile_at_the_routed_cells_shapes(one_chip_mesh):
     x = jax.ShapeDtypeStruct((1, 1, 8192, 3072), jnp.float32, sharding=sh)
     step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
                              out_specs=spec))
-    compiled = step.lower(boxed, x).compile()
-    text = compiled.as_text()
+    return step.lower(boxed, x).compile()
+
+
+def test_the_held_experts_compile_at_the_routed_cells_shapes(
+        held_experts_program):
+    """Router, sort, the walk over the routed pairs with its grouped
+    products, the shared expert, forward and backward."""
+    text = held_experts_program.as_text()
     assert "moe/router" in text and "experts" in text
     assert "shared_expert" in text
     # one stretch of 4,096 rows at a time, never the worst case's 65,536
     assert "[4096,3072]" in text and "[65536," not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30
+    assert held_experts_program.memory_analysis().temp_size_in_bytes \
+        < 2 * 2 ** 30
+
+
+def _computations(text):
+    """``{name: its instruction lines}`` of a compiled module's text, and
+    the entry computation's name."""
+    found, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            found[name] = []
+            entry = name if head.group(1) else entry
+        elif line == "}":       # a kernel's attributes may span lines
+            name = None
+        elif name is not None:
+            found[name].append(line)
+    return found, entry
+
+
+def test_a_weight_gradient_of_the_experts_is_written_once(
+        held_experts_program):
+    """The first stretch of each walk is straight-line and the loops hold
+    what overflows it.  In the entry computation nothing produces an array
+    of the stacked experts' float32 shape but the three weight-gradient
+    products, which the backward loop takes as its first carry and the
+    output takes from the loop: no fill, no add into a carry, no round
+    trip through bfloat16.  The straight-line backward stretch makes eight
+    grouped products (two of the forward's again, six of its own: the third
+    forward product is not needed for the router weights' gradient), so
+    with the forward stretch's three the entry holds eleven; the loops'
+    bodies three and eight."""
+    found, entry = _computations(held_experts_program.as_text())
+    loops = [line for line in found[entry] if " while(" in line]
+    bodies = {re.search(r'op_name="([^"]*)"', line).group(1):
+              re.search(r"body=%?([\w.\-]+)", line).group(1)
+              for line in loops}
+    forward, backward = sorted(bodies, key=lambda path: "transpose(" in path)
+    assert forward.endswith("jvp(moe)/while")
+    assert backward.endswith("checkpoint/moe/while") and len(loops) == 2
+    products = {name: sum("ragged_dot_tiling" in line for line in lines)
+                for name, lines in found.items()}
+    assert products[entry] == 11
+    assert products[bodies[forward]] == 3
+    assert products[bodies[backward]] == 8
+    assert sum(products.values()) == 22
+    stacked = re.compile(r" = f32\[8,(3072,1024|1024,3072)\]\S* ([\w\-]+)\(")
+    made = [(m.group(2), "ragged_dot_tiling" in line)
+            for line in found[entry] for m in [stacked.search(line)] if m]
+    # the three products, the loop's results, and views of the parameters
+    assert made.count(("custom-call", True)) == 3
+    assert set(made) == {("custom-call", True), ("get-tuple-element", False),
+                         ("bitcast", False)}, made
+    # the transposed matrices are made behind a branch, in bfloat16
+    assert sum(" conditional(" in line for line in found[entry]) == 1
+
+
+@pytest.mark.parametrize("form", ["as it is", "transposes straight-line"])
+def test_the_experts_update_stays_in_the_parameters_layout(
+        topo, monkeypatch, form):
+    """A cut-down step of the routed model through ``steps.build_train_step``:
+    a dense layer and one routed layer at the routed cell's shapes (8 of 256
+    experts of width 1,024 over 8,192 tokens of 3,072, the shared expert),
+    with Adam over every parameter and the state aliased to the outputs, as
+    the cell's step has them and one layer compiled alone has not.  As the
+    program is, the entry computation copies no float32 array of the stacked
+    experts' shape and lays none out but row-major: the transposed bfloat16
+    matrices of the backward products are made behind ``moe._turned``'s
+    branch.  With the three transposes straight-line (the control, which is
+    what a step without the branch is) layout assignment transposes the
+    float32 parameters at the top of the step and runs the experts' update
+    in that layout, its moments and gradients copied into it and its results
+    copied back: 21 copies here, 84 and 16 ms a step in the cell, 3.8%
+    slower than before the walk was touched (PERF.md section 6, PR 40).
+    Where the control stops showing them the compiler no longer needs the
+    branch and ``_turned`` can lose it."""
+    from theanompi_tpu.models.routed_lm import RoutedLM
+    from theanompi_tpu.parallel import moe, steps
+    from theanompi_tpu.parallel.exchanger import BSP_Exchanger
+
+    if form != "as it is":
+        monkeypatch.setattr(moe, "_turned", lambda experts, cd, any_pair: (
+            jax.tree.map(lambda a: jnp.swapaxes(a.astype(cd), 1, 2),
+                         experts)))
+    mesh = Mesh(np.array(topo.devices[:1]), ("workers",))
+    monkeypatch.setattr(steps, "_keep_collectives_apart", lambda: None)
+    model = RoutedLM({
+        "mesh": mesh, "size": 1, "rank": 0, "verbose": False,
+        "batch_size": 1, "vocab": 256, "d_model": 3072, "head_dim": 128,
+        "n_kv_head": 1, "n_layer": 2, "d_ff": 1024, "n_experts": 256,
+        "experts_held": (0, 8), "top_k": 10, "expert_width": 1024,
+        "shared_width": 1024, "window": 512, "seq_len": 8192,
+        "layer_types": ("sliding_attention",) * 2,
+        "mlp_layer_types": ("dense", "sparse"), "n_head_per_layer": (2, 2),
+        "attn_impl": "flash", "synthetic_train": 2, "synthetic_val": 1,
+        "synthetic_batches": 1})
+    exchanger = BSP_Exchanger(model.config)
+    exchanger.prepare(mesh, model)
+    rows = NamedSharding(mesh, P("workers"))
+    whole = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype,
+                                       sharding=rows),
+        jax.eval_shape(lambda p: {
+            "params": p, "opt_state": model.opt.init(p),
+            "bn_state": model.bn_state,
+            "extra": exchanger.extra_state_template()}, model.params))
+    batch = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows),
+        model._peek_batch_aval())
+    scalars = [jax.ShapeDtypeStruct((), dtype, sharding=whole) for dtype in
+               (jnp.float32, jax.random.key(0).dtype, jnp.int32)]
+    step = steps.build_train_step(mesh, model, exchanger)
+    found, entry = _computations(
+        step.lower(state, batch, *scalars).compile().as_text())
+    stacked = re.compile(r" = \(?f32\[(?:1,)?8,(?:3072,1024|1024,3072)\]"
+                         r"\{([\d,]+)\S* ([\w\-]+)\(")
+    made = [m.groups() for line in found[entry]
+            for m in [stacked.search(line)] if m]
+    # the parameters, two moments and the results of three matrices at least
+    assert len(made) >= 18, made
+    copies = [layout for layout, opcode in made if opcode == "copy"]
+    turned = [layout for layout, _ in made
+              if layout not in ("2,1,0", "3,2,1,0")]
+    branches = sum(" conditional(" in line for line in found[entry])
+    if form == "as it is":
+        assert (copies, turned, branches) == ([], [], 1)
+    else:
+        assert len(copies) >= 9 and len(turned) >= 9 and branches == 0, (
+            "the compiler leaves the experts' update alone without the "
+            "branch: moe._turned may not need it any more", made)
 
 
 def test_the_heads_make_three_products_a_block_and_no_backward_loop(

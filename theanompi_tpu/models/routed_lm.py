@@ -41,7 +41,10 @@ come from one pass over the logits, ``head_block`` tokens at a time
 Scopes: ``block<i>`` (inside ``attn``, ``attn_core``, ``mlp`` or ``moe``
 with ``router``, ``experts``, ``shared_expert``) and ``head``.  Counters,
 at the step's first trace: ``model.experts_held``,
-``model.experts_routed``, ``model.routed_pairs``, ``model.window_layers``,
+``model.experts_routed``, ``model.routed_pairs``,
+``model.routed_rows_at_once`` (the rows the routed layers' first stretches
+hold: where ``routed_pairs * held / experts`` fits them, the expected
+routing never enters a walk's loop), ``model.window_layers``,
 ``model.full_layers``, ``model.attn_kernel_applications``,
 ``model.head_logit_products``.
 """
@@ -236,6 +239,8 @@ class RoutedLM(ModelBase):
                             sum(ff.n_experts for ff in sparse))
             telemetry.count("model.routed_pairs", sum(
                 rows * self.seq_len * ff.top_k for ff in sparse))
+            telemetry.count("model.routed_rows_at_once", sum(
+                ff.rows_at_once(rows * self.seq_len) for ff in sparse))
             telemetry.count("model.window_layers", windows)
             telemetry.count("model.full_layers", len(self.blocks) - windows)
             telemetry.count("model.attn_kernel_applications", sum(

@@ -263,13 +263,22 @@ class HeldExperts(L.Layer):
     routing**: every (token, expert) pair whose expert is held is
     computed.  The pairs are sorted by expert; the static shape is the
     worst case, ``N * min(top_k, held)`` rows, walked a sixteenth at a
-    time (gather, grouped products, weighted scatter-add) as far as the
-    last routed pair and no further, so the work follows the rows
-    actually routed and the memory is one stretch's (``_routed_part``).
-    The experts' products are grouped matrix products over the stacked
-    ``[held, d, width]`` weights (``lax.ragged_dot``).  Router in
-    float32 at the highest precision, so that a float32 reference chooses
-    alike; matrix operands in ``compute_dtype``.
+    time (gather, grouped products, weighted scatter-add): one stretch
+    straight-line, added into the shared expert's result, then a loop as
+    far as the last routed pair and no further, so the work follows the
+    rows actually routed and the memory is one stretch's
+    (``_routed_part``).  The expected routing, ``N * top_k * held /
+    n_experts`` pairs, fits the first stretch of a chip that holds a
+    small share of the experts (2,560 pairs in 4,096 rows at 8 of 256
+    held and 10 a token): the loop is for routing that overflows it, and
+    with no pair routed the one stretch runs with every row cut off and
+    adds nought.  The experts' products are grouped
+    matrix products over the stacked ``[held, d, width]`` weights
+    (``lax.ragged_dot``); the backward pass is written out beside the
+    forward one, and a stretch's weight gradients are its grouped
+    products' float32 results, written once.  Router in float32 at the
+    highest precision, so that a float32 reference chooses alike; matrix
+    operands in ``compute_dtype``, sums in float32.
 
     With more than one chip in the expert group this layer would be
     followed by its exchange (a sum over the group of the routed parts,
@@ -341,24 +350,41 @@ class HeldExperts(L.Layer):
             ends = jnp.cumsum(jnp.sum(
                 local[:, None] == jnp.arange(self.n_held)[None], axis=0,
                 dtype=jnp.int32))
+            # what the routed part is added into: the shared expert's
+            # result where the layer has one
+            base = jnp.zeros(xf.shape, jnp.float32) if self.shared is None \
+                else self.shared.apply(params["shared_expert"],
+                                       xf).astype(jnp.float32)
             y = _routed_part(self.rows_at_once(xf.shape[0]),
                              params["experts"], xf.astype(self.compute_dtype),
-                             weights, order, ends)
-            if self.shared is not None:
-                y = y + self.shared.apply(params["shared_expert"],
-                                          xf).astype(jnp.float32)
+                             weights, order, ends, base)
             return y.reshape(x.shape[:-1] + (self.dim,))
 
 
-# The held pairs' part of a routed layer, ``[N, d]`` float32:
-# ``sum_j weights[n, j] * Expert_{chosen[n, j]}(xf[n])`` over the pairs whose
-# expert is held.  ``order`` sorts the pairs ``n * top_k + j`` by held expert,
-# one not held last; expert ``e``'s rows of that order end at ``ends[e]``.
-# The sorted pairs are walked ``rows`` at a time, as far as the last routed
-# pair and no further (a loop whose trip count is the step's own), forward
-# and backward alike: reverse-mode differentiation cannot transpose such a
+# The held pairs' part of a routed layer, added into ``base`` (``[N, d]``
+# float32: the shared expert's result, or nought):
+# ``base[n] + sum_j weights[n, j] * Expert_{chosen[n, j]}(xf[n])`` over the
+# pairs whose expert is held.  ``order`` sorts the pairs ``n * top_k + j`` by
+# held expert, one not held last; expert ``e``'s rows of that order end at
+# ``ends[e]``.  The sorted pairs are walked ``rows`` at a time: one stretch
+# straight-line, then as far as the last routed pair and no further (a loop
+# whose trip count is the step's own, and nought where the routed pairs fit
+# the first stretch, as the expected routing does), forward and backward
+# alike.  With no pair routed the first stretch runs with every row cut off
+# and adds nought.  Reverse-mode differentiation cannot transpose such a
 # loop, so the backward pass is written out beside the forward one.  It
-# makes each stretch's products again; nothing but the arguments is kept.
+# makes each stretch's first two products again; nothing but the arguments
+# is kept.  A stretch's weight gradients are its grouped products' float32
+# results as they are written: the first stretch's are the loop's first
+# carry, so where the loop does not run nothing passes over an array of the
+# stacked experts' shape but the product that writes it.
+
+# an expert's weight gradient from its rows of two arrays: [rows, k] and
+# [rows, n], grouped along the rows, give [held, k, n]
+_WEIGHT_GRADIENT = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
 
 def _stretch(rows, order, ends, top_k, i):
     """Stretch ``i``: its pairs, their tokens and choices, each expert's
@@ -375,8 +401,7 @@ def _expert_rows(sizes, live, experts, xs, w):
     ``xs[r]``, times ``w[r]``; nought where ``r`` is no pair.  A grouped
     product leaves in a row of no group whatever it finds, and so does
     its transpose: such a row is cut off on the way in and on the way
-    out, which cuts its cotangents off too (on the chip they are not
-    nought: PERF.md section 6, PR 37)."""
+    out (on the chip it is not nought: PERF.md section 6, PR 37)."""
     cd = xs.dtype
     xs = jnp.where(live[:, None], xs, 0)
     with jax.named_scope("experts"):
@@ -390,12 +415,70 @@ def _expert_rows(sizes, live, experts, xs, w):
     return jnp.where(live[:, None], ye * w[:, None], 0.0)
 
 
+def _expert_rows_back(sizes, live, experts, turned, xs, w, dy):
+    """The cotangents of :func:`_expert_rows` to ``experts``, ``xs`` and
+    ``w`` at ``dy`` ``[rows, d]``, all float32; ``turned`` is
+    :func:`_turned` of ``experts``.  ``g`` and ``u`` are made again,
+    ``a = silu(g) * u``; with ``p[r] = dy[r] wd^T`` the weight's cotangent
+    is ``<a[r], p[r]>`` (the third forward product is not needed for it)
+    and ``a``'s is ``w[r] p[r]``.  Each weight gradient is one grouped
+    product over the stretch's rows, returned as the product wrote it.
+    Rows of no group are cut off on the way in, and what a product left
+    in one (it need not be finite) before anything is multiplied by it or
+    summed, so that neither operand of a weight gradient holds anything
+    there."""
+    cd = xs.dtype
+    cut = lambda a: jnp.where(live[:, None], a, 0)          # noqa: E731
+    rows_by = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                                preferred_element_type=jnp.float32)
+    weight_gradient = functools.partial(
+        lax.ragged_dot_general, group_sizes=sizes,
+        ragged_dot_dimension_numbers=_WEIGHT_GRADIENT,
+        preferred_element_type=jnp.float32)
+    xs, dy = cut(xs), cut(dy).astype(cd)
+    with jax.named_scope("experts"):
+        g = rows_by(xs, experts["wg"].astype(cd))
+        u = rows_by(xs, experts["wu"].astype(cd))
+        p = cut(rows_by(dy, turned["wd"]))
+    gate = jax.nn.sigmoid(g)
+    silu = g * gate
+    a = cut(silu * u)
+    d_w = jnp.sum(a * p, axis=-1)
+    d_a = p * w[:, None]
+    d_g = cut(d_a * u * (gate + silu * (1 - gate))).astype(cd)
+    d_u = cut(d_a * silu).astype(cd)
+    with jax.named_scope("experts"):
+        d_xs = rows_by(d_g, turned["wg"]) + rows_by(d_u, turned["wu"])
+        d_experts = {"wg": weight_gradient(xs, d_g),
+                     "wu": weight_gradient(xs, d_u),
+                     "wd": weight_gradient((a * w[:, None]).astype(cd), dy)}
+    return d_experts, cut(d_xs), d_w
+
+
+def _turned(experts, cd, any_pair):
+    """The experts' matrices in ``cd``, each transposed, for the backward
+    products that take them so; nought where no pair is routed (every row
+    is then cut off and nothing reads them).  Behind a branch for the
+    compiler's sake: with the transposes straight-line in the step
+    the compiler transposes the float32 parameters themselves at the top
+    of the program, and then runs the optimizer's update of the experts
+    in that layout, its moments and gradients copied into it and the
+    results copied back (PERF.md section 6, PR 40: 16 ms a step).  A
+    branch takes its operands as they are laid out."""
+    with jax.named_scope("experts"):
+        turn = lambda a: jnp.swapaxes(a.astype(cd), 1, 2)   # noqa: E731
+        return lax.cond(
+            any_pair, lambda: jax.tree.map(turn, experts),
+            # of the same varying type as the other branch's
+            lambda: jax.tree.map(lambda a: 0 * turn(a), experts))
+
+
 def _stretches(rows, ends):
     return (ends[-1] + rows - 1) // rows
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed_part(rows, experts, xf, weights, order, ends):
+def _routed_part(rows, experts, xf, weights, order, ends, base):
     top_k = weights.shape[1]
 
     def some_rows(i, y):
@@ -403,36 +486,40 @@ def _routed_part(rows, experts, xf, weights, order, ends):
         return y.at[token].add(_expert_rows(
             sizes, live, experts, xf[token], weights[token, choice]))
 
-    return lax.fori_loop(0, _stretches(rows, ends), some_rows,
-                         lax.full_like(xf, 0, jnp.float32))
+    return lax.fori_loop(1, _stretches(rows, ends), some_rows,
+                         some_rows(0, base))
 
 
-def _routed_part_fwd(rows, experts, xf, weights, order, ends):
-    return _routed_part(rows, experts, xf, weights, order, ends), \
+def _routed_part_fwd(rows, experts, xf, weights, order, ends, base):
+    return _routed_part(rows, experts, xf, weights, order, ends, base), \
         (experts, xf, weights, order, ends)
 
 
 def _routed_part_bwd(rows, kept, dy):
     experts, xf, weights, order, ends = kept
     top_k = weights.shape[1]
+    turned = _turned(experts, xf.dtype, ends[-1] > 0)
 
-    def some_rows(i, grads):
+    def some_rows(i, g_xf, g_weights):
         token, choice, sizes, live = _stretch(rows, order, ends, top_k, i)
-        _, back = jax.vjp(functools.partial(_expert_rows, sizes, live),
-                          experts, xf[token], weights[token, choice])
-        d_experts, d_xs, d_w = back(dy[token])
-        g_experts, g_xf, g_weights = grads
-        return (jax.tree.map(jnp.add, g_experts, d_experts),
-                g_xf.at[token].add(d_xs.astype(jnp.float32)),
+        d_experts, d_xs, d_w = _expert_rows_back(
+            sizes, live, experts, turned, xf[token],
+            weights[token, choice], dy[token])
+        return (d_experts, g_xf.at[token].add(d_xs),
                 g_weights.at[token, choice].add(d_w))
+
+    def more_rows(i, grads):
+        g_experts, g_xf, g_weights = grads
+        d_experts, g_xf, g_weights = some_rows(i, g_xf, g_weights)
+        return jax.tree.map(jnp.add, g_experts, d_experts), g_xf, g_weights
 
     zeros = lambda a: lax.full_like(a, 0, jnp.float32)      # noqa: E731
     g_experts, g_xf, g_weights = lax.fori_loop(
-        0, _stretches(rows, ends), some_rows,
-        (jax.tree.map(zeros, experts), zeros(xf), zeros(weights)))
+        1, _stretches(rows, ends), more_rows,
+        some_rows(0, zeros(xf), zeros(weights)))
     return (jax.tree.map(lambda g, a: g.astype(a.dtype), g_experts, experts),
             g_xf.astype(xf.dtype), g_weights.astype(weights.dtype),
-            None, None)
+            None, None, dy)
 
 
 _routed_part.defvjp(_routed_part_fwd, _routed_part_bwd)

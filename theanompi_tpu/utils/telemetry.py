@@ -102,7 +102,8 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "model.head_logit_products",
           # a routed model's share a step, written at its first trace
           "model.experts_held", "model.experts_routed",
-          "model.routed_pairs", "model.window_layers", "model.full_layers")
+          "model.routed_pairs", "model.routed_rows_at_once",
+          "model.window_layers", "model.full_layers")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
