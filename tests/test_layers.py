@@ -528,6 +528,22 @@ def test_flash_tiles_follow_the_sequence_length(t, want):
         assert name == "use_fused_bwd_kernel" or (t % b == 0 and b % 128 == 0)
 
 
+@pytest.mark.parametrize("t", [16384, 32768])
+def test_flash_tiles_past_8192_tokens_halve_the_fused_dq_parts(t):
+    """Without a window, past 8,192 tokens the fused backward takes k/v
+    2,048 at a time (1,024 to a product) and q 512: dq leaves the kernel
+    in t / 2,048 parts, not t / 1,024; the forward's tiles are as at 8,192,
+    and a window keeps its own."""
+    tiles = L.flash_tiles(t)
+    assert (tiles["block_q_dkv"], tiles["block_kv_dkv"],
+            tiles["block_kv_dkv_compute"]) == (512, 2048, 1024)
+    assert tiles["use_fused_bwd_kernel"] is True
+    assert {k: tiles[k] for k in ("block_q", "block_kv", "block_kv_compute")} \
+        == {k: L.flash_tiles(8192)[k]
+            for k in ("block_q", "block_kv", "block_kv_compute")}
+    assert L.flash_tiles(t, 512)["block_kv_dkv"] == 1024
+
+
 @pytest.mark.parametrize("attn_impl, want", [("flash", 16), ("reference", 0)])
 def test_the_looped_step_counts_the_attention_cores_on_the_kernel_path(
         attn_impl, want):

@@ -499,3 +499,50 @@ def test_a_cut_of_the_lfm2_cells_step_compiles_at_its_widths(
     assert len(made_again.findall(remade_text)) >= 3
     base = remade.memory_analysis().temp_size_in_bytes
     assert base < temp <= base + 1.1 * kept, (base, temp, kept)
+
+
+@pytest.mark.parametrize("kind", ["kda", "mla"])
+def test_a_delta_rule_and_a_latent_attention_layer_compile_at_the_kimi_cells_widths(
+        one_chip_mesh, kind):
+    """One layer of each of the Kimi Linear cell's operators at its widths,
+    hidden 2,304 over one sequence of 16,384, forward and backward under
+    ``jax.checkpoint`` inside the step's ``shard_map``: the delta rule, 32
+    heads of 128 with 4-tap convolutions, chunked by 64 (a scan of 256
+    carries, the decays never exponentiated alone); the latent attention,
+    32 heads of 192 for q and k over a latent of 512 and heads of 128 for
+    v, on the splash kernel at the tiles ``flash_tiles`` gives 16,384."""
+    mesh = one_chip_mesh
+    if kind == "kda":
+        op = L.DeltaAttention(2304, 32, 128, 1e-5, name="kda")
+    else:
+        op = L.LatentAttention(2304, 32, 512, 128, 64, 128, 1e-5,
+                               attn_impl="flash", name="mla")
+    params = jax.eval_shape(op.init, jax.random.key(0))
+
+    def per_worker(p, x):
+        def loss(p, x):
+            return jnp.sum(jax.checkpoint(op.apply)(p, x[0]).astype(
+                jnp.float32))
+        return jax.tree.map(lambda g: g[None], jax.value_and_grad(loss)(
+            jax.tree.map(lambda a: a[0], p), x))
+
+    spec = P("workers")
+    sh = NamedSharding(mesh, spec)
+    boxed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=sh), params)
+    x = jax.ShapeDtypeStruct((1, 1, 16384, 2304), jnp.float32, sharding=sh)
+    step = jax.jit(shard_map(per_worker, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec))
+    compiled = step.lower(boxed, x).compile()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(kind, "temporaries", temp)
+    if kind == "kda":
+        assert "kda/kda_core/" in text and " while(" in text
+        assert "tpu_custom_call" not in text
+    else:
+        assert text.count('custom_call_target="tpu_custom_call"') == 3
+        assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text
+        for name, size in L.flash_tiles(16384).items():
+            assert f'{name}\\": {json.dumps(size)}' in text, name
+        assert "mla/attn_core" in text

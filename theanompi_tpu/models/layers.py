@@ -574,7 +574,12 @@ def flash_tiles(t: int, window: Optional[int] = None) -> dict:
     3.74 ms forward, forward again and backward against 4.33) the
     forward's k/v tile is no longer than the window, so that fewer keys
     outside it are fetched and masked, and the backward takes q 512 rows
-    at a time."""
+    at a time.  Past 8,192 tokens without a window (a v5e sweep at T
+    16,384, 32 heads, q and k of 192, v of 128: 113.4 ms forward, forward
+    again and backward against 115.9 at the tiles above, 127.9 unfused)
+    the fused backward takes q 512 rows and k/v 2,048 at a time, 1,024 to
+    a product: dq leaves the kernel in one part a k/v block, half as many
+    parts (8 of ``[32, 16384, 192]`` there)."""
     if t % 128:
         raise ValueError(f"attn_impl='flash' needs a sequence length that "
                          f"is a multiple of 128, the kernel's lanes; got {t}")
@@ -584,6 +589,11 @@ def flash_tiles(t: int, window: Optional[int] = None) -> dict:
 
     q, kv = tile(t, 512), tile(t, 1024)
     fwd_kv = kv if window is None else tile(t, max(128, min(window, 1024)))
+    if t > 8192 and window is None:     # half as many parts of dq
+        return dict(block_q=q, block_kv=fwd_kv,
+                    block_kv_compute=tile(fwd_kv, 512), block_q_dkv=q,
+                    block_kv_dkv=tile(t, 2048), block_kv_dkv_compute=kv,
+                    use_fused_bwd_kernel=True)
     return dict(block_q=q, block_kv=fwd_kv,
                 block_kv_compute=tile(fwd_kv, 512),
                 block_q_dkv=kv if window is None else q, block_kv_dkv=kv,
@@ -966,7 +976,13 @@ def short_conv_core(bcx, taps):
     causal convolution as ``L`` shifted multiply-adds over ``v = B * x~``
     padded with ``L - 1`` noughts in front."""
     gate_b, gate_c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-    v = gate_b * x
+    return (gate_c * causal_taps(gate_b * x, taps)).astype(bcx.dtype)
+
+
+def causal_taps(v, taps):
+    """``v [..., T, d]``, ``taps [L, d]`` -> ``z_t = sum_j taps_j *
+    v_{t - L + 1 + j}``: a causal depthwise convolution as ``L`` shifted
+    multiply-adds over ``v`` padded with ``L - 1`` noughts in front."""
     n, t = taps.shape[0], v.shape[-2]
     pad = [(0, 0)] * v.ndim
     pad[-2] = (n - 1, 0)
@@ -974,7 +990,7 @@ def short_conv_core(bcx, taps):
     z = taps[0] * v[..., :t, :]
     for j in range(1, n):
         z = z + taps[j] * v[..., j:j + t, :]
-    return (gate_c * z).astype(bcx.dtype)
+    return z
 
 
 class GatedMLP(Layer):
@@ -1012,6 +1028,295 @@ class GatedMLP(Layer):
     def kept_bytes(self, tokens: int) -> int:
         return 2 * tokens * self.hidden \
             * jnp.dtype(self.compute_dtype).itemsize
+
+
+class DeltaAttention(Layer):
+    """Kimi Delta Attention (KDA, arXiv:2510.26692): a gated delta rule
+    whose decay is per channel, the ``kda`` operator of Moonshot's Kimi
+    Linear layers.  Per head of ``head_dim`` (``x`` the normed input):
+
+        q, k, v = SiLU(conv(x W_q)), SiLU(conv(x W_k)), SiLU(conv(x W_v))
+        q <- q / |q| * head_dim^-1/2;   k <- k / |k|
+        a_t = -exp(A_log) * softplus(x W_fa W_fb + b_dt)   (per channel)
+        beta_t = sigmoid(x W_beta)                          (per head)
+        S_t = (I - beta_t k_t k_t^T) Diag(exp a_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+        y = (RMSNorm(o) * w * sigmoid(x W_ga W_gb)) W_o
+
+    ``conv`` is a causal depthwise convolution of ``TAPS`` taps a channel
+    (:func:`causal_taps`; the published ``short_conv_kernel_size``), nought
+    before the sequence's start; ``S_0 = 0``.  The projections run in
+    ``compute_dtype``; the convolutions, norms, decays and gates in
+    float32.  The recurrence is :func:`kda_core`, in chunks of ``CHUNK``.
+    Scopes: ``<name>`` around the operator, ``kda_core`` around the
+    recurrence alone.  No bias but ``b_dt``."""
+
+    TAPS = 4
+    CHUNK = 64
+
+    def __init__(self, dim: int, n_head: int, head_dim: int,
+                 eps: float = 1e-5, w_init=("normal", 0.02),
+                 compute_dtype=jnp.bfloat16, name: str = "kda"):
+        self.dim, self.n_head, self.hd = dim, n_head, head_dim
+        self.eps = eps
+        self.w_init = w_init
+        self.compute_dtype = compute_dtype
+        self.name = name
+
+    def init(self, key):
+        ks = jax.random.split(key, 14)
+        d, hd, w = self.dim, self.hd, self.n_head * self.hd
+        mk = lambda k, shape: init_weight(k, shape, self.w_init)  # noqa: E731
+        # decay rates exp(A_log) in [1, 16] a head; softplus(b_dt) in
+        # [1e-3, 1e-1] a channel, log-uniform (the lineage's initialisation)
+        dt = jnp.exp(jax.random.uniform(ks[12], (w,), minval=math.log(1e-3),
+                                        maxval=math.log(1e-1)))
+        return {"wq": mk(ks[0], (d, w)), "wk": mk(ks[1], (d, w)),
+                "wv": mk(ks[2], (d, w)),
+                **{f"{n}_conv": mk(k, (self.TAPS, w))
+                   for n, k in zip("qkv", ks[3:6])},
+                "w_fa": mk(ks[6], (d, hd)), "w_fb": mk(ks[7], (hd, w)),
+                "b_dt": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(jax.random.uniform(
+                    ks[13], (self.n_head,), minval=1.0, maxval=16.0)),
+                "w_beta": mk(ks[8], (d, self.n_head)),
+                "w_ga": mk(ks[9], (d, hd)), "w_gb": mk(ks[10], (hd, w)),
+                "o_norm": {"scale": jnp.ones((hd,))},
+                "wo": mk(ks[11], (w, d))}
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        cd, f32 = self.compute_dtype, jnp.float32
+        b, t, _ = x.shape
+        h, hd = self.n_head, self.hd
+        x = x.astype(cd)
+
+        def dot(a, w):
+            return jnp.dot(a.astype(cd), params[w].astype(cd))
+
+        # the float32 arithmetic between a projection and the core is made
+        # again in the backward pass from the compute_dtype projection
+        @functools.partial(jax.checkpoint, static_argnums=(2,))
+        def conv(y, taps, scale):                           # [B, T, H, hd]
+            y = jax.nn.silu(causal_taps(y.astype(f32), taps)).reshape(
+                b, t, h, hd)
+            if scale:                   # to the unit sphere, times scale
+                y = y * jax.lax.rsqrt(jnp.sum(y * y, -1, keepdims=True)
+                                      + 1e-6) * scale
+            return y.astype(cd)
+
+        with jax.named_scope(self.name):
+            q, k, v = (conv(dot(x, "w" + n), params[n + "_conv"], scale)
+                       for n, scale in (("q", hd ** -0.5), ("k", 1.0),
+                                        ("v", None)))
+            a = -jnp.exp(params["A_log"])[:, None] * jax.nn.softplus(
+                dot(dot(x, "w_fa"), "w_fb").astype(f32) + params["b_dt"]
+            ).reshape(b, t, h, hd)
+            beta = jax.nn.sigmoid(dot(x, "w_beta").astype(f32))
+            with jax.named_scope("kda_core"):
+                o = kda_core(q, k, v, a, beta, chunk=min(self.CHUNK, t),
+                             compute_dtype=cd)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                  + self.eps) * params["o_norm"]["scale"]
+            gate = jax.nn.sigmoid(dot(dot(x, "w_ga"), "w_gb").astype(f32))
+            o = o.reshape(b, t, h * hd) * gate
+            return jnp.dot(o.astype(cd), params["wo"].astype(cd))
+
+
+def kda_core(q, k, v, a, beta, *, chunk: int, compute_dtype,
+             group: int = 16):
+    """The KDA recurrence of :class:`DeltaAttention` over ``q, k [B, T, H,
+    dk]``, ``v [B, T, H, dv]``, the log decay ``a [B, T, H, dk]`` (<= 0)
+    and ``beta [B, T, H]`` -> ``o [B, T, H, dv]`` float32, in the chunked
+    WY form of the gated delta rule (arXiv:2412.06464, the per-channel
+    decay of arXiv:2510.26692).  Inside a chunk of ``chunk`` tokens, with
+    ``G`` the cumulative log decay from its start and ``S`` the state it
+    starts from:
+
+        A_ij = sum_c q_ic k_jc e^{G_ic - G_jc}  (j <= i),  L_ij = beta_i
+        sum_c k_ic k_jc e^{G_ic - G_jc}  (j < i),  T = (I + L)^-1
+        W = T (beta K e^G),  U = T (beta V) - W S
+        O = (Q e^G) S + A U,  S' = e^{G_last} S + (K e^{G_last - G})^T U
+
+    Every exponent formed is a difference that cannot be positive (``G``
+    falls): ``G_i - G_j`` for ``i >= j`` (:func:`_decayed_pairs`) or
+    ``G_last - G_i``, so no decay, however strong, overflows.  A
+    ``lax.scan`` carries ``S`` (float32) over ``group`` chunks at a time,
+    made again in the backward pass: for those chunks at once everything
+    but the carry, then an inner ``lax.scan`` from chunk to chunk, so that
+    what a step holds of the recurrence is one group's and the states at
+    the groups' edges.  ``T`` and the decays are float32; the pairs'
+    products, ``T``'s applications and the carry's products take
+    ``compute_dtype`` operands and sum in float32."""
+    f32, cd = jnp.float32, compute_dtype
+    b, t, h, dk = q.shape
+    c = chunk
+    n = t // c
+    assert n * c == t and c & (c - 1) == 0, (t, c)
+
+    def chunks(x):                      # [B, T, H, ...] -> [N, B, H, C, ...]
+        x = x.reshape(b, n, c, h, *x.shape[3:])
+        return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+
+    def mm(eq, x, y, **kw):
+        return jnp.einsum(eq, x, y, preferred_element_type=f32, **kw)
+
+    def carry(s, xs):
+        w, u, qg, kg, a_qk, fall = xs
+        s_cd = s.astype(cd)
+        u = u - mm("...id,...de->...ie", w, s_cd)
+        o = mm("...id,...de->...ie", qg, s_cd) \
+            + mm("...ij,...je->...ie", a_qk, u.astype(cd))
+        return fall * s + mm("...id,...ie->...de", kg, u.astype(cd)), o
+
+    @jax.checkpoint
+    def some_chunks(s, xs):
+        q, k, v = (y.astype(f32) for y in xs[:3])     # [per, B, H, C, ...]
+        a, beta = xs[3:]
+        g = jnp.cumsum(a, axis=-2)
+        a_qk, a_kk = _decayed_pairs(jnp.stack([q, k]), k, g, cd)
+        a_qk = a_qk + jnp.sum(q * k, -1)[..., None] * jnp.eye(c, dtype=f32)
+        inv = _unit_lower_inverse(beta * a_kk).astype(cd)
+        w = mm("...ij,...jd->...id", inv, (beta * k * jnp.exp(g)).astype(cd))
+        u = mm("...ij,...jd->...id", inv, (beta * v).astype(cd))
+        g_last = g[..., -1:, :]
+        qg, kg = q * jnp.exp(g), k * jnp.exp(g_last - g)
+        fall = jnp.exp(g_last).swapaxes(-1, -2)             # [.., dk, 1]
+        return jax.lax.scan(carry, s, (
+            w.astype(cd), u, qg.astype(cd), kg.astype(cd), a_qk.astype(cd),
+            fall))
+
+    per = math.gcd(n, group)
+    xs = tuple(chunks(x).reshape(n // per, per, b, h, c, -1)
+               for x in (q, k, v, a.astype(f32), beta[..., None].astype(f32)))
+    s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
+    for axis in jax.typeof(q).vma:      # of the inputs' type in a shard_map
+        s0 = vary(s0, axis)
+    _, o = jax.lax.scan(some_chunks, s0, xs)
+    o = o.reshape(n, b, h, c, -1)
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, t, h, -1)
+
+
+def _parting(c: int):
+    """``(b, mask)`` for each level of the pairs ``j < i`` of ``C``
+    positions (a power of two): the pair first parts where blocks of
+    ``2b`` split into halves of ``b``, ``i`` in the right half and ``j``
+    in the left; the ``[C, C]`` masks of the levels add up to the strictly
+    lower triangle."""
+    i = np.arange(c)
+    out, b = [], 1
+    while b < c:
+        same = (i[:, None] // (2 * b)) == (i[None, :] // (2 * b))
+        out.append((b, same & ((i[:, None] // b) % 2 == 1)
+                    & ((i[None, :] // b) % 2 == 0)))
+        b *= 2
+    return out
+
+
+def _decayed_pairs(x, col, g, compute_dtype):
+    """``[..., C, C]``, strictly lower: ``sum_c x_ic col_jc e^{g_ic -
+    g_jc}`` for ``j < i``, where ``g`` falls along ``C`` (``x``, ``col``,
+    ``g`` ``[..., C, d]``; ``x`` may hold more rows ahead, ``[S, ..., C,
+    d]``).  At each level of :func:`_parting` one product of the rows times
+    ``e^{g_i - g_r}`` and the columns times ``e^{g_r - g_j}``, ``r`` the
+    last position of the left half, kept where the level's mask is: both
+    exponents are differences that are not positive there (a row of the
+    left half and a column of the right half take nought, and are
+    masked)."""
+    f32 = jnp.float32
+    *lead, c, d = col.shape
+    out = 0.0
+    for b, mask in _parting(c):
+        halves = g.reshape(*lead, c // (2 * b), 2 * b, d)
+        ref = jnp.broadcast_to(halves[..., b - 1:b, :],
+                               halves.shape).reshape(g.shape)
+        right = ((np.arange(c) // b) % 2 == 1)[:, None]
+        rows = x * jnp.exp(jnp.where(right, g - ref, 0.0))
+        cols = col * jnp.exp(jnp.where(right, 0.0, ref - g))
+        out = out + jnp.where(mask, jnp.einsum(
+            "...id,...jd->...ij", rows.astype(compute_dtype),
+            cols.astype(compute_dtype), preferred_element_type=f32), 0.0)
+    return out
+
+
+def _unit_lower_inverse(l):
+    """``(I + l)^-1`` of a strictly lower ``[..., C, C]`` in float32, by
+    block forward substitution doubled level by level (:func:`_parting`):
+    with ``X`` the inverse of the diagonal blocks of ``b``, ``X - X (M_b
+    l) X`` is that of the blocks of ``2b`` (``[[A, 0], [B, D]]^-1 = [[A^-1,
+    0], [-D^-1 B A^-1, D^-1]]``), every product at the highest precision
+    and of the whole ``[C, C]``."""
+    hi = jax.lax.Precision.HIGHEST
+    c = l.shape[-1]
+    x = jnp.eye(c, dtype=l.dtype)
+    for _, mask in _parting(c):
+        x = x - jnp.einsum("...ij,...jk,...kl->...il", x,
+                           jnp.where(mask, l, 0.0), x, precision=hi)
+    return x
+
+
+class LatentAttention(Layer):
+    """Multi-head latent attention (MLA, DeepSeek-V2, arXiv:2405.04434)
+    with the query projected whole and no rotary turn (the ``mla``
+    operator of Kimi Linear, ``q_lora_rank`` null and ``mla_use_nope``):
+
+        q_h = [q^n_h | q^r_h] = (x W_q)_h
+        [c | k^r] = x W_kva;   c <- RMSNorm(c)
+        [k^n_h | v_h] = (c W_kvb)_h;   k_h = [k^n_h | k^r]
+        o_h = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
+        y = concat(o_h) W_o
+
+    ``k^r`` is one ``rope``-wide part shared by every head.  The core is
+    :func:`attend` with the scale folded into q in float32; query and key
+    heads are ``nope + rope`` wide, value heads ``v_dim``.  No bias.
+    Scopes: ``<name>`` around the operator, ``attn_core`` around the core,
+    ``kv_norm`` around the latent's norm."""
+
+    def __init__(self, dim: int, n_head: int, latent: int, nope: int,
+                 rope: int, v_dim: int, eps: float = 1e-5,
+                 w_init=("normal", 0.02), compute_dtype=jnp.bfloat16,
+                 attn_impl: str = "reference", name: str = "mla"):
+        assert attn_impl in ("reference", "flash"), attn_impl
+        self.dim, self.n_head, self.latent = dim, n_head, latent
+        self.nope, self.rope, self.v_dim = nope, rope, v_dim
+        self.kv_norm = RMSNorm(latent, eps, name="kv_norm")
+        self.w_init = w_init
+        self.compute_dtype = compute_dtype
+        self.attn_impl = attn_impl
+        self.name = name
+
+    def init(self, key):
+        ks = jax.random.split(key, 4)
+        d, h, qk = self.dim, self.n_head, self.nope + self.rope
+        mk = lambda k, shape: init_weight(k, shape, self.w_init)  # noqa: E731
+        return {"wq": mk(ks[0], (d, h * qk)),
+                "w_kva": mk(ks[1], (d, self.latent + self.rope)),
+                "kv_norm": self.kv_norm.init(None),
+                "w_kvb": mk(ks[2], (self.latent, h * (self.nope + self.v_dim))),
+                "wo": mk(ks[3], (h * self.v_dim, d))}
+
+    def apply(self, params, x, *, train=False, rng=None, state=None):
+        cd, f32 = self.compute_dtype, jnp.float32
+        b, t, _ = x.shape
+        h, qk = self.n_head, self.nope + self.rope
+        x = x.astype(cd)
+
+        def dot(a, w):
+            return jnp.dot(a.astype(cd), params[w].astype(cd),
+                           preferred_element_type=f32)
+
+        with jax.named_scope(self.name):
+            q = dot(x, "wq").reshape(b, t, h, qk) * qk ** -0.5
+            ckr = dot(x, "w_kva")
+            c = self.kv_norm.apply(params["kv_norm"], ckr[..., :self.latent])
+            kv = dot(c, "w_kvb").reshape(b, t, h, self.nope + self.v_dim)
+            k = jnp.concatenate([kv[..., :self.nope], jnp.broadcast_to(
+                ckr[:, :, None, self.latent:], (b, t, h, self.rope))], -1)
+            heads = [y.astype(cd).transpose(0, 2, 1, 3)             # [B, H,
+                     for y in (q, k, kv[..., self.nope:])]          # T, hd]
+            with jax.named_scope("attn_core"):
+                o = attend(*heads, attn_impl=self.attn_impl, scaled=True)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim)
+            return jnp.dot(o.astype(cd), params["wo"].astype(cd))
 
 
 class Flatten(Layer):

@@ -1,13 +1,16 @@
 """Routed decoder-only language model whose layers differ along the stack:
-full-attention, causal-window and short-convolution layers, attention with
-its own query-head counts and rotary forms, a dense gated feed-forward in
-some layers and a routed one in the others, held as one chip's share of an
-expert- and head-parallel deployment.
+full-attention, causal-window, short-convolution, delta-rule and latent
+attention layers, attention with its own query-head counts and rotary
+forms, a dense gated feed-forward in some layers and a routed one in the
+others, held as one chip's share of an expert- and head-parallel
+deployment.
 
 The architectures of poolside's Laguna models
-(https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json) and
-of LiquidAI's LFM2 mixtures of experts
-(https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json),
+(https://huggingface.co/poolside/Laguna-S-2.1/blob/main/config.json), of
+LiquidAI's LFM2 mixtures of experts
+(https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json) and
+of Moonshot's Kimi Linear
+(https://huggingface.co/moonshotai/Kimi-Linear-48B-A3B-Instruct/blob/main/config.json),
 through the same duck-typed model contract as the rest of the zoo --
 ``rule.init(modelfile='theanompi_tpu.models.routed_lm',
 modelclass='RoutedLM', ...)``:
@@ -19,7 +22,10 @@ modelclass='RoutedLM', ...)``:
 than query heads, rotary positions over a part of the head, a per-head
 sigmoid output gate or none, q and k normed per head or not), causal in a
 ``full_attention`` layer and a causal window in a ``sliding_attention``
-layer, and :class:`layers.ShortConv` in a ``conv`` layer; ``FF_l`` is
+layer, :class:`layers.ShortConv` in a ``conv`` layer,
+:class:`layers.DeltaAttention` (a chunked gated delta rule with a decay
+per channel) in a ``kda`` layer and :class:`layers.LatentAttention`
+(low-rank keys and values, no rotary turn) in an ``mla`` layer; ``FF_l`` is
 :class:`layers.GatedMLP` in a ``dense`` layer and
 :class:`parallel.moe.HeldExperts` in a ``sparse`` one (a router over all
 the model's experts, softmax or sigmoid with a bias, the held ones' part
@@ -50,18 +56,21 @@ matmul operands are ``compute_dtype``.  Each layer runs under a
 projections, a dense layer's or a shared expert's, and a short
 convolution's input projection, as ``compute_dtype`` left them) and makes
 the rest again in the backward pass: the attention and its kernel, the
-convolution's core, the out-projections, the norms, the router and the
-walk.  The head's loss and its gradients come from one pass over the
-logits, ``head_block`` tokens at a time (:func:`layers.weighted_cross_entropy`
-with every token's weight ``1/N``).  Scopes: ``block<i>`` (inside ``attn``
-and ``attn_core``, or ``conv`` and ``conv_core``; ``mlp``, or ``moe`` with
+convolution's core, the delta rule's scan, the out-projections, the
+norms, the router and the walk.  The head's loss and its gradients come
+from one pass over the logits, ``head_block`` tokens at a time
+(:func:`layers.weighted_cross_entropy` with every token's weight ``1/N``).  Scopes: ``block<i>`` (inside ``attn``
+and ``attn_core``, ``conv`` and ``conv_core``, ``kda`` and ``kda_core``,
+or ``mla`` with ``kv_norm`` and ``attn_core``; ``mlp``, or ``moe`` with
 ``router``, ``experts``, ``shared_expert``) and ``head``.  Counters, at
 the step's first trace:
 ``model.experts_held``, ``model.experts_routed``, ``model.routed_pairs``,
 ``model.routed_rows_at_once`` (the rows the routed layers' first stretches
 hold: where ``routed_pairs * held / experts`` fits them, the expected
 routing never enters a walk's loop), ``model.window_layers``,
-``model.full_layers``, ``model.conv_layers``, ``model.sigmoid_routers``,
+``model.full_layers``, ``model.conv_layers``, ``model.kda_layers``,
+``model.mla_layers``, ``model.kda_chunks`` (the delta rule's chunks a
+step: kda layers x sequences x chunks a sequence), ``model.sigmoid_routers``,
 ``model.attn_kernel_applications``, ``model.head_logit_products``,
 ``model.kept_residual_bytes`` (the bytes of the named products a step
 keeps, from the layers' shapes).
@@ -149,6 +158,11 @@ class RoutedLM(ModelBase):
     routed_scale = 2.5
     router_scoring = "softmax"  # or "sigmoid": choose by s + b, weigh by s
     window = 8
+    kda_head_dim = 16       # a kda layer's heads (its gates' rank too)
+    kv_lora_rank = 16       # an mla layer's latent
+    qk_nope_head_dim = 8    # and its heads: q and k nope + rope wide,
+    qk_rope_head_dim = 4    # the rope part of k shared by the heads,
+    v_head_dim = 8          # v its own width
     qk_norm = False         # a per-head RMSNorm of q and k
     attn_gate = True        # a per-head sigmoid output gate
     tie_word_embeddings = False
@@ -175,7 +189,9 @@ class RoutedLM(ModelBase):
         self.cd = cd = cfg.get("compute_dtype", jnp.bfloat16)
         for k in ("vocab", "d_model", "head_dim", "n_kv_head", "n_layer",
                   "d_ff", "n_experts", "top_k", "expert_width",
-                  "shared_width", "window", "seq_len", "head_block"):
+                  "shared_width", "window", "seq_len", "head_block",
+                  "kda_head_dim", "kv_lora_rank",
+                  "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"):
             if k in cfg:
                 setattr(self, k, int(cfg[k]))
         for k in ("norm_eps", "routed_scale"):
@@ -193,6 +209,17 @@ class RoutedLM(ModelBase):
             if kind == "conv":
                 return L.ShortConv(self.d_model, compute_dtype=cd,
                                    name="conv")
+            if kind == "kda":
+                return L.DeltaAttention(
+                    self.d_model, int(heads), self.kda_head_dim,
+                    self.norm_eps, compute_dtype=cd,
+                    name="kda")
+            if kind == "mla":
+                return L.LatentAttention(
+                    self.d_model, int(heads), self.kv_lora_rank,
+                    self.qk_nope_head_dim, self.qk_rope_head_dim,
+                    self.v_head_dim, self.norm_eps, compute_dtype=cd,
+                    attn_impl=impl, name="mla")
             freq, factor = rotary_frequencies(self.rope[kind], self.head_dim)
             return L.GroupedQueryAttention(
                 self.d_model, int(heads), self.n_kv_head, self.head_dim,
@@ -280,8 +307,10 @@ class RoutedLM(ModelBase):
             self._counted = True
             sparse = [b.ff for b in self.blocks
                       if isinstance(b.ff, HeldExperts)]
-            attn = [b.attn for b in self.blocks
-                    if isinstance(b.attn, L.GroupedQueryAttention)]
+            ops = [b.attn for b in self.blocks]
+            attn = [a for a in ops if isinstance(a, L.GroupedQueryAttention)]
+            kda = sum(isinstance(a, L.DeltaAttention) for a in ops)
+            mla = sum(isinstance(a, L.LatentAttention) for a in ops)
             windows = sum(a.window is not None for a in attn)
             telemetry.count("model.experts_held",
                             sum(ff.n_held for ff in sparse))
@@ -293,12 +322,17 @@ class RoutedLM(ModelBase):
                 ff.rows_at_once(rows * self.seq_len) for ff in sparse))
             telemetry.count("model.window_layers", windows)
             telemetry.count("model.full_layers", len(attn) - windows)
-            telemetry.count("model.conv_layers",
-                            len(self.blocks) - len(attn))
+            telemetry.count("model.conv_layers", sum(
+                isinstance(a, L.ShortConv) for a in ops))
+            telemetry.count("model.kda_layers", kda)
+            telemetry.count("model.mla_layers", mla)
+            telemetry.count("model.kda_chunks", sum(
+                rows * self.seq_len // min(a.CHUNK, self.seq_len)
+                for a in ops if isinstance(a, L.DeltaAttention)))
             telemetry.count("model.sigmoid_routers", sum(
                 ff.scoring == "sigmoid" for ff in sparse))
             telemetry.count("model.attn_kernel_applications", sum(
-                a.attn_impl == "flash" for a in attn))
+                getattr(a, "attn_impl", None) == "flash" for a in ops))
             telemetry.count("model.head_logit_products", L.head_logit_products(
                 rows * self.seq_len, self.head_block))
             telemetry.count("model.kept_residual_bytes", sum(

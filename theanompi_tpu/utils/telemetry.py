@@ -105,6 +105,7 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "model.routed_pairs", "model.routed_rows_at_once",
           "model.window_layers", "model.full_layers",
           "model.conv_layers", "model.sigmoid_routers",
+          "model.kda_layers", "model.mla_layers", "model.kda_chunks",
           # bytes of named products its checkpoints keep a step
           "model.kept_residual_bytes")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
