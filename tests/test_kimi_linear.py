@@ -109,22 +109,26 @@ def recurrence_inputs(seed, decay, t=96, h=3, d=16):
     return tuple(jnp.asarray(x, F32) for x in (q, k, v, a, beta))
 
 
-@pytest.mark.parametrize("decay, chunk", [(0.05, 32), (0.05, 16), (6.0, 32),
-                                          (20.0, 32)],
-                         ids=["mild", "mild-chunk16", "strong", "drastic"])
-def test_the_chunked_delta_rule_is_the_token_recurrence(decay, chunk):
-    """Three chunks and more, float32: the output to 1e-5 of its largest
-    entry and every input's gradient to 1e-4 of its norm.  Under the strong
-    decays the cumulative log decay over a chunk falls below -100, where
-    ``e^-G`` alone is not a float32 number: nothing the chunked form makes
-    overflows."""
-    q, k, v, a, beta = recurrence_inputs(int(decay * 10) + chunk, decay)
-    assert q.shape[1] // chunk >= 3
+@pytest.mark.parametrize("decay, chunk, t", [
+    (0.05, 32, 96), (0.05, 16, 96), (6.0, 32, 96), (20.0, 32, 96),
+    (0.05, 64, 192), (0.05, 64, 64), (0.05, 2, 96)],
+    ids=["mild", "mild-chunk16", "strong", "drastic", "three-chunks-of-64",
+         "one-chunk", "three-groups"])
+def test_the_chunked_delta_rule_is_the_token_recurrence(decay, chunk, t):
+    """Float32: the output to 1e-5 of its largest entry and every input's
+    gradient to 1e-4 of its norm, over three chunks and more, three chunks
+    of the cells' 64, a single chunk (as the model's tests run it) and 48
+    chunks of 2, three groups of ``KDA_GROUP`` with the state and its
+    cotangent carried across.  Under the strong decays the cumulative log
+    decay over a chunk falls below -100, where ``e^-G`` alone is not a
+    float32 number: nothing the chunked form makes overflows."""
+    q, k, v, a, beta = recurrence_inputs(int(decay * 10) + chunk, decay, t=t)
     if decay >= 6.0:
         falls = jnp.sum(a.reshape(2, -1, chunk, 3, 16), axis=2)
         assert float(jnp.max(falls)) < -100
-    core = lambda *x: L.kda_core(*x, chunk=chunk, compute_dtype=F32,  # noqa
-                                 group=2)
+    if chunk == 2:
+        assert t // chunk == 3 * L.KDA_GROUP
+    core = lambda *x: L.kda_core(*x, chunk=chunk, compute_dtype=F32)  # noqa
     with jax.default_matmul_precision("highest"):
         got, want = core(q, k, v, a, beta), kimi_linear.delta_rule(
             q, k, v, a, beta)
@@ -138,6 +142,41 @@ def test_the_chunked_delta_rule_is_the_token_recurrence(decay, chunk):
         assert bool(jnp.isfinite(g).all()), name
         assert float(jnp.linalg.norm(g - gw)) \
             <= 1e-4 * float(jnp.linalg.norm(gw)), name
+
+
+def test_the_written_gradient_is_autodiffs_in_bfloat16(monkeypatch):
+    """In bfloat16, as the cells run it: the written backward pass against
+    ``jax.grad`` of the same chunked forward without its rule, over three
+    groups of chunks of 16 (q, k and v in bfloat16, their cotangents too):
+    the outputs equal, every input's gradient to 1e-2 of its norm (a few
+    bfloat16 roundings) from autodiff's and from the float32 token
+    recurrence's."""
+    q, k, v, a, beta = recurrence_inputs(11, 0.5, t=768, h=2, d=32)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    core = lambda *x: L.kda_core(*x, chunk=16,  # noqa: E731
+                                 compute_dtype=jnp.bfloat16)
+    w = jnp.asarray(np.random.RandomState(2).randn(2, 768, 2, 32), F32)
+
+    def outputs_and_grads():
+        return jax.jit(lambda *x: (core(*x), jax.grad(
+            lambda *x: jnp.sum(core(*x) * w), argnums=range(5))(*x)))(
+                q, k, v, a, beta)
+
+    got, grads = outputs_and_grads()
+    monkeypatch.setattr(L, "_kda", lambda cd, *x: L._kda_fwd(cd, *x)[0])
+    want, auto = outputs_and_grads()
+    assert bool(jnp.all(got == want))
+    with jax.default_matmul_precision("highest"):
+        ref = jax.grad(lambda *x: jnp.sum(kimi_linear.delta_rule(*x) * w),
+                       argnums=range(5))(*(x.astype(F32) for x in (
+                           q, k, v, a, beta)))
+    for name, g, ga, gr in zip("q k v a beta".split(), grads, auto, ref):
+        assert g.dtype == ga.dtype, name
+        g, ga = g.astype(F32), ga.astype(F32)
+        assert bool(jnp.isfinite(g).all()), name
+        for other in (ga, gr):
+            assert float(jnp.linalg.norm(g - other)) \
+                <= 1e-2 * float(jnp.linalg.norm(other)), name
 
 
 def test_the_unit_lower_inverse_is_by_substitution():
@@ -316,7 +355,8 @@ def test_it_trains_through_the_rule_and_counts_once():
     from theanompi_tpu import BSP
     names = ("model.kda_layers", "model.mla_layers", "model.kda_chunks",
              "model.conv_layers", "model.full_layers", "model.window_layers",
-             "model.sigmoid_routers", "model.attn_kernel_applications")
+             "model.sigmoid_routers", "model.attn_kernel_applications",
+             "model.kda_state_bytes")
     assert set(names) <= set(telemetry.COUNTS)
     before = {k: telemetry.totals().get(k, (0, 0))[0] for k in names}
     rule = BSP()
@@ -328,15 +368,21 @@ def test_it_trains_through_the_rule_and_counts_once():
     assert math.isfinite(rec.epoch_records[-1]["val_cost"])
     after = {k: telemetry.totals()[k][0] - before[k] for k in names}
     # four kda layers of two 64-token sequences: one chunk each a layer and
-    # sequence; one mla layer, on the reference path here
+    # sequence, whose starting state of 4 heads of 12 x 12 the backward
+    # pass keeps in float32; one mla layer, on the reference path here
     assert after == {"model.kda_layers": 4, "model.mla_layers": 1,
                      "model.kda_chunks": 4 * 2 * 1, "model.conv_layers": 0,
                      "model.full_layers": 0, "model.window_layers": 0,
                      "model.sigmoid_routers": 4,
-                     "model.attn_kernel_applications": 0}
+                     "model.attn_kernel_applications": 0,
+                     "model.kda_state_bytes": 4 * 2 * 1 * 4 * 12 * 12 * 4}
 
 
 def test_the_scopes_reach_the_lowering(model, params, tokens):
+    """Every operator's scope, and the delta rule's three passes under
+    ``kda_core``: the forward, the forward the layer's checkpoint makes
+    again, and the written backward pass, which runs under ``transpose``
+    as autodiff's did."""
     x, y = tokens
     text = jax.jit(jax.grad(lambda p: objective(model, p, x, y))).lower(
         params).as_text(debug_info=True)
@@ -344,6 +390,12 @@ def test_the_scopes_reach_the_lowering(model, params, tokens):
                   "checkpoint/kda/kda_core/", "mla/kv_norm/",
                   "mla/attn_core/", "moe/shared_expert/"):
         assert scope in text, scope
+    for way in ("jvp(block0)/kda/kda_core/",
+                "transpose(jvp(block0))/jvp(block0)/checkpoint/"
+                "rematted_computation/kda/kda_core/while",
+                "transpose(jvp(block0))/jvp(block0)/checkpoint/kda/kda_core/"
+                "while"):
+        assert way in text, way
 
 
 # -- the expert shares -----------------------------------------------------------------

@@ -507,10 +507,13 @@ def test_a_delta_rule_and_a_latent_attention_layer_compile_at_the_kimi_cells_wid
     """One layer of each of the Kimi Linear cell's operators at its widths,
     hidden 2,304 over one sequence of 16,384, forward and backward under
     ``jax.checkpoint`` inside the step's ``shard_map``: the delta rule, 32
-    heads of 128 with 4-tap convolutions, chunked by 64 (a scan of 256
-    carries, the decays never exponentiated alone); the latent attention,
-    32 heads of 192 for q and k over a latent of 512 and heads of 128 for
-    v, on the splash kernel at the tiles ``flash_tiles`` gives 16,384."""
+    heads of 128 with 4-tap convolutions, chunked by 64 (sixteen groups of
+    16 chunks, a scan that carries the state alone in each, forward and in
+    the written backward pass; the decays never exponentiated alone), its
+    temporaries under 5 GB (4.39 GB where this was written); the latent
+    attention, 32 heads of 192 for q and k over a latent of 512 and heads
+    of 128 for v, on the splash kernel at the tiles ``flash_tiles`` gives
+    16,384."""
     mesh = one_chip_mesh
     if kind == "kda":
         op = L.DeltaAttention(2304, 32, 128, 1e-5, name="kda")
@@ -540,6 +543,7 @@ def test_a_delta_rule_and_a_latent_attention_layer_compile_at_the_kimi_cells_wid
     if kind == "kda":
         assert "kda/kda_core/" in text and " while(" in text
         assert "tpu_custom_call" not in text
+        assert temp < 5e9
     else:
         assert text.count('custom_call_target="tpu_custom_call"') == 3
         assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text

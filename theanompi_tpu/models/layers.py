@@ -1122,8 +1122,7 @@ class DeltaAttention(Layer):
             return jnp.dot(o.astype(cd), params["wo"].astype(cd))
 
 
-def kda_core(q, k, v, a, beta, *, chunk: int, compute_dtype,
-             group: int = 16):
+def kda_core(q, k, v, a, beta, *, chunk: int, compute_dtype):
     """The KDA recurrence of :class:`DeltaAttention` over ``q, k [B, T, H,
     dk]``, ``v [B, T, H, dv]``, the log decay ``a [B, T, H, dk]`` (<= 0)
     and ``beta [B, T, H]`` -> ``o [B, T, H, dv]`` float32, in the chunked
@@ -1139,61 +1138,144 @@ def kda_core(q, k, v, a, beta, *, chunk: int, compute_dtype,
 
     Every exponent formed is a difference that cannot be positive (``G``
     falls): ``G_i - G_j`` for ``i >= j`` (:func:`_decayed_pairs`) or
-    ``G_last - G_i``, so no decay, however strong, overflows.  A
-    ``lax.scan`` carries ``S`` (float32) over ``group`` chunks at a time,
-    made again in the backward pass: for those chunks at once everything
-    but the carry, then an inner ``lax.scan`` from chunk to chunk, so that
-    what a step holds of the recurrence is one group's and the states at
-    the groups' edges.  ``T`` and the decays are float32; the pairs'
-    products, ``T``'s applications and the carry's products take
-    ``compute_dtype`` operands and sum in float32."""
-    f32, cd = jnp.float32, compute_dtype
-    b, t, h, dk = q.shape
+    ``G_last - G_i``, so no decay, however strong, overflows.  ``T`` and
+    the decays are float32; the pairs' products, ``T``'s applications and
+    the carries' products take ``compute_dtype`` operands and sum in
+    float32.
+
+    The chunks go in groups of ``KDA_GROUP``: for each group what needs no
+    state (:func:`_kda_chunk_work`) for all its chunks at once, then a
+    ``lax.scan`` that carries ``S`` alone from chunk to chunk, two products
+    a step, then the group's outputs from the states it passed.  The
+    gradient is written out (:func:`_kda_bwd`): the groups in reverse, a
+    ``lax.scan`` carrying ``dS`` alone, two products a step, and the chunk
+    work's own gradient by ``jax.vjp``, which has no carry to
+    differentiate through; the backward pass keeps the state each chunk
+    starts from, float32."""
+    f32 = jnp.float32
+    b, t, h, _ = q.shape
     c = chunk
     n = t // c
     assert n * c == t and c & (c - 1) == 0, (t, c)
+    per = math.gcd(n, KDA_GROUP)
 
-    def chunks(x):                      # [B, T, H, ...] -> [N, B, H, C, ...]
+    def groups(x):              # [B, T, H, ...] -> [N / per, per, B, H, C, ...]
         x = x.reshape(b, n, c, h, *x.shape[3:])
-        return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+        x = x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+        return x.reshape(n // per, per, *x.shape[1:])
 
-    def mm(eq, x, y, **kw):
-        return jnp.einsum(eq, x, y, preferred_element_type=f32, **kw)
+    o = _kda(compute_dtype, *(groups(x) for x in (
+        q, k, v, a.astype(f32), beta[..., None].astype(f32))))
+    return o.reshape(n, b, h, c, -1).transpose(1, 0, 3, 2, 4).reshape(
+        b, t, h, -1)
+
+
+# chunks whose state-free work is made at once: what the recurrence holds of
+# it at a time is one group's
+KDA_GROUP = 16
+
+
+def _mm(eq, x, y):
+    return jnp.einsum(eq, x, y, preferred_element_type=jnp.float32)
+
+
+def _kda_chunk_work(cd, q, k, v, a, beta):
+    """``(w, u, qg, kg, a_qk, fall)`` of :func:`kda_core` for chunks ``[...,
+    C, d]`` (``beta`` ``[..., C, 1]``; ``fall`` ``[..., dk, 1]``): what a
+    chunk needs that does not depend on the state it starts from."""
+    f32 = jnp.float32
+    c = q.shape[-2]
+    q, k, v = (y.astype(f32) for y in (q, k, v))
+    g = jnp.cumsum(a, axis=-2)
+    a_qk, a_kk = _decayed_pairs(jnp.stack([q, k]), k, g, cd)
+    a_qk = a_qk + jnp.sum(q * k, -1)[..., None] * jnp.eye(c, dtype=f32)
+    inv = _unit_lower_inverse(beta * a_kk).astype(cd)
+    w = _mm("...ij,...jd->...id", inv, (beta * k * jnp.exp(g)).astype(cd))
+    u = _mm("...ij,...jd->...id", inv, (beta * v).astype(cd))
+    g_last = g[..., -1:, :]
+    qg, kg = q * jnp.exp(g), k * jnp.exp(g_last - g)
+    fall = jnp.exp(g_last).swapaxes(-1, -2)
+    return (w.astype(cd), u, qg.astype(cd), kg.astype(cd), a_qk.astype(cd),
+            fall)
+
+
+def _varying_zeros(shape, like):
+    """Float32 zeros of ``like``'s ``vma`` type in a ``shard_map``: a
+    scan's first carry."""
+    z = jnp.zeros(shape, jnp.float32)
+    for axis in jax.typeof(like).vma:
+        z = vary(z, axis)
+    return z
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kda(cd, q, k, v, a, beta):
+    return _kda_fwd(cd, q, k, v, a, beta)[0]
+
+
+def _kda_fwd(cd, *ins):
+    """``O`` of grouped chunks ``[N / per, per, B, H, C, ...]``, and what
+    the backward pass keeps: the inputs, the state each chunk starts from
+    and ``D = U - W S`` as the carry used it."""
+    q, v = ins[0], ins[2]
 
     def carry(s, xs):
-        w, u, qg, kg, a_qk, fall = xs
-        s_cd = s.astype(cd)
-        u = u - mm("...id,...de->...ie", w, s_cd)
-        o = mm("...id,...de->...ie", qg, s_cd) \
-            + mm("...ij,...je->...ie", a_qk, u.astype(cd))
-        return fall * s + mm("...id,...ie->...de", kg, u.astype(cd)), o
+        w, u, kg, fall = xs
+        d = (u - _mm("...id,...de->...ie", w, s.astype(cd))).astype(cd)
+        return fall * s + _mm("...id,...ie->...de", kg, d), (s, d)
 
-    @jax.checkpoint
-    def some_chunks(s, xs):
-        q, k, v = (y.astype(f32) for y in xs[:3])     # [per, B, H, C, ...]
-        a, beta = xs[3:]
-        g = jnp.cumsum(a, axis=-2)
-        a_qk, a_kk = _decayed_pairs(jnp.stack([q, k]), k, g, cd)
-        a_qk = a_qk + jnp.sum(q * k, -1)[..., None] * jnp.eye(c, dtype=f32)
-        inv = _unit_lower_inverse(beta * a_kk).astype(cd)
-        w = mm("...ij,...jd->...id", inv, (beta * k * jnp.exp(g)).astype(cd))
-        u = mm("...ij,...jd->...id", inv, (beta * v).astype(cd))
-        g_last = g[..., -1:, :]
-        qg, kg = q * jnp.exp(g), k * jnp.exp(g_last - g)
-        fall = jnp.exp(g_last).swapaxes(-1, -2)             # [.., dk, 1]
-        return jax.lax.scan(carry, s, (
-            w.astype(cd), u, qg.astype(cd), kg.astype(cd), a_qk.astype(cd),
-            fall))
+    def group(s, xs):
+        w, u, qg, kg, a_qk, fall = _kda_chunk_work(cd, *xs)
+        s, (ss, d) = jax.lax.scan(carry, s, (w, u, kg, fall))
+        return s, (_mm("...id,...de->...ie", qg, ss.astype(cd))
+                   + _mm("...ij,...je->...ie", a_qk, d), ss, d)
 
-    per = math.gcd(n, group)
-    xs = tuple(chunks(x).reshape(n // per, per, b, h, c, -1)
-               for x in (q, k, v, a.astype(f32), beta[..., None].astype(f32)))
-    s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
-    for axis in jax.typeof(q).vma:      # of the inputs' type in a shard_map
-        s0 = vary(s0, axis)
-    _, o = jax.lax.scan(some_chunks, s0, xs)
-    o = o.reshape(n, b, h, c, -1)
-    return o.transpose(1, 0, 3, 2, 4).reshape(b, t, h, -1)
+    _, (o, s, d) = jax.lax.scan(group, _varying_zeros(
+        (*q.shape[2:4], q.shape[-1], v.shape[-1]), q), ins)
+    return o, (ins, s, d)
+
+
+def _kda_bwd(cd, res, do):
+    """The inputs' cotangents from ``O``'s, the groups in reverse: the
+    group's chunk work made again under ``jax.vjp``; a ``lax.scan``
+    carrying ``dS`` alone, two products a step (``dD = A^T dO + K' dS'``
+    as one product over ``[dO | dS']``, ``dS = e^G_last dS' + [Q e^G |
+    -W]^T [dO | dD]`` as another), that passes ``dS'`` (of the state the
+    chunk hands on), ``dD`` and the last decay's cotangent; then the chunk
+    work's cotangents for the group's chunks at once, pulled back."""
+    ins, s, d = res
+    cat = functools.partial(jnp.concatenate, axis=-2)
+
+    def carry(ds, xs):
+        ak, qw, fall, s, do = xs
+        dd = _mm("...ji,...je->...ie", ak, cat([do, ds.astype(cd)]))
+        return fall * ds + _mm("...id,...ie->...de", qw, cat(
+            [do, dd.astype(cd)])), (ds.astype(cd), dd, jnp.sum(
+                s * ds, -1, keepdims=True))
+
+    def group(ds, xs):
+        *xs, s, d, do = xs
+        work, back = jax.vjp(functools.partial(_kda_chunk_work, cd), *xs)
+        w, u, qg, kg, a_qk, fall = work
+        ds, (ds_out, dd, dfall) = jax.lax.scan(carry, ds, (
+            cat([a_qk, kg.swapaxes(-1, -2)]), cat([qg, -w]), fall, s, do),
+            reverse=True)
+        s, dd_cd = s.astype(cd), dd.astype(cd)
+        c = jnp.arange(do.shape[-2])
+        cots = (-_mm("...ie,...de->...id", dd_cd, s),               # W
+                dd,                                                 # U
+                _mm("...ie,...de->...id", do, s),                   # Q e^G
+                _mm("...ie,...de->...id", d, ds_out),               # K e^..
+                jnp.where(c[:, None] >= c, _mm("...ie,...je->...ij", do,
+                                               d), 0.0),            # A
+                dfall)                                              # e^G_l
+        return ds, back(tuple(x.astype(y.dtype) for x, y in zip(cots, work)))
+
+    return jax.lax.scan(group, _varying_zeros(s.shape[2:], s),
+                        (*ins, s, d, do.astype(cd)), reverse=True)[1]
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
 
 
 def _parting(c: int):

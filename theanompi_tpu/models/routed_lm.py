@@ -73,7 +73,9 @@ routing never enters a walk's loop), ``model.window_layers``,
 step: kda layers x sequences x chunks a sequence), ``model.sigmoid_routers``,
 ``model.attn_kernel_applications``, ``model.head_logit_products``,
 ``model.kept_residual_bytes`` (the bytes of the named products a step
-keeps, from the layers' shapes).
+keeps, from the layers' shapes), ``model.kda_state_bytes`` (the bytes of
+the float32 states the delta rule's chunks start from, which its backward
+pass keeps: kda chunks x heads x head_dim^2 x 4).
 """
 
 from __future__ import annotations
@@ -326,9 +328,11 @@ class RoutedLM(ModelBase):
                 isinstance(a, L.ShortConv) for a in ops))
             telemetry.count("model.kda_layers", kda)
             telemetry.count("model.mla_layers", mla)
-            telemetry.count("model.kda_chunks", sum(
-                rows * self.seq_len // min(a.CHUNK, self.seq_len)
-                for a in ops if isinstance(a, L.DeltaAttention)))
+            chunks = [(a, rows * self.seq_len // min(a.CHUNK, self.seq_len))
+                      for a in ops if isinstance(a, L.DeltaAttention)]
+            telemetry.count("model.kda_chunks", sum(n for _, n in chunks))
+            telemetry.count("model.kda_state_bytes", sum(
+                n * a.n_head * a.hd * a.hd * 4 for a, n in chunks))
             telemetry.count("model.sigmoid_routers", sum(
                 ff.scoring == "sigmoid" for ff in sparse))
             telemetry.count("model.attn_kernel_applications", sum(

@@ -107,7 +107,10 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "model.conv_layers", "model.sigmoid_routers",
           "model.kda_layers", "model.mla_layers", "model.kda_chunks",
           # bytes of named products its checkpoints keep a step
-          "model.kept_residual_bytes")
+          "model.kept_residual_bytes",
+          # bytes of the delta rule's chunk-start states (float32) its
+          # backward pass keeps a step, summed over the kda layers
+          "model.kda_state_bytes")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
