@@ -179,6 +179,39 @@ def test_the_written_gradient_is_autodiffs_in_bfloat16(monkeypatch):
                 <= 1e-2 * float(jnp.linalg.norm(other)), name
 
 
+@pytest.mark.parametrize("c, decay, beta", [
+    (2, 0.5, (0.0, 1.0)), (16, 0.5, (0.0, 1.0)), (64, 0.5, (0.0, 1.0)),
+    (16, 20.0, (0.0, 1.0)), (64, 6.0, (0.0, 1.0)), (16, 0.5, (0.0, 1e-3)),
+    (64, 0.5, (1 - 1e-3, 1.0))],
+    ids=["chunk2", "chunk16", "chunk64", "strong-chunk16", "strong-chunk64",
+         "beta-near-0", "beta-near-1"])
+def test_the_written_chunk_pull_back_is_autodiffs(c, decay, beta):
+    """The chunk work's pull-back written out (``T``'s gradient as ``-T^T
+    dT T^T``, the pairs' transpose level by level, from ``T`` and ``a_kk``
+    as the forward kept them) against ``jax.vjp`` of the chunk work, in
+    float32 at the highest precision, random cotangents of all six
+    outputs: every input's cotangent to 1e-5 of its norm, for chunks of
+    2, 16 and 64, decays whose fall over a chunk is below -100, and
+    ``beta`` near 0 and near 1."""
+    r = np.random.RandomState(c + int(decay))
+    q, k, v, a, _ = recurrence_inputs(c, decay, t=c, h=4, d=16)
+    q, k, v, a = (x.swapaxes(1, 2) for x in (q, k, v, a))  # [B, H, C, d]
+    b = jnp.asarray(r.uniform(*beta, (2, 4, c, 1)), F32)
+    if decay > 1:
+        assert float(jnp.max(jnp.sum(a, -2))) < -100
+    with jax.default_matmul_precision("highest"):
+        work, back = jax.vjp(lambda *x: L._kda_chunk_work(F32, *x)[0],
+                             q, k, v, a, b)
+        _, (t, a_kk) = L._kda_chunk_work(F32, q, k, v, a, b)
+        cots = tuple(jnp.asarray(r.randn(*w.shape), F32) for w in work)
+        want = back(cots)
+        got = L._kda_chunk_back(F32, q, k, v, a, b, t, a_kk, cots)
+    for name, g, w in zip("q k v a beta".split(), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert float(jnp.linalg.norm(g - w)) \
+            <= 1e-5 * float(jnp.linalg.norm(w)), name
+
+
 def test_the_unit_lower_inverse_is_by_substitution():
     """``(I + L)^-1`` against a float64 inverse: random entries, and all
     ones below the diagonal, whose inverse has ones and minus ones while
@@ -356,7 +389,7 @@ def test_it_trains_through_the_rule_and_counts_once():
     names = ("model.kda_layers", "model.mla_layers", "model.kda_chunks",
              "model.conv_layers", "model.full_layers", "model.window_layers",
              "model.sigmoid_routers", "model.attn_kernel_applications",
-             "model.kda_state_bytes")
+             "model.kda_state_bytes", "model.kda_chunk_kept_bytes")
     assert set(names) <= set(telemetry.COUNTS)
     before = {k: telemetry.totals().get(k, (0, 0))[0] for k in names}
     rule = BSP()
@@ -369,13 +402,17 @@ def test_it_trains_through_the_rule_and_counts_once():
     after = {k: telemetry.totals()[k][0] - before[k] for k in names}
     # four kda layers of two 64-token sequences: one chunk each a layer and
     # sequence, whose starting state of 4 heads of 12 x 12 the backward
-    # pass keeps in float32; one mla layer, on the reference path here
+    # pass keeps in float32, and of whose work of 64 x 64 a head T with
+    # a_kk (one float32 array) and a_qk (bfloat16, the default compute
+    # dtype); one mla layer, on the reference path here
     assert after == {"model.kda_layers": 4, "model.mla_layers": 1,
                      "model.kda_chunks": 4 * 2 * 1, "model.conv_layers": 0,
                      "model.full_layers": 0, "model.window_layers": 0,
                      "model.sigmoid_routers": 4,
                      "model.attn_kernel_applications": 0,
-                     "model.kda_state_bytes": 4 * 2 * 1 * 4 * 12 * 12 * 4}
+                     "model.kda_state_bytes": 4 * 2 * 1 * 4 * 12 * 12 * 4,
+                     "model.kda_chunk_kept_bytes":
+                         4 * 2 * 1 * 4 * 64 * 64 * (4 + 2)}
 
 
 def test_the_scopes_reach_the_lowering(model, params, tokens):

@@ -510,7 +510,12 @@ def test_a_delta_rule_and_a_latent_attention_layer_compile_at_the_kimi_cells_wid
     heads of 128 with 4-tap convolutions, chunked by 64 (sixteen groups of
     16 chunks, a scan that carries the state alone in each, forward and in
     the written backward pass; the decays never exponentiated alone), its
-    temporaries under 5 GB (4.39 GB where this was written); the latent
+    temporaries under 5 GB (4.39 GB where this was written, 4.60 GB since
+    the backward pass reads ``T``, ``a_kk`` and ``a_qk`` from the forward
+    rule); ``T``'s six levels at the highest precision in the forward and
+    again in its recomputation, and nothing of them in the backward pass
+    but ``T``'s gradient, two products (58 such products when the
+    backward pass transposed the levels); the latent
     attention, 32 heads of 192 for q and k over a latent of 512 and heads
     of 128 for v, on the splash kernel at the tiles ``flash_tiles`` gives
     16,384."""
@@ -544,6 +549,10 @@ def test_a_delta_rule_and_a_latent_attention_layer_compile_at_the_kimi_cells_wid
         assert "kda/kda_core/" in text and " while(" in text
         assert "tpu_custom_call" not in text
         assert temp < 5e9
+        # a level is a product of three operands, two instructions
+        highest = re.findall(r"= \S+ (?:convolution|dot)\(.*operand_"
+                             r"precision=\{highest,highest\}", text)
+        assert len(highest) == 2 * 6 * 2 + 2
     else:
         assert text.count('custom_call_target="tpu_custom_call"') == 3
         assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text

@@ -1149,9 +1149,10 @@ def kda_core(q, k, v, a, beta, *, chunk: int, compute_dtype):
     a step, then the group's outputs from the states it passed.  The
     gradient is written out (:func:`_kda_bwd`): the groups in reverse, a
     ``lax.scan`` carrying ``dS`` alone, two products a step, and the chunk
-    work's own gradient by ``jax.vjp``, which has no carry to
-    differentiate through; the backward pass keeps the state each chunk
-    starts from, float32."""
+    work's own gradient written out too (:func:`_kda_chunk_back`); the
+    backward pass keeps the state each chunk starts from, float32, and of
+    the chunk work ``T``, ``a_kk`` and ``a_qk`` (:func:`_kda_pack`), so
+    that it makes nothing of the pairs or of ``T`` again."""
     f32 = jnp.float32
     b, t, h, _ = q.shape
     c = chunk
@@ -1182,21 +1183,73 @@ def _mm(eq, x, y):
 def _kda_chunk_work(cd, q, k, v, a, beta):
     """``(w, u, qg, kg, a_qk, fall)`` of :func:`kda_core` for chunks ``[...,
     C, d]`` (``beta`` ``[..., C, 1]``; ``fall`` ``[..., dk, 1]``): what a
-    chunk needs that does not depend on the state it starts from."""
+    chunk needs that does not depend on the state it starts from; and
+    ``(T, a_kk)`` (float32), what its pull-back (:func:`_kda_chunk_back`)
+    reads instead of making them again."""
     f32 = jnp.float32
     c = q.shape[-2]
     q, k, v = (y.astype(f32) for y in (q, k, v))
     g = jnp.cumsum(a, axis=-2)
     a_qk, a_kk = _decayed_pairs(jnp.stack([q, k]), k, g, cd)
     a_qk = a_qk + jnp.sum(q * k, -1)[..., None] * jnp.eye(c, dtype=f32)
-    inv = _unit_lower_inverse(beta * a_kk).astype(cd)
+    t = _unit_lower_inverse(beta * a_kk)
+    w, u, qg, kg, fall = _kda_from_inverse(cd, t, q, k, v, g, beta)
+    return (w, u, qg, kg, a_qk.astype(cd), fall), (t, a_kk)
+
+
+def _kda_from_inverse(cd, t, q, k, v, g, beta):
+    """``(w, u, qg, kg, fall)`` of :func:`_kda_chunk_work` from ``T`` and
+    ``G``, the cumulative log decay (``q, k, v`` float32)."""
+    inv = t.astype(cd)
     w = _mm("...ij,...jd->...id", inv, (beta * k * jnp.exp(g)).astype(cd))
     u = _mm("...ij,...jd->...id", inv, (beta * v).astype(cd))
     g_last = g[..., -1:, :]
     qg, kg = q * jnp.exp(g), k * jnp.exp(g_last - g)
     fall = jnp.exp(g_last).swapaxes(-1, -2)
-    return (w.astype(cd), u, qg.astype(cd), kg.astype(cd), a_qk.astype(cd),
-            fall)
+    return w.astype(cd), u, qg.astype(cd), kg.astype(cd), fall
+
+
+def _kda_chunk_back(cd, q, k, v, a, beta, t, a_kk, cots):
+    """The cotangents of :func:`_kda_chunk_work`'s inputs from those of
+    its first six outputs, ``cots``, written out with ``T`` and ``a_kk`` as
+    the forward made them: ``dT = dW (beta K e^G)^T + dU (beta V)^T``;
+    ``T = (I + L)^-1`` gives ``dL = -T^T dT T^T`` on the strictly lower
+    triangle, two products at the highest precision where autodiff
+    transposes every level of :func:`_unit_lower_inverse`; the pairs by
+    :func:`_decayed_pairs_back`; ``dG`` summed back to ``da`` in reverse.
+    Products take ``compute_dtype`` operands as the forward's do."""
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    dw, du, dqg, dkg, da_qk, dfall = (x.astype(f32) for x in cots)
+    c = q.shape[-2]
+    dtypes = [y.dtype for y in (q, k, v)]
+    q, k, v = (y.astype(f32) for y in (q, k, v))
+    g = jnp.cumsum(a, axis=-2)
+    eg, g_last = jnp.exp(g), g[..., -1:, :]
+    ekl = jnp.exp(g_last - g)
+    kb = beta * k * eg
+    inv, dw, du = t.astype(cd), dw.astype(cd), du.astype(cd)
+    dinv = (_mm("...id,...jd->...ij", dw, kb.astype(cd))
+            + _mm("...id,...jd->...ij", du, (beta * v).astype(cd)))
+    dkb = _mm("...ji,...jd->...id", inv, dw)
+    dvb = _mm("...ji,...jd->...id", inv, du)
+    dl = jnp.where(np.tri(c, k=-1, dtype=bool), -jnp.einsum(
+        "...ji,...jk,...lk->...il", t, dinv, t, precision=hi), 0.0)
+    # the diagonal q.k of a_qk, then the pairs below it
+    diag = jnp.diagonal(da_qk, axis1=-2, axis2=-1)[..., None]
+    (dq, dk), dcol, dg = _decayed_pairs_back(
+        jnp.stack([q, k]), k, g, cd, jnp.stack([da_qk, beta * dl]))
+    dq = dq + diag * k + dqg * eg
+    dk = dk + dcol + diag * q + dkb * beta * eg + dkg * ekl
+    dv = dvb * beta
+    dbeta = (jnp.sum(dl * a_kk, -1, keepdims=True)
+             + jnp.sum(dkb * k * eg + dvb * v, -1, keepdims=True))
+    dkl = dkg * k * ekl
+    dg = dg + dkb * kb + dqg * q * eg - dkl
+    dg = dg.at[..., -1:, :].add(jnp.sum(dkl, -2, keepdims=True)
+                                + dfall.swapaxes(-1, -2) * jnp.exp(g_last))
+    da = jax.lax.cumsum(dg, axis=dg.ndim - 2, reverse=True)
+    return (dq.astype(dtypes[0]), dk.astype(dtypes[1]), dv.astype(dtypes[2]),
+            da, dbeta)
 
 
 def _varying_zeros(shape, like):
@@ -1208,6 +1261,29 @@ def _varying_zeros(shape, like):
     return z
 
 
+def _kda_pack(t, a_kk, a_qk):
+    """``T^T`` (unit upper: the backward pass takes ``T`` turned) and
+    ``a_kk`` (strictly lower) as one float32 ``[..., C, C]``, and ``a_qk``,
+    each with 128 entries a row where ``C^2`` allows: a TPU tile holds 128
+    in its minor dimension, and ``[C, C]`` of 64 would fill half of it."""
+    packed = jnp.where(np.tri(t.shape[-1], k=-1, dtype=bool), a_kk,
+                       t.swapaxes(-1, -2))
+
+    def lanes(x):
+        c2 = x.shape[-1] * x.shape[-2]
+        return x.reshape(*x.shape[:-2], c2 // 128, 128) if c2 % 128 == 0 \
+            else x
+    return lanes(packed), lanes(a_qk)
+
+
+def _kda_unpack(kept, c):
+    """``(T, a_kk, a_qk)`` of ``C x C`` from :func:`_kda_pack`'s form."""
+    packed, a_qk = (x.reshape(*x.shape[:-2], c, c) for x in kept)
+    below = np.tri(c, k=-1, dtype=bool)
+    return (jnp.where(below, 0.0, packed).swapaxes(-1, -2),
+            jnp.where(below, packed, 0.0), a_qk)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _kda(cd, q, k, v, a, beta):
     return _kda_fwd(cd, q, k, v, a, beta)[0]
@@ -1215,8 +1291,9 @@ def _kda(cd, q, k, v, a, beta):
 
 def _kda_fwd(cd, *ins):
     """``O`` of grouped chunks ``[N / per, per, B, H, C, ...]``, and what
-    the backward pass keeps: the inputs, the state each chunk starts from
-    and ``D = U - W S`` as the carry used it."""
+    the backward pass keeps: the inputs, the state each chunk starts from,
+    ``D = U - W S`` as the carry used it, and of the chunk work ``T``,
+    ``a_kk`` (float32) and ``a_qk`` (``compute_dtype``)."""
     q, v = ins[0], ins[2]
 
     def carry(s, xs):
@@ -1225,25 +1302,28 @@ def _kda_fwd(cd, *ins):
         return fall * s + _mm("...id,...ie->...de", kg, d), (s, d)
 
     def group(s, xs):
-        w, u, qg, kg, a_qk, fall = _kda_chunk_work(cd, *xs)
+        (w, u, qg, kg, a_qk, fall), (t, a_kk) = _kda_chunk_work(cd, *xs)
         s, (ss, d) = jax.lax.scan(carry, s, (w, u, kg, fall))
         return s, (_mm("...id,...de->...ie", qg, ss.astype(cd))
-                   + _mm("...ij,...je->...ie", a_qk, d), ss, d)
+                   + _mm("...ij,...je->...ie", a_qk, d), ss, d,
+                   _kda_pack(t, a_kk, a_qk))
 
-    _, (o, s, d) = jax.lax.scan(group, _varying_zeros(
+    _, (o, s, d, kept) = jax.lax.scan(group, _varying_zeros(
         (*q.shape[2:4], q.shape[-1], v.shape[-1]), q), ins)
-    return o, (ins, s, d)
+    return o, (ins, s, d, kept)
 
 
 def _kda_bwd(cd, res, do):
-    """The inputs' cotangents from ``O``'s, the groups in reverse: the
-    group's chunk work made again under ``jax.vjp``; a ``lax.scan``
-    carrying ``dS`` alone, two products a step (``dD = A^T dO + K' dS'``
-    as one product over ``[dO | dS']``, ``dS = e^G_last dS' + [Q e^G |
-    -W]^T [dO | dD]`` as another), that passes ``dS'`` (of the state the
-    chunk hands on), ``dD`` and the last decay's cotangent; then the chunk
-    work's cotangents for the group's chunks at once, pulled back."""
-    ins, s, d = res
+    """The inputs' cotangents from ``O``'s, the groups in reverse: ``W``
+    made again from the kept ``T``, ``Q e^G``, ``K e^{G_last - G}`` and
+    the last decay from ``G``; a ``lax.scan`` carrying ``dS`` alone, two
+    products a step (``dD = A^T dO + K' dS'`` as one product over ``[dO |
+    dS']``, ``dS = e^G_last dS' + [Q e^G | -W]^T [dO | dD]`` as another),
+    that passes ``dS'`` (of the state the chunk hands on), ``dD`` and the
+    last decay's cotangent; then the chunk work's cotangents for the
+    group's chunks at once, pulled back by :func:`_kda_chunk_back`."""
+    ins, s, d, kept = res
+    f32 = jnp.float32
     cat = functools.partial(jnp.concatenate, axis=-2)
 
     def carry(ds, xs):
@@ -1254,9 +1334,12 @@ def _kda_bwd(cd, res, do):
                 s * ds, -1, keepdims=True))
 
     def group(ds, xs):
-        *xs, s, d, do = xs
-        work, back = jax.vjp(functools.partial(_kda_chunk_work, cd), *xs)
-        w, u, qg, kg, a_qk, fall = work
+        ins, s, d, kept, do = xs
+        t, a_kk, a_qk = _kda_unpack(kept, do.shape[-2])
+        q, k, v, a, beta = ins
+        w, _, qg, kg, fall = _kda_from_inverse(
+            cd, t, *(y.astype(f32) for y in (q, k, v)),
+            jnp.cumsum(a, axis=-2), beta)
         ds, (ds_out, dd, dfall) = jax.lax.scan(carry, ds, (
             cat([a_qk, kg.swapaxes(-1, -2)]), cat([qg, -w]), fall, s, do),
             reverse=True)
@@ -1269,10 +1352,10 @@ def _kda_bwd(cd, res, do):
                 jnp.where(c[:, None] >= c, _mm("...ie,...je->...ij", do,
                                                d), 0.0),            # A
                 dfall)                                              # e^G_l
-        return ds, back(tuple(x.astype(y.dtype) for x, y in zip(cots, work)))
+        return ds, _kda_chunk_back(cd, *ins, t, a_kk, cots)
 
     return jax.lax.scan(group, _varying_zeros(s.shape[2:], s),
-                        (*ins, s, d, do.astype(cd)), reverse=True)[1]
+                        (ins, s, d, kept, do.astype(cd)), reverse=True)[1]
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
@@ -1294,6 +1377,20 @@ def _parting(c: int):
     return out
 
 
+def _level_decays(g, b):
+    """``(e^{g_i - g_r}, e^{g_r - g_j})`` of :func:`_decayed_pairs`' level
+    of halves ``b``, ``r`` the last position of each left half: the first
+    scales the right halves' rows, the second the left halves' columns,
+    and each is 1 on the side it does not scale."""
+    *lead, c, d = g.shape
+    halves = g.reshape(*lead, c // (2 * b), 2 * b, d)
+    ref = jnp.broadcast_to(halves[..., b - 1:b, :],
+                           halves.shape).reshape(g.shape)
+    right = ((np.arange(c) // b) % 2 == 1)[:, None]
+    return (jnp.exp(jnp.where(right, g - ref, 0.0)),
+            jnp.exp(jnp.where(right, 0.0, ref - g)))
+
+
 def _decayed_pairs(x, col, g, compute_dtype):
     """``[..., C, C]``, strictly lower: ``sum_c x_ic col_jc e^{g_ic -
     g_jc}`` for ``j < i``, where ``g`` falls along ``C`` (``x``, ``col``,
@@ -1305,19 +1402,32 @@ def _decayed_pairs(x, col, g, compute_dtype):
     left half and a column of the right half take nought, and are
     masked)."""
     f32 = jnp.float32
-    *lead, c, d = col.shape
     out = 0.0
-    for b, mask in _parting(c):
-        halves = g.reshape(*lead, c // (2 * b), 2 * b, d)
-        ref = jnp.broadcast_to(halves[..., b - 1:b, :],
-                               halves.shape).reshape(g.shape)
-        right = ((np.arange(c) // b) % 2 == 1)[:, None]
-        rows = x * jnp.exp(jnp.where(right, g - ref, 0.0))
-        cols = col * jnp.exp(jnp.where(right, 0.0, ref - g))
+    for b, mask in _parting(col.shape[-2]):
+        er, ec = _level_decays(g, b)
+        rows, cols = x * er, col * ec
         out = out + jnp.where(mask, jnp.einsum(
             "...id,...jd->...ij", rows.astype(compute_dtype),
             cols.astype(compute_dtype), preferred_element_type=f32), 0.0)
     return out
+
+
+def _decayed_pairs_back(x, col, g, compute_dtype, dout):
+    """The cotangents of :func:`_decayed_pairs`' ``x``, ``col`` and ``g``
+    from its output's ``dout``, for ``x`` of one axis more than ``col``
+    (``[S, ..., C, d]``, ``dout`` ``[S, ..., C, C]``): at each level two
+    products, the rows' and the columns' (the latter summed over ``S`` in
+    the product).  A pair depends on ``g`` through ``g_i - g_j`` alone (the
+    reference cancels), so ``dg = sum_S x dx - col dcol``."""
+    dx = dcol = 0.0
+    for b, mask in _parting(col.shape[-2]):
+        er, ec = _level_decays(g, b)
+        dp = jnp.where(mask, dout, 0.0).astype(compute_dtype)
+        dx = dx + er * _mm("...jd,s...ij->s...id",
+                           (col * ec).astype(compute_dtype), dp)
+        dcol = dcol + ec * _mm("s...ij,s...id->...jd", dp,
+                               (x * er).astype(compute_dtype))
+    return dx, dcol, jnp.sum(x * dx, 0) - col * dcol
 
 
 def _unit_lower_inverse(l):

@@ -75,7 +75,11 @@ step: kda layers x sequences x chunks a sequence), ``model.sigmoid_routers``,
 ``model.kept_residual_bytes`` (the bytes of the named products a step
 keeps, from the layers' shapes), ``model.kda_state_bytes`` (the bytes of
 the float32 states the delta rule's chunks start from, which its backward
-pass keeps: kda chunks x heads x head_dim^2 x 4).
+pass keeps: kda chunks x heads x head_dim^2 x 4),
+``model.kda_chunk_kept_bytes`` (the bytes of chunk work its backward pass
+reads from the forward rule instead of making again: ``T`` and ``a_kk``
+packed in one float32 ``C x C`` and ``a_qk`` in ``compute_dtype``, kda
+chunks x heads x C^2 x (4 + 2) in bfloat16).
 """
 
 from __future__ import annotations
@@ -333,6 +337,10 @@ class RoutedLM(ModelBase):
             telemetry.count("model.kda_chunks", sum(n for _, n in chunks))
             telemetry.count("model.kda_state_bytes", sum(
                 n * a.n_head * a.hd * a.hd * 4 for a, n in chunks))
+            telemetry.count("model.kda_chunk_kept_bytes", sum(
+                n * a.n_head * min(a.CHUNK, self.seq_len) ** 2
+                * (4 + jnp.dtype(a.compute_dtype).itemsize)
+                for a, n in chunks))
             telemetry.count("model.sigmoid_routers", sum(
                 ff.scoring == "sigmoid" for ff in sparse))
             telemetry.count("model.attn_kernel_applications", sum(

@@ -110,7 +110,10 @@ COUNTS = ("input.dequeues", "input.unready_dequeues", "input.bytes_put",
           "model.kept_residual_bytes",
           # bytes of the delta rule's chunk-start states (float32) its
           # backward pass keeps a step, summed over the kda layers
-          "model.kda_state_bytes")
+          "model.kda_state_bytes",
+          # bytes of chunk work (T, a_kk, a_qk) its backward pass reads
+          # from the forward rule a step, summed over the kda layers
+          "model.kda_chunk_kept_bytes")
 # a 20 s window at 20 steps/s and 30 spans a step, with room to spare
 RING_SPANS = 16384
 # jax.monitoring duration events -> span names.  jax wraps
