@@ -20,33 +20,10 @@ near the full-overlap bound.  The reference's own headline table
 (SURVEY.md §6: time-per-5120-images vs worker count) is the shape this
 mirrors.
 
-**Bucketed-pipeline model (round 9, ISSUE 13).**  The one-shot bounds
-above say nothing about WHERE between them a config lands; the bucketed
-wire (``parallel/buckets.py``) makes that predictable.  With the
-payload split into ``n = ceil(payload_bytes / bucket_bytes)`` buckets:
-
-    t_comm   = n·LAT + wire_bytes / BW          (latency + bandwidth)
-    fill     = LAT + (wire_bytes / n) / BW      (first bucket: nothing
-                                                 can hide before its
-                                                 producers finish)
-    credit   = min(t_comm − fill, TAIL·t_step)  (overlap credit, capped
-                                                 by the backprop tail —
-                                                 there is no compute
-                                                 left to hide behind
-                                                 once backprop drains)
-    exposed  = t_comm − credit
-    eff      = t_step / (t_step + exposed)
-
-``LAT`` (:data:`COLL_LATENCY_S`) is the per-collective setup cost that
-makes n → ∞ a loss, not a win; ``TAIL`` (:data:`BACKPROP_TAIL_FRAC`)
-approximates the backward share of the step a reduction can overlap
-(grads become final back-to-front through roughly the second half).
-A monolithic wire is the n = 1 case: fill = t_comm, credit = 0 — the
-``eff_no_overlap`` bound, recovered exactly.  Each row reports the
-monolithic and the 4 MiB-bucketed prediction side by side, and
-``pred_exposed_comm_secs`` is emitted per row so the measured
-``exposed_comm_secs`` trace column of the r9 matrix rows (bucketed +
-monolithic controls) can be compared prediction-vs-trace per config.
+Each row also reports the ``monolithic`` prediction: one collective's
+setup latency ``LAT`` (:data:`COLL_LATENCY_S`) plus ``wire_bytes / BW``,
+none of it hidden, and ``pred_exposed_comm_secs`` beside the measured
+``exposed_comm_secs`` trace column where a matrix row has one.
 
 Wire-bytes-per-step models (P = param count, b = wire bytes/elem,
 N = chips; ring collectives over a 1D ICI ring, per-chip bytes):
@@ -94,35 +71,21 @@ ICI_GBPS = 90e9          # bidirectional 1D-ring effective, v5e (see above)
 SENS = (45e9, 180e9)     # sensitivity band
 CHIP_COUNTS = (8, 32)
 
-# bucketed-pipeline model constants (docstring above): per-collective
-# setup latency (dispatch + ICI rendezvous — order of the ~µs published
-# for small TPU collectives; the 2x band on BW dwarfs its uncertainty),
-# the planner default bucket size, and the backprop-tail share of the
-# step available as overlap credit
+# per-collective setup latency (dispatch + ICI rendezvous — order of the
+# ~µs published for small TPU collectives; the 2x band on BW dwarfs its
+# uncertainty)
 COLL_LATENCY_S = 5e-6
-DEFAULT_BUCKET_BYTES = 4 << 20
-BACKPROP_TAIL_FRAC = 0.5
 
 
-def bucketed_exchange(wire_b: float, payload_b: float, t_step: float,
-                      bucket_bytes: int) -> dict:
-    """Exposed-comm prediction for one exchange under the bucketed
-    -pipeline model.  ``wire_b`` is what actually crosses ICI (compressed
-    strategies ship less), ``payload_b`` is what the planner slices —
-    strategy-dependent, see :func:`bucket_payload_bytes`;
-    ``bucket_bytes <= 0`` or a payload smaller than one bucket is the
-    monolithic n = 1 case."""
-    n = 1 if bucket_bytes <= 0 else max(1, -(-int(payload_b) // int(bucket_bytes)))
-    t_comm = n * COLL_LATENCY_S + wire_b / ICI_GBPS
-    fill = COLL_LATENCY_S + (wire_b / n) / ICI_GBPS
-    credit = min(max(0.0, t_comm - fill), BACKPROP_TAIL_FRAC * t_step)
-    exposed = t_comm - credit
-    return {"n_buckets": n,
-            "t_comm_s": round(t_comm, 6),
-            "pred_exposed_comm_secs": round(exposed, 6),
-            "pred_overlap_ratio": (round(1.0 - exposed / t_comm, 4)
-                                   if t_comm > 0 else None),
-            "eff": round(t_step / (t_step + exposed), 4)}
+def monolithic_exchange(wire_b: float, t_step: float) -> dict:
+    """Exposed-comm prediction for one monolithic exchange: ``wire_b``
+    (what actually crosses ICI) behind one collective's setup latency,
+    none of it hidden behind compute."""
+    t_comm = COLL_LATENCY_S + wire_b / ICI_GBPS
+    return {"t_comm_s": round(t_comm, 6),
+            "pred_exposed_comm_secs": round(t_comm, 6),
+            "eff": round(t_step / (t_step + t_comm), 4)}
+
 
 def pipeline_bubble(pp: int, v: int, m: int, t_chunk: float = 1.0,
                     t_hop: float = 0.0) -> dict:
@@ -294,22 +257,6 @@ def wire_bytes(strategy: str, P: int, rows_plus_cols: int, n: int,
     raise ValueError(strategy)
 
 
-def bucket_payload_bytes(strategy: str, P: int, powersgd_dense: int) -> float:
-    """What the bucket planner actually SLICES per strategy — the bucket
-    count (and so the latency term) follows this, not the raw fp32
-    gradient: the psum-family rules and onebit bucket the fp32 payload
-    (onebit slices the error-fed fp32 vector before packing), topk
-    buckets its packed (bf16 val + i16 offset = 4·k_c bytes) chunk rows
-    (TopK.CHUNK=8192, ratio 1% — strategies.TopK._rows_per_bucket), and
-    powersgd buckets only the dense remainder its low-rank factors skip."""
-    if strategy == "topk":
-        chunk, k_c = 8192, max(1, round(8192 * 0.01))
-        return 4.0 * k_c * (P / chunk)
-    if strategy.startswith("powersgd"):
-        return powersgd_dense * 4.0
-    return P * 4.0
-
-
 def newest_matrix(paths: list) -> dict:
     """config -> result dict from ``perf_matrix_rN.jsonl`` files, newest
     round wins; rows noted as degraded readings are excluded."""
@@ -369,16 +316,12 @@ def main() -> int:
         row.update(measured_ips_per_chip=ips, t_step_s=round(t_step, 6),
                    params=P)
         # measured overlap evidence (trace columns) when the r9
-        # matrix rows exist — the prediction-vs-trace comparison per row
-        for m_res, key in ((measured.get(cfg + "-trace") or res,
-                            "measured_monolithic"),
-                           (measured.get(cfg + "-bucket4m-trace"),
-                            "measured_bucket4m")):
-            if m_res and m_res.get("exposed_comm_secs") is not None:
-                row[key] = {
-                    "exposed_comm_secs": m_res["exposed_comm_secs"],
-                    "overlap_ratio": m_res.get("overlap_ratio"),
-                    "n_buckets": m_res.get("n_buckets")}
+        # matrix row exists — the prediction-vs-trace comparison per row
+        m_res = measured.get(cfg + "-trace") or res
+        if m_res.get("exposed_comm_secs") is not None:
+            row["measured_monolithic"] = {
+                "exposed_comm_secs": m_res["exposed_comm_secs"],
+                "overlap_ratio": m_res.get("overlap_ratio")}
         cells = ""
         for n in CHIP_COUNTS:
             wb = wire_bytes(strat, P, rc, n, dense)
@@ -391,23 +334,14 @@ def main() -> int:
                 "eff_full_overlap": round(full_ovl, 4),
                 "eff_band_low": round(t_step / (t_step + wb / SENS[0]), 4),
                 "eff_band_high": round(t_step / (t_step + wb / SENS[1]), 4),
-                # the bucketed-pipeline refinement (docstring): where
-                # between the bounds the schedule actually lands — the
-                # planner slices this strategy's OWN bucketable payload
-                # (bucket_payload_bytes), the wire ships its (possibly
-                # compressed) bytes
-                "monolithic": bucketed_exchange(
-                    wb, bucket_payload_bytes(strat, P, dense), t_step, 0),
-                "bucket4m": bucketed_exchange(
-                    wb, bucket_payload_bytes(strat, P, dense), t_step,
-                    DEFAULT_BUCKET_BYTES)}
+                "monolithic": monolithic_exchange(wb, t_step)}
             cells += f"{no_ovl:>11.3f}/{full_ovl:<10.3f}"
         out["rows"].append(row)
         print(f"{cfg:24} {ips:>9.0f} {t_step * 1e3:>9.2f} {cells}",
               file=sys.stderr)
     # pipeline-schedule rows (round 10): predicted bubble vs the measured
     # devprof columns of the r10 matrix rows — same predicted-vs-measured
-    # join the r9 bucket rows get above
+    # join the r9 trace rows get above
     out["pipeline_rows"] = []
     print(f"\n{'pipeline row':34} {'pred bubble':>11} {'meas ticks':>10} "
           f"{'meas time':>9} {'verified':>8}", file=sys.stderr)

@@ -6,10 +6,12 @@ cadenced exchanges.
 Contracts pinned here:
 
 * bit-equivalence — k steps fused must equal k single-step dispatches
-  driven through the Python exchange hook, for EASGD / ASGD / BSP params
-  mode exactly, and for GoSGD given the same traced gossip draws (the
-  fused path derives them from ``steps.fused_exchange_key``; the
-  standalone run is handed the same base key);
+  driven through the Python exchange hook, for EASGD / ASGD / BSP (params
+  mode, and grads mode on every error-fed or cast wire) exactly, for
+  EASGD / ASGD with a demoted set, and for GoSGD given the same traced
+  gossip draws (the fused path derives them from
+  ``steps.fused_exchange_key``; the standalone run is handed the same
+  base key);
 * one dispatch per window — ``train_fn`` fires once per k-step window and
   the standalone ``_exchange_fn`` never fires;
 * recorder sanity — with the exchange in-scan, its cost rides the
@@ -29,12 +31,16 @@ from theanompi_tpu.parallel.mesh import worker_mesh
 from theanompi_tpu.utils.recorder import Recorder
 
 
-def _build(exch_cls, spc, n=4, **cfg):
+def _build(exch_cls, spc, n=4, active=None, **cfg):
     mesh = worker_mesh(n)
     config = {"mesh": mesh, "size": n, "rank": 0, "verbose": False,
               "batch_size": 8, "steps_per_call": spc, **cfg}
     model = TinyModel(config)
     exch = exch_cls(config)
+    if active is not None:
+        # demote BEFORE compile so both dispatch shapes trace the mask
+        exch.mesh, exch.model, exch.size = mesh, model, n
+        exch.set_active_ranks(active)
     model.compile_iter_fns(exch)
     model.data.shuffle_data(0)
     return model, exch
@@ -64,11 +70,38 @@ def _assert_state_equal(a, b, parts=("params", "opt_state", "extra")):
     (ASGD_Exchanger, {"sync_freq": 1}),      # lax.cond gate must skip steps
     (ASGD_Exchanger, {"sync_freq": 2}),
     (BSP_Exchanger, {"exch_mode": "params"}),
-], ids=["easgd-f2", "easgd-f3", "asgd-f1", "asgd-f2", "bsp-params"])
+    # grads mode: the wire runs inside every scanned step, and the
+    # compressed wires carry their error feedback through the scan
+    (BSP_Exchanger, {"exch_strategy": "allreduce"}),
+    (BSP_Exchanger, {"exch_strategy": "nccl16"}),
+    (BSP_Exchanger, {"exch_strategy": "onebit"}),
+    (BSP_Exchanger, {"exch_strategy": "topk"}),
+    (BSP_Exchanger, {"exch_strategy": "powersgd"}),
+], ids=["easgd-f2", "easgd-f3", "asgd-f1", "asgd-f2", "bsp-params",
+        "bsp-allreduce", "bsp-nccl16", "bsp-onebit", "bsp-topk",
+        "bsp-powersgd"])
 def test_fused_bit_equals_unfused(exch_cls, cfg):
     s1 = _drive(*_build(exch_cls, 1, **cfg), k=1)
     s4 = _drive(*_build(exch_cls, 4, **cfg), k=4)
     _assert_state_equal(s1, s4)
+
+
+@pytest.mark.parametrize("exch_cls,cfg", [
+    (EASGD_Exchanger, {"sync_freq": 1}),
+    (EASGD_Exchanger, {"sync_freq": 2}),
+    (ASGD_Exchanger, {"sync_freq": 1}),
+], ids=["easgd-f1", "easgd-f2", "asgd-f1"])
+def test_fused_demoted_set_bit_equals_unfused(exch_cls, cfg):
+    """Ranks (0, 2) active: the membership mask traced into the scanned
+    window gives the state two single dispatches give, bit for bit, and
+    the mask is live (the full set ends elsewhere)."""
+    s1 = _drive(*_build(exch_cls, 1, active=(0, 2), **cfg), k=1)
+    s2 = _drive(*_build(exch_cls, 2, active=(0, 2), **cfg), k=2)
+    _assert_state_equal(s1, s2)
+    full = _drive(*_build(exch_cls, 2, **cfg), k=2)
+    assert any(not np.array_equal(x, y) for x, y in zip(
+        jax.tree_util.tree_leaves(s2["params"]),
+        jax.tree_util.tree_leaves(full["params"])))
 
 
 @pytest.mark.parametrize("peers", ["perm", "shift", "iid"])

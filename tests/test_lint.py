@@ -1298,7 +1298,7 @@ def test_collective_discipline_start_done_pairing(tmp_path):
 
 
 def test_collective_discipline_discarded_ticket(tmp_path):
-    """The bucket-balance probe: a start whose ticket hits the floor is
+    """The pairing probe: a start whose ticket hits the floor is
     flagged even when another pair balances the scope's counts."""
     code = (
         "from jax import lax\n"
@@ -1314,13 +1314,14 @@ def test_collective_discipline_discarded_ticket(tmp_path):
 
 
 def test_collective_discipline_bucket_loop_balanced_ok(tmp_path):
-    """The bucketed-wire shape (parallel/buckets.py): starts collected
-    into a ticket list, dones drained from it — balanced, clean."""
+    """Starts collected into a ticket list, dones drained from it —
+    balanced, clean."""
     code = (
-        "from theanompi_tpu.jax_compat import psum_start, psum_done\n"
-        "def exchange(vecs):\n"
-        "    tickets = [psum_start(v, 'workers') for v in vecs]\n"
-        "    return [psum_done(t) for t in tickets]\n")
+        "from theanompi_tpu.jax_compat import ppermute_start, "
+        "ppermute_done\n"
+        "def exchange(vecs, perm):\n"
+        "    tickets = [ppermute_start(v, 'workers', perm) for v in vecs]\n"
+        "    return [ppermute_done(t) for t in tickets]\n")
     assert lint_snippet(tmp_path, "x.py", code,
                         "collective-discipline") == []
 
@@ -1335,35 +1336,36 @@ def test_collective_discipline_shim_module_exempt():
 
 
 def test_injection_dropped_done_in_buckets(tmp_path):
-    """Live injection (the ISSUE 13 bucket-balance gate): drop the ONE
-    psum_done from the real bucketed-psum engine and the checker must
-    catch the leaked in-flight buckets; the unmodified file is clean."""
-    clean = core.run_lint(REPO, paths=["theanompi_tpu/parallel/buckets.py"],
+    """Live injection: unwrap the pipeline hop's ticket by hand instead
+    of through its ppermute_done and the checker must catch the
+    unbalanced pair; the unmodified file is clean."""
+    clean = core.run_lint(REPO,
+                          paths=["theanompi_tpu/parallel/pipeline.py"],
                           only=["collective-discipline"])
     assert clean == [], [f.render() for f in clean]
-    rel = _inject(tmp_path, "theanompi_tpu/parallel/buckets.py",
-                  "lambda t: psum_done(t))",
-                  "lambda t: t.value)")
+    rel = _inject(tmp_path, "theanompi_tpu/parallel/pipeline.py",
+                  "            state = jc.ppermute_done(ticket)",
+                  "            state = ticket.value")
     found = core.run_lint(str(tmp_path), paths=[rel],
                           only=["collective-discipline"])
     assert any("unbalanced async collective pair" in f.message
-               and "psum_start" in f.message for f in found), \
+               and "ppermute_start" in f.message for f in found), \
         [f.render() for f in found]
 
 
 def test_injection_dropped_done_in_onebit_strategy(tmp_path):
-    """Same gate on the compressed wire: strip the all_gather_done from
-    OneBit's bucketed decode loop → unbalanced pair."""
-    rel = _inject(tmp_path, "theanompi_tpu/parallel/strategies.py",
-                  "compress_ops.unpack_signs_weighted_mean(\n"
-                  "                all_gather_done(t), all_scales, size)",
-                  "compress_ops.unpack_signs_weighted_mean(\n"
-                  "                t.value, all_scales, size)")
+    """Live injection: a discarded ppermute_start before the pipeline's
+    real one is a leaked in-flight collective, though another pair
+    balances the scope's counts."""
+    rel = _inject(tmp_path, "theanompi_tpu/parallel/pipeline.py",
+                  "            ticket = jc.ppermute_start(",
+                  "            jc.ppermute_start(out, axis, "
+                  "list(sched.perm))\n"
+                  "            ticket = jc.ppermute_start(")
     found = core.run_lint(str(tmp_path), paths=[rel],
                           only=["collective-discipline"])
-    assert any("unbalanced async collective pair" in f.message
-               and "all_gather_start" in f.message for f in found), \
-        [f.render() for f in found]
+    assert any("leaked in-flight collective" in f.message
+               for f in found), [f.render() for f in found]
 
 
 def test_collective_discipline_dead_ticket(tmp_path):

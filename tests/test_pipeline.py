@@ -256,3 +256,24 @@ def test_pp_interleave_validation_errors(mesh8):
         mesh = worker_mesh(2, pp=1)
         TransformerLM({**LM_CFG, "mesh": mesh, "size": 2, "rank": 0,
                        "pp": 1, "pp_interleave": 2})
+
+
+def test_shim_sync_fallback_round_trip():
+    """Without a real async surface the start permutes eagerly and the
+    done unwraps — the one calling convention the pipeline's hop (and
+    tpulint's pairing probe) sees, with a plain ppermute's result."""
+    from jax.sharding import PartitionSpec as P
+    from theanompi_tpu import jax_compat
+    mesh = worker_mesh(4)
+    perm = [(i, (i + 1) % 4) for i in range(4)]
+
+    def f(x):
+        t = jax_compat.ppermute_start(x, WORKER_AXIS, perm)
+        return jax_compat.ppermute_done(t)
+
+    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P(WORKER_AXIS),
+                          out_specs=P(WORKER_AXIS)))
+    x = jnp.arange(8.0)
+    np.testing.assert_array_equal(np.asarray(g(x)),
+                                  np.roll(np.asarray(x).reshape(4, 2), 1,
+                                          axis=0).reshape(-1))

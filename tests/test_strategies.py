@@ -332,3 +332,63 @@ def test_allreduce_scales_summed_leaves_without_a_collective(mesh8):
                                   np.asarray(out["b"]))
     np.testing.assert_allclose(np.asarray(out_held["w"]), tree["w"] / N,
                                rtol=1e-6)
+
+
+def _n_compressible(params):
+    psgd = get_strategy("powersgd")
+    return sum(psgd._compressible(np.shape(l))
+               for l in jax.tree.leaves(params))
+
+
+# each BSP wire's collectives a dispatch, a device: (op class, count from
+# TinyModel's parameters and the mesh's size n)
+_WIRE_COLLECTIVES = {
+    "allreduce": ("all-reduce", lambda p, n: len(jax.tree.leaves(p))),
+    "nccl16": ("all-reduce", lambda p, n: len(jax.tree.leaves(p))),
+    # the scale and the packed signs / the values and the offsets
+    "onebit": ("all-gather", lambda p, n: 2),
+    "topk": ("all-gather", lambda p, n: 2),
+    # reduce-scatter, then all-gather: one hop each
+    "ring": ("collective-permute", lambda p, n: 2 * (n - 1)),
+    # the stacked P and Q factor sums, then one sum a dense leaf
+    "powersgd": ("all-reduce", lambda p, n: 2 + len(jax.tree.leaves(p))
+                 - _n_compressible(p)),
+}
+
+
+@pytest.mark.parametrize("wire", sorted(_WIRE_COLLECTIVES))
+def test_bsp_window_collective_count(wire):
+    """A devprof capture of k BSP dispatches on the four-device CPU mesh
+    holds each wire's collectives k times on each device, and every
+    async start its done.  train_fn is driven directly: train_iter's
+    cost-mean helper dispatches a collective of its own."""
+    from tests.conftest import TinyModel
+    from theanompi_tpu.parallel import steps
+    from theanompi_tpu.parallel.exchanger import BSP_Exchanger
+    from theanompi_tpu.parallel.mesh import worker_mesh
+    from theanompi_tpu.utils import devprof
+    k, n = 3, 4
+    mesh = worker_mesh(n)
+    cfg = {"mesh": mesh, "size": n, "rank": 0, "verbose": False,
+           "batch_size": 8, "exch_strategy": wire}
+    model = TinyModel(cfg)
+    model.compile_iter_fns(BSP_Exchanger(cfg))
+    model.data.shuffle_data(0)
+    batch = steps.put_batch(mesh, model.data.next_train_batch(0),
+                            model.batch_spec())
+    lr, rng = jnp.float32(0.05), jax.random.key(0)
+    st, _, _ = model.train_fn(model.step_state, batch, lr, rng,
+                              jnp.int32(1))
+    jax.block_until_ready(st["params"])         # compile outside the window
+    with devprof.capture() as cap:
+        for count in range(2, 2 + k):
+            st, _, _ = model.train_fn(st, batch, lr, rng, jnp.int32(count))
+        jax.block_until_ready(st["params"])
+    assert cap.profile is not None
+    ops = {o["op"]: o["count"] for o in cap.profile["top_ops"]}
+    for op, c in ops.items():
+        if op.endswith("-start"):
+            assert ops.get(op[:-len("-start")] + "-done") == c, ops
+    op_class, per_device = _WIRE_COLLECTIVES[wire]
+    got = sum(c for op, c in ops.items() if op.startswith(op_class))
+    assert got == per_device(model.params, n) * k * n, (got, ops)

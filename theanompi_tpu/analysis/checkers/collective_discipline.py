@@ -1,7 +1,6 @@
 """collective-discipline: named-axis collectives must be well-formed SPMD.
 
-Three invariants (docs/design.md §12), guarding the ROADMAP-item-1
-bucketed overlap-scheduled collectives before they exist:
+Three invariants (docs/design.md §12):
 
 1. **Axis names are real.**  Every ``lax.psum`` / ``pmean`` /
    ``ppermute`` / ``all_gather`` / ``all_to_all`` / ``axis_index`` /
@@ -26,18 +25,18 @@ bucketed overlap-scheduled collectives before they exist:
    Dataflow is per-function: names assigned from the two APIs taint,
    taint propagates through assignments.
 
-3. **Paired start/done APIs match — the bucket-balance probe.**  Async
+3. **Paired start/done APIs match — the pairing probe.**  Async
    collective pairs (``lax.<x>_start`` / ``lax.<x>_done`` and the
-   ``jax_compat`` shims the bucketed overlap wire of
-   ``parallel/buckets.py`` issues) must balance within one function
-   scope: a start with no done leaks an in-flight collective, a done
-   with no start is undefined.  Additionally a ``<x>_start`` whose
-   ticket is DISCARDED (a bare expression statement) is flagged even
-   when another start/done pair balances the counts — the in-flight
-   token must reach its done.  The shim-definition module itself
-   (``theanompi_tpu/jax_compat.py``) is exempt: each shim half
-   lexically contains its one-sided underlying ``lax`` call by
-   construction — that file IS the pairing boundary.
+   ``jax_compat.ppermute_start`` / ``ppermute_done`` shim that
+   ``parallel/pipeline.py``'s per-slot hop issues) must balance within
+   one function scope: a start with no done leaks an in-flight
+   collective, a done with no start is undefined.  Additionally a
+   ``<x>_start`` whose ticket is DISCARDED (a bare expression
+   statement) is flagged even when another start/done pair balances
+   the counts — the in-flight token must reach its done.  The
+   shim-definition module itself (``theanompi_tpu/jax_compat.py``) is
+   exempt: each shim half lexically contains its one-sided underlying
+   ``lax`` call by construction — that file IS the pairing boundary.
 """
 
 from __future__ import annotations
@@ -179,7 +178,7 @@ class CollectiveDisciplineChecker(Checker):
         stmts = list(self._scope_stmts(sf, scope))
         seen_hazard: Set[Tuple[int, int]] = set()
 
-        # 1 + 3: axis validity and start/done balance (the bucket-balance
+        # 1 + 3: axis validity and start/done balance (the pairing
         # probe).  Each call is visited exactly once: through the
         # expression roots of its own statement (nested block statements
         # are yielded separately).  The shim-definition module is exempt

@@ -87,9 +87,10 @@ COLLECTIVES = {
     "ppermute": 1, "pshuffle": 1, "all_gather": 1,
     "all_gather_invariant": 1, "all_to_all": 1, "psum_scatter": 1,
     "axis_index": 0, "axis_size": 0,
-    # async start halves (the bucketed-wire shims in jax_compat — their
-    # `_done` twins take a ticket, not an axis, and are covered by the
-    # collective-discipline pairing probe instead)
+    # async start halves (lax's own, and jax_compat's ppermute shim that
+    # pipeline.py's hop goes through — their `_done` twins take a ticket,
+    # not an axis, and are covered by the collective-discipline pairing
+    # probe instead)
     "psum_start": 1, "all_gather_start": 1, "ppermute_start": 1,
 }
 
@@ -135,7 +136,6 @@ TRACE_SHAPE_SLOTS = {
     # host-side tables into the traced program (docs/design.md §26)
     "theanompi_tpu.parallel.pipeline.build_schedule": "all",
     "theanompi_tpu.parallel.update_sharding.plan_tree": "all",
-    "theanompi_tpu.parallel.buckets.plan_buckets": (1, "bucket_bytes"),
 }
 
 #: Method names whose arguments are shape slots on any receiver.

@@ -1,5 +1,5 @@
 """The one place this package touches jax's moving SPMD surface:
-``shard_map``, the vma "varying" marker, and async collectives.
+``shard_map``, the vma "varying" marker, and the async permute pair.
 
 The target is the jax this container and the chip machine run (0.9.0):
 ``jax.shard_map`` with the vma varying-axes type system, ``jax.typeof`` and
@@ -7,26 +7,20 @@ The target is the jax this container and the chip machine run (0.9.0):
 ``compat-boundary`` checker enforces it), so the next API move is absorbed
 in one file.
 
-**Async collective start/done pairs** (the bucketed-overlap wire,
-``parallel/buckets.py``): some jaxlibs expose an explicit async
-collective surface (``lax.psum_start``/``psum_done``-shaped APIs that
-return an in-flight token); most — including this one — do not, and rely
-on XLA's latency-hiding scheduler to convert independent collectives to
-``<op>-start``/``<op>-done`` HLO pairs itself.  The shims below give the
-exchange path ONE calling convention for both worlds:
-
-* when the running jaxlib exposes the async API, ``<x>_start`` returns
-  its real in-flight ticket and ``<x>_done`` awaits it;
-* otherwise (the sync fallback) ``<x>_start`` issues the plain
-  collective eagerly — the ticket IS the result — and ``<x>_done``
-  unwraps it.  Scheduling-wise nothing is lost: each bucket is still its
-  own independent collective for the latency-hiding scheduler to
-  overlap with the backward pass.
+**The async permute pair** (the pipeline's per-slot hop,
+``parallel/pipeline.py``): some jaxlibs expose ``lax.ppermute_start`` /
+``ppermute_done``, which return and await an in-flight token; this one
+(0.9.0) does not, and relies on XLA's latency-hiding scheduler to make
+``collective-permute-start``/``-done`` HLO pairs itself.  When the
+running jaxlib has the API, ``ppermute_start`` returns its real ticket
+and ``ppermute_done`` awaits it; otherwise ``ppermute_start`` issues the
+plain ``lax.ppermute`` eagerly — the ticket IS the result — and
+``ppermute_done`` unwraps it.
 
 Discipline contract (enforced by tpulint's collective-discipline
-checker): every ``<x>_start`` call's ticket must reach a matching
-``<x>_done`` in the same scope — a dropped ticket is a leaked in-flight
-collective the day a real async surface binds.
+checker): every ``ppermute_start`` call's ticket must reach a matching
+``ppermute_done`` in the same scope — a dropped ticket is a leaked
+in-flight collective the day a real async surface binds.
 """
 
 from __future__ import annotations
@@ -120,55 +114,18 @@ class _VaryingOutShapes:
         return jax.ShapeDtypeStruct(shape, dtype, **kw)
 
 
-# -- async collective start/done ---------------------------------------------
-
-# True when the running jaxlib exposes a real async start/done surface;
-# the sync fallback below is used otherwise.  False on jax 0.9.0
-# (``lax.psum_start`` is absent).
-HAS_ASYNC_COLLECTIVES = all(
-    hasattr(lax, n) for n in ("psum_start", "psum_done"))
-
+# -- async permute start/done ------------------------------------------------
 
 class _SyncTicket(NamedTuple):
     """Sync-fallback in-flight token: the collective already ran eagerly,
-    the ticket carries its result to the paired ``<x>_done``."""
+    the ticket carries its result to the paired ``ppermute_done``."""
 
     value: Any
 
 
-def psum_start(x, axis_name):
-    """Begin one bucket's cross-worker sum; returns an in-flight ticket
-    for :func:`psum_done`."""
-    if HAS_ASYNC_COLLECTIVES:
-        return lax.psum_start(x, axis_name)
-    return _SyncTicket(lax.psum(x, axis_name))
-
-
-def psum_done(ticket):
-    """Await one :func:`psum_start` ticket and return the reduced value."""
-    if HAS_ASYNC_COLLECTIVES:
-        return lax.psum_done(ticket)
-    return ticket.value
-
-
-def all_gather_start(x, axis_name):
-    """Begin one bucket's all-gather (compressed wires ship packed
-    buckets); returns an in-flight ticket for :func:`all_gather_done`."""
-    if hasattr(lax, "all_gather_start"):
-        return lax.all_gather_start(x, axis_name)
-    return _SyncTicket(lax.all_gather(x, axis_name))
-
-
-def all_gather_done(ticket):
-    """Await one :func:`all_gather_start` ticket."""
-    if hasattr(lax, "all_gather_done"):
-        return lax.all_gather_done(ticket)
-    return ticket.value
-
-
 def ppermute_start(x, axis_name, perm):
-    """Begin one bucket's peer-to-peer permute (GoSGD gossip payloads);
-    returns an in-flight ticket for :func:`ppermute_done`."""
+    """Begin one peer-to-peer permute; returns an in-flight ticket for
+    :func:`ppermute_done`."""
     if hasattr(lax, "ppermute_start"):
         return lax.ppermute_start(x, axis_name, perm)
     return _SyncTicket(lax.ppermute(x, axis_name, perm))

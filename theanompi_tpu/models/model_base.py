@@ -361,13 +361,6 @@ class ModelBase:
                 f"composes; got {type(self.exchanger).__name__} mode="
                 f"{getattr(self.exchanger, 'mode', '?')} strategy="
                 f"{getattr(getattr(self.exchanger, 'strategy', None), 'name', '?')}")
-            # same silently-ignored class of knob: the bucketed wire
-            # (parallel/buckets.py) lives in the strategy/exchange_body
-            # hooks the fsdp path never runs — a bucketed-looking row
-            # measuring a monolithic wire would corrupt the r9 analysis
-            assert int(self.config.get("bucket_bytes", 0) or 0) == 0, (
-                "fsdp=true has no exchanger wire to bucket (grads arrive "
-                "via the all_gather transpose) — drop bucket_bytes")
         if self.config.get("zero_opt", False) or self.config.get("ema_decay") \
                 or self._ushard_plan is not None:
             # ZeRO-1 / leaf-wise update sharding assume every worker sees

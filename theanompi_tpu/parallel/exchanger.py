@@ -45,7 +45,6 @@ See docs/design.md §8 for the GoSGD traced-RNG contract.
 
 from __future__ import annotations
 
-import functools
 import time
 from typing import Any, Dict, Optional
 
@@ -55,7 +54,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import buckets, steps, strategies, topology, update_sharding
+from . import steps, strategies, topology, update_sharding
 from ..jax_compat import shard_map
 from ..models import layers
 from ..utils import telemetry, tracing
@@ -119,13 +118,6 @@ class Exchanger:
         # shardable extra keys, built in prepare() once model+mesh exist
         self._ushard_plan = None
         self._ushard_keys: tuple = ()
-        # bucketed overlap-scheduled wire (parallel/buckets.py): split the
-        # exchange payload into ~bucket_bytes collectives issued as async
-        # start/done pairs so XLA's latency-hiding scheduler can overlap
-        # them with the backprop tail.  0 (default) = the monolithic wire
-        # — bucketed ≡ monolithic bit-for-bit at fixed membership
-        # (tests/test_buckets.py), so this is purely a schedule knob.
-        self.bucket_bytes = int(self.config.get("bucket_bytes", 0) or 0)
         # True when compile_iter_fns fused this rule's cadence into the
         # scanned multi-step train dispatch (steps_per_call > 1): the
         # Python exchange() hook then must not run the collective again.
@@ -201,27 +193,6 @@ class Exchanger:
             mask[:] = 0.0
             mask[list(self._active_ranks)] = 1.0
         return mask
-
-    # -- bucketed wire (parallel/buckets.py) --------------------------------
-
-    def _psum_tree(self, tree, axis):
-        """The rule's cross-worker sum of a params-shaped payload: the
-        leaf-wise monolithic ``lax.psum`` at ``bucket_bytes=0``, else
-        per-bucket async start/done pairs.  Membership masking composes
-        per bucket for free — masks scale VALUES upstream of the pack,
-        and the plan is a pure function of shapes, so a demoted rank's
-        zeroed contribution rides the identical bucket schedule."""
-        return buckets.bucketed_psum(tree, axis, self.bucket_bytes)
-
-    def n_buckets(self) -> Optional[int]:
-        """Collectives one exchange issues under the current
-        ``bucket_bytes`` (bench's ``n_buckets`` row column; None when the
-        wire is monolithic or the rule has no exchange payload).  The
-        default models the params-shaped payload the psum/gossip rules
-        ship; compressed strategies override via their packed layouts."""
-        if self.bucket_bytes <= 0 or self.model is None:
-            return None
-        return buckets.count_buckets(self.model.params, self.bucket_bytes)
 
     def exchange_body(self, state, key, count):
         """The rule's exchange algebra as a PURE per-worker function:
@@ -511,16 +482,6 @@ class BSP_Exchanger(Exchanger):
         self.mode = self.config.get("exch_mode", "grads")
         self.strategy: Strategy = get_strategy(
             self.config.get("exch_strategy", "allreduce"))
-        # bucketed wire: the strategy owns BSP's collectives (in-step
-        # grads mode and the params-mode exchange_body alike), so the
-        # knob is forwarded there — each strategy buckets its OWN wire
-        # format (fp32 leaves, packed signs, topk rows...)
-        self.strategy.bucket_bytes = self.bucket_bytes
-
-    def n_buckets(self):
-        if self.bucket_bytes <= 0 or self.model is None:
-            return None
-        return self.strategy.n_buckets(self.model.params, self.bucket_bytes)
 
     def identical_parts(self):
         # grads mode: every worker applies the same reduced gradient; params
@@ -815,10 +776,8 @@ class EASGD_Exchanger(Exchanger):
             m = jnp.asarray(self.active_mask())[ridx]
             contrib = jax.tree.map(lambda d: d * m, delta)
             pull, n_act = m, float(len(active))
-        # the wire: one psum per bucket (bucket_bytes > 0) or the leaf
-        # -wise monolith — bit-identical either way, the mask already
-        # scaled the values above
-        delta_sum = self._psum_tree(contrib, axis)
+        # the wire: one psum a leaf; the mask already scaled the values
+        delta_sum = jax.tree.map(lambda d: lax.psum(d, axis), contrib)
         mean_delta = jax.tree.map(lambda d: d / n_act, delta_sum)
         new_center = jax.tree.map(lambda c, d: c + alpha * d,
                                   center, mean_delta)
@@ -897,10 +856,10 @@ class ASGD_Exchanger(Exchanger):
             d = p - c
             return d * gate if gate is not None else d
 
-        # mask-then-psum, bucketed or monolithic per bucket_bytes — the
-        # downpour sum is element-wise, so the schedule can't change it
-        delta_sum = self._psum_tree(
-            jax.tree.map(leaf_delta, params, center), axis)
+        # mask, then sum: one psum a leaf
+        delta_sum = jax.tree.map(
+            lambda d: lax.psum(d, axis),
+            jax.tree.map(leaf_delta, params, center))
         new_center = jax.tree.map(jnp.add, center, delta_sum)
         if gate is None:
             new_params = new_center
@@ -1120,18 +1079,7 @@ class GOSGD_Exchanger(Exchanger):
         w_send = jnp.where(send, alpha * 0.5, 0.0)
         w_keep = alpha - w_send
         msg = jax.tree.map(lambda p: p * w_send, params)
-        # bucketed wire: the routing modes tree.map their ppermutes over
-        # whatever payload structure they are handed, so packing the
-        # message into ~bucket_bytes vectors turns ONE whole-model
-        # permute per hop into n_buckets independent per-bucket permutes
-        # the scheduler can pipeline — and the merge below unpacks the
-        # bit-identical payload (permutes are element-wise routing)
-        plan = buckets.plan_buckets(params, self.bucket_bytes) \
-            if self.bucket_bytes > 0 else None
-        wire_msg = msg if plan is None else buckets.pack(msg, plan)
-        wire_msg, w_recv = self._route((wire_msg, w_send), step_key)
-        recv_msg = wire_msg if plan is None else \
-            buckets.unpack(wire_msg, msg, plan)
+        recv_msg, w_recv = self._route((msg, w_send), step_key)
 
         new_alpha = w_keep + w_recv
         new_params = jax.tree.map(

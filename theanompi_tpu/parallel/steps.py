@@ -554,11 +554,10 @@ def build_train_step(mesh: Mesh, model, exchanger, n_steps: int = 1) -> Callable
 def _keep_collectives_apart() -> Optional[dict]:
     """Compiler options for the train step on the CPU backend: its
     ``cpu-all-reduce-combiner`` pass merges the step's all-reduces (one a
-    leaf on the monolithic wire, one a bucket on the bucketed one) into a
-    single variadic collective, so a ``devprof`` capture on the CPU mesh
-    counted 1 collective a step whatever the wire issued — the structure
-    the CPU rehearsals exist to check.  Nothing on any other backend:
-    their programs, and cache keys, stay as they were."""
+    leaf) into a single variadic collective, so a ``devprof`` capture on
+    the CPU mesh counted 1 collective a step whatever the wire issued —
+    the structure the CPU rehearsals exist to check.  Nothing on any
+    other backend: their programs, and cache keys, stay as they were."""
     if jax.default_backend() != "cpu":
         return None
     return {"xla_disable_hlo_passes": "cpu-all-reduce-combiner"}

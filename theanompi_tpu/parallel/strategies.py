@@ -39,7 +39,6 @@ Stateful strategies (error feedback) carry per-worker state.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -47,11 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..jax_compat import (all_gather_done, all_gather_start, psum_done,
-                          psum_start)
 from ..utils import helper_funcs
 from ..ops import compress as compress_ops
-from . import buckets
 
 #: Deliberate non-bit-exact rounding sites, audited by tpulint's
 #: dtype-flow checker (docs/design.md §26) — every direct
@@ -74,27 +70,11 @@ class Strategy:
     # replicated-leaf segments, so the exchanger re-imposes replication on
     # the replicated leaves afterwards (a pmean over 'model')
     flattens = False
-    # bucketed overlap-scheduled wire (parallel/buckets.py, ROADMAP item
-    # 1): > 0 splits this strategy's collectives into ~bucket_bytes
-    # slices issued as async start/done pairs; 0 keeps the monolithic
-    # wire.  Set by BSP_Exchanger from config['bucket_bytes'] — a
-    # SCHEDULE knob only: bucketed ≡ monolithic bit-for-bit, pinned per
-    # strategy in tests/test_buckets.py.
-    bucket_bytes = 0
 
     def init_state(self, params) -> Any:
         """Per-worker persistent state (unsharded template; the exchanger adds
         the leading ``[n_workers]`` axis)."""
         return ()
-
-    def n_buckets(self, params, bucket_bytes: int) -> Optional[int]:
-        """Wire slices one exchange of a ``params``-shaped payload ships
-        at ``bucket_bytes`` (bench's ``n_buckets`` row column).  The
-        default models the fp32 leaf payload (allreduce-family);
-        compressed strategies override with their packed layouts; None =
-        this strategy's wire does not bucket (ring's hand-rolled chunk
-        pipeline, the no-comm probe)."""
-        return buckets.count_buckets(params, bucket_bytes)
 
     def __call__(self, tree, state, *, axis: str, size: int):
         raise NotImplementedError
@@ -111,9 +91,6 @@ class NoComm(Strategy):
     """
 
     name = "none"
-
-    def n_buckets(self, params, bucket_bytes: int):
-        return None                       # no collective, nothing to slice
 
     def __call__(self, tree, state, *, axis: str, size: int):
         inv = 1.0 / size
@@ -182,20 +159,6 @@ class AllReduce(Strategy):
                 [g * inv if h else next(rest)
                  for (_, g), h in zip(flat, held)]), state
         wd = self.wire_dtype
-        if self.bucket_bytes > 0:
-            # per-bucket async psum pairs: all starts issued before the
-            # first done so the latency-hiding scheduler can overlap the
-            # buckets with the backprop tail.  The wire cast (if any)
-            # happens per bucket — same elementwise cast→psum→cast chain
-            # as the monolithic leaf, so bit-identity holds either way.
-            plan = buckets.plan_buckets(tree, self.bucket_bytes)
-            vecs = buckets.pack(tree, plan)
-            tickets = [psum_start(v if wd is None else v.astype(wd), axis)
-                       for v in vecs]
-            sums = [psum_done(t) for t in tickets]
-            reduced = [(s if wd is None else s.astype(v.dtype)) * inv
-                       for s, v in zip(sums, vecs)]
-            return buckets.unpack(reduced, tree, plan), state
         if wd is None:
             out = jax.tree.map(lambda g: lax.psum(g, axis) * inv, tree)
         else:
@@ -223,11 +186,6 @@ class Ring(Strategy):
         self.wire_dtype = wire_dtype
         self.name = "ring" if wire_dtype is None else "ring16"
         self.flattens = True
-
-    def n_buckets(self, params, bucket_bytes: int):
-        # the ring IS a chunk pipeline already (2(size-1) ppermute hops
-        # over size-th slices) — the bucket planner does not re-slice it
-        return None
 
     def __call__(self, tree, state, *, axis: str, size: int):
         if size == 1:
@@ -302,21 +260,6 @@ class OneBit(Strategy):
         padded = n + (-n) % compress_ops.PACK_ALIGN
         return jnp.zeros((padded,), jnp.float32)
 
-    def _segment_elems(self, bucket_bytes: int) -> int:
-        """fp32 elements per wire bucket, rounded DOWN to the pack-kernel
-        grid (PACK_ALIGN) so every bucket's packed buffer is whole tiles
-        — the pack/decode pair is blockwise, which is exactly why
-        bucketed ≡ monolithic bit-for-bit."""
-        return max(compress_ops.PACK_ALIGN,
-                   (int(bucket_bytes) // 4 // compress_ops.PACK_ALIGN)
-                   * compress_ops.PACK_ALIGN)
-
-    def n_buckets(self, params, bucket_bytes: int):
-        n = helper_funcs.tree_size(params)
-        n += (-n) % compress_ops.PACK_ALIGN
-        seg = self._segment_elems(bucket_bytes)
-        return max(1, -(-n // seg))
-
     def __call__(self, tree, state, *, axis: str, size: int):
         flat = helper_funcs.flatten_tree(
             tree, pad_to_multiple_of=compress_ops.PACK_ALIGN)
@@ -332,26 +275,9 @@ class OneBit(Strategy):
         # unfused c − scale·sign(c)
         new_state = compress_ops.signed_residual(absc, packed, scale)
         all_scales = lax.all_gather(scale, axis)       # [size] — one scalar
-        if self.bucket_bytes > 0:
-            # per-bucket wire: the vector is packed ONCE and each bucket
-            # all-gathers its PACK_ALIGN-aligned slice of PACKED rows as
-            # its own async pair (all starts before the first done),
-            # decoding per bucket with the GLOBAL scale — the pack/decode
-            # pair is blockwise, so bucketed ≡ monolithic bit-for-bit
-            seg = self._segment_elems(self.bucket_bytes)
-            rows_per = seg // (32 * compress_ops.LANES)  # packed rows/bucket
-            p_rows = packed.shape[0]
-            bounds = [(a, min(a + rows_per, p_rows))
-                      for a in range(0, p_rows, rows_per)]
-            tickets = [all_gather_start(packed[a:b], axis)
-                       for a, b in bounds]
-            segs = [compress_ops.unpack_signs_weighted_mean(
-                all_gather_done(t), all_scales, size) for t in tickets]
-            mean = segs[0] if len(segs) == 1 else jnp.concatenate(segs)
-        else:
-            all_packed = lax.all_gather(packed, axis)  # P/8 bytes/worker
-            mean = compress_ops.unpack_signs_weighted_mean(
-                all_packed, all_scales, size)
+        all_packed = lax.all_gather(packed, axis)      # P/8 bytes/worker
+        mean = compress_ops.unpack_signs_weighted_mean(
+            all_packed, all_scales, size)
         return helper_funcs.unflatten_like(tree, mean), new_state
 
 
@@ -399,16 +325,8 @@ class TopK(Strategy):
         return jnp.zeros((padded,), jnp.float32)
 
     def _k_c(self) -> int:
-        """Selected entries per chunk row — ONE derivation for the
-        exchange itself and the n_buckets bench column."""
+        """Selected entries per chunk row."""
         return self.k or max(1, int(round(self.chunk * self.ratio)))
-
-    def _rows_per_bucket(self, k_c: int, bucket_bytes: int) -> int:
-        """Chunk rows per wire bucket: a row ships ``k_c`` bf16 values +
-        ``k_c`` int16 offsets = 4·k_c bytes.  Shared by the bucketed
-        exchange and n_buckets so the bench column can't drift from the
-        collectives actually issued."""
-        return max(1, int(bucket_bytes) // (4 * k_c))
 
     def __call__(self, tree, state, *, axis: str, size: int):
         flat = helper_funcs.flatten_tree(tree, pad_to_multiple_of=self.chunk)
@@ -426,37 +344,10 @@ class TopK(Strategy):
         # stream loses nothing to the wire rounding either.
         wire_vals, wire_idx, new_c2 = compress_ops.topk_encode(c2, k_c)
         new_state = new_c2.reshape(-1)
-        if self.bucket_bytes > 0:
-            # per-bucket wire: the (vals, idx) pairs of ~bucket_bytes
-            # worth of CHUNK ROWS ride as their own async all-gather
-            # pairs; each bucket decodes into its own disjoint dense
-            # segment (chunk c only ever lands in [c·chunk, (c+1)·chunk)),
-            # so the per-bucket decodes reproduce the monolithic decode
-            # bit-for-bit
-            rows_per = self._rows_per_bucket(k_c, self.bucket_bytes)
-            bounds = [(a, min(a + rows_per, n_chunks))
-                      for a in range(0, n_chunks, rows_per)]
-            tickets = [(all_gather_start(wire_vals[a:b], axis),
-                        all_gather_start(wire_idx[a:b], axis))
-                       for a, b in bounds]
-            segs = [compress_ops.topk_decode(all_gather_done(tv),
-                                             all_gather_done(ti),
-                                             self.chunk, size)
-                    for tv, ti in tickets]
-            mean = segs[0] if len(segs) == 1 else jnp.concatenate(segs)
-        else:
-            all_vals = lax.all_gather(wire_vals, axis)  # [size, C, k_c]
-            all_idx = lax.all_gather(wire_idx, axis)
-            mean = compress_ops.topk_decode(all_vals, all_idx,
-                                            self.chunk, size)
+        all_vals = lax.all_gather(wire_vals, axis)  # [size, C, k_c]
+        all_idx = lax.all_gather(wire_idx, axis)
+        mean = compress_ops.topk_decode(all_vals, all_idx, self.chunk, size)
         return helper_funcs.unflatten_like(tree, mean), new_state
-
-    def n_buckets(self, params, bucket_bytes: int):
-        n = helper_funcs.tree_size(params)
-        n += (-n) % self.chunk
-        n_chunks = n // self.chunk
-        rows_per = self._rows_per_bucket(self._k_c(), bucket_bytes)
-        return max(1, -(-n_chunks // rows_per))
 
 
 class PowerSGD(Strategy):
@@ -528,14 +419,6 @@ class PowerSGD(Strategy):
                               "e": jnp.zeros((0, 0), jnp.float32)})
         return state
 
-    def n_buckets(self, params, bucket_bytes: int):
-        # the compressible leaves' P/Q factor psums are per-leaf small
-        # collectives already (their own pipeline); the planner buckets
-        # the DENSE remainder (vectors, norms, tiny matrices)
-        dense = [l for l in jax.tree.leaves(params)
-                 if not self._compressible(np.shape(l))]
-        return buckets.count_buckets(dense, bucket_bytes) if dense else 0
-
     def __call__(self, tree, state, *, axis: str, size: int):
         from ..ops import factor_pack
         from .steps import _vary
@@ -588,17 +471,8 @@ class PowerSGD(Strategy):
                 new_state[i] = {"q": _vary(Qn, axis), "e": Mps[i] - Mhat}
 
         # -- dense remainder ----------------------------------------------
-        dense_ids = [i for i in range(len(leaves)) if out[i] is None]
-        if self.bucket_bytes > 0 and dense_ids:
-            # the dense remainder rides the bucket planner: one async
-            # psum pair per ~bucket_bytes of incompressible leaves
-            # (element-wise sum — bit-identical to the leaf-wise psums)
-            summed = buckets.bucketed_psum([leaves[i] for i in dense_ids],
-                                           axis, self.bucket_bytes)
-            for i, s in zip(dense_ids, summed):
-                out[i] = s * inv
-        else:
-            for i in dense_ids:
+        for i in range(len(leaves)):
+            if out[i] is None:
                 out[i] = lax.psum(leaves[i], axis) * inv
         return jax.tree_util.tree_unflatten(treedef, out), new_state
 

@@ -253,7 +253,7 @@ def shard_opt(opt: OptPair, plan: UpdatePlan,
     grads/params down to this worker's chunks, runs the inner optimizer's
     elementwise math on them, and rebuilds full params with the fused
     allgather — inside whatever compiled step traces it, so
-    ``steps_per_call`` scans and bucketed exchange collectives are
+    ``steps_per_call`` scans and the exchange collectives are
     untouched.  Pad lanes are zeros in params AND grads, and every wrapped
     optimizer's update maps zeros to zeros, so the pad never leaks (and is
     trimmed by the rebuild regardless).  Requires bit-identical grads
